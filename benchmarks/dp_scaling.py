@@ -3,9 +3,10 @@
 Times one jit-compiled training step at device counts {1, 2, 4} — the
 single-device ``les.train_step`` baseline against the sharded
 ``dp.dp_train_step`` under each reducer (``psum`` / ``ring`` /
-``compress``).  Because forced host devices only exist if ``XLA_FLAGS``
-is set before backend init, each device count runs in a *worker
-subprocess* (``--worker``); the parent aggregates.
+``compress``).  Everything runs in one process, which holds every device
+it uses: each device count builds its ``data`` mesh over the first n of
+``jax.devices()``.  On a CPU host, ``__main__`` forces four host devices
+before JAX starts; counts above what the process has are skipped.
 
 Before timing, every variant is **parity-gated**: one step of each
 reducer must produce bitwise-identical parameters to the single-device
@@ -32,9 +33,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
-import tempfile
 
 JSON_PATH = "BENCH_parallel.json"
 
@@ -64,10 +62,9 @@ def _build(config: str, batch: int):
     return cfg, state, x, labels
 
 
-def _worker(out_path: str, config: str, batch: int, devices: int,
-            iters: int) -> None:
-    """Runs inside a subprocess whose XLA_FLAGS already forced ``devices``
-    host devices; writes the timing dict as JSON to ``out_path``."""
+def _measure(config: str, batch: int, devices: int, iters: int) -> dict:
+    """Parity-gate then time every variant on a mesh of ``devices``
+    devices; returns ``{variant: us_per_step}``."""
     import functools
 
     import jax
@@ -77,7 +74,6 @@ def _worker(out_path: str, config: str, batch: int, devices: int,
     from repro.core import les
     from repro.parallel import dp
 
-    assert jax.device_count() == devices, (jax.device_count(), devices)
     cfg, state, x, labels = _build(config, batch)
     key = jax.random.PRNGKey(2)
 
@@ -97,45 +93,26 @@ def _worker(out_path: str, config: str, batch: int, devices: int,
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"{reducer} @ {devices}dev")
 
-    us = time_paired(steps, state, x=x, labels=labels, key=key, iters=iters)
-    with open(out_path, "w") as f:
-        json.dump({"us_per_step": us, "bit_exact": True}, f)
+    return time_paired(steps, state, x=x, labels=labels, key=key,
+                       iters=iters)
 
 
 def run(quick: bool = False, smoke: bool = False) -> None:
+    import jax
+
     from benchmarks.common import emit
 
     iters = 3 if (quick or smoke) else 10
     configs = [("tiny", 8)] if smoke else CONFIGS
-    device_counts = [1, 2] if smoke else DEVICE_COUNTS
+    device_counts = [
+        n for n in ([1, 2] if smoke else DEVICE_COUNTS)
+        if n <= jax.device_count()
+    ]
     results: list[dict] = []
     for config, batch in configs:
         base_us = None
         for devices in device_counts:
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (
-                env.get("XLA_FLAGS", "").replace(
-                    "--xla_force_host_platform_device_count", "--removed") +
-                f" --xla_force_host_platform_device_count={devices}"
-            ).strip()
-            with tempfile.NamedTemporaryFile(suffix=".json",
-                                             delete=False) as tf:
-                out_path = tf.name
-            try:
-                proc = subprocess.run(
-                    [sys.executable, "-m", "benchmarks.dp_scaling",
-                     "--worker", "--out", out_path, "--config", config,
-                     "--batch", str(batch), "--devices", str(devices),
-                     "--iters", str(iters)],
-                    env=env, capture_output=True, text=True, timeout=1800)
-                if proc.returncode != 0:
-                    raise RuntimeError(
-                        f"dp_scaling worker ({config}, {devices}dev) "
-                        f"failed:\n{proc.stdout}\n{proc.stderr}")
-                with open(out_path) as f:
-                    us = json.load(f)["us_per_step"]
-            finally:
-                os.unlink(out_path)
+            us = _measure(config, batch, devices, iters)
             if base_us is None:
                 base_us = us["single"]
             for variant, t in sorted(us.items()):
@@ -146,12 +123,13 @@ def run(quick: bool = False, smoke: bool = False) -> None:
                 "us_per_step": us,
                 "speedup_vs_single_1dev":
                     {m: base_us / t for m, t in us.items()},
-                "bit_exact": True,  # parity-gated in the worker
+                "bit_exact": True,  # parity-gated before timing
             })
     payload = {
         "benchmark": "dp_scaling",
+        "device": jax.devices()[0].device_kind,
         "reducers": ["psum", "ring", "compress"],
-        "timing": "interleaved min-of-N (ABBA) per worker subprocess; "
+        "timing": "interleaved min-of-N (ABBA) per device count; "
                   "every reducer parity-gated bitwise against the "
                   "single-device step before timing",
         "note": "CPU host devices share one socket — relative reducer "
@@ -168,14 +146,11 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true", help="fewer timing iters")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny config, 1-2 devices (CI gate)")
-    ap.add_argument("--worker", action="store_true")
-    ap.add_argument("--out")
-    ap.add_argument("--config", default="tiny")
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args()
-    if args.worker:
-        _worker(args.out, args.config, args.batch, args.devices, args.iters)
-    else:
-        run(quick=args.quick, smoke=args.smoke)
+    # Host devices exist only if forced before JAX starts; the flag shapes
+    # the CPU platform alone, so an accelerator host keeps its own devices.
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={max(DEVICE_COUNTS)}"
+    ).strip()
+    run(quick=args.quick, smoke=args.smoke)

@@ -119,6 +119,7 @@ def forward_layers(
     conv_mode: str = "stream",
     dp_axis: str | None = None,
     dp_shards: int = 1,
+    x_fits_int8: bool = False,
 ) -> tuple[jax.Array, dict]:
     """Run a block's forward layers; cache everything backward needs.
 
@@ -141,8 +142,16 @@ def forward_layers(
     make IntegerDropout draw the global-batch mask and slice this
     shard's rows — see ``layers.dropout_forward``; no other layer
     samples, so nothing else needs them.
+
+    ``x_fits_int8`` states that ``x`` is a NITRO-ReLU activation whose
+    range ``activations.relu_fits_int8`` bounds (the caller knows this
+    statically).  The fused path then narrows it to int8 — same values —
+    so the kernels, forward and grad_W alike, take it as one limb
+    (``kernels.limbs``) instead of four.
     """
     cache: dict[str, Any] = {}
+    if fused and x_fits_int8:
+        x = x.astype(jnp.int8)
     if spec.kind == "conv":
         c_in = x.shape[-1]
         sf = scaling.conv_scale_factor(spec.kernel_size, c_in)

@@ -25,11 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 LANE = 128
 DEFAULT_BLOCK_ROWS = 8  # (8, 128) native int32 VREG tile
 
-# jax renamed TPUCompilerParams → CompilerParams; support both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 
 def integer_sgd_tile(w, g, gamma_inv, eta_inv):
     """One IntegerSGD step on in-register values — the shared epilogue body.
@@ -99,7 +94,7 @@ def integer_sgd_update(
         ],
         out_specs=pl.BlockSpec((br, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(wf.shape, w.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -58,8 +58,15 @@ from repro.core.scaling import pow2_split
 from repro.kernels.autotune.tiles import DEFAULT_TILES
 from repro.kernels.nitro_conv.ref import DEFAULT_BH, conv_geometry, rot180_swap
 from repro.kernels.integer_sgd.integer_sgd import integer_sgd_tile
+from repro.kernels.limbs import (
+    INT32_LIMBS,
+    limb_count,
+    limb_dot,
+    planes,
+    split_limbs,
+    stack_limbs,
+)
 from repro.kernels.nitro_matmul.nitro_matmul import (
-    _CompilerParams,
     _relu_bwd_tile,
     _relu_tile,
     _scale_tile,
@@ -68,6 +75,57 @@ from repro.kernels.nitro_matmul.nitro_matmul import (
 #: Filter-tile width (MXU lane dimension) — alias of the single definition
 #: in ``kernels.autotune.tiles.DEFAULT_TILES``.
 DEFAULT_BF = DEFAULT_TILES.bf
+
+_SUBLANE = 8    # int32 sublane tile: the row ring's width granularity
+_LANE = 128     # lane tile: channel and filter granularity
+_FLUSH_ROWS = 128  # accumulator rows per step of the update flush (K²C is
+                   # a multiple of K²·128)
+
+#: ``dot_general`` dimension numbers: patches @ w, and patchesᵀ @ g.
+_P_W = (((1,), (0,)), ((), ()))
+_PT_G = (((0,), (0,)), ((), ()))
+
+
+def _round_up(v: int, m: int) -> int:
+    return -(-v // m) * m
+
+
+def _width_geometry(w_sp: int, k: int) -> tuple[int, int]:
+    """``(w_out, ring_w)``: the band's output width rounded up to the
+    sublane tile, and the row ring's width (halo included), rounded up
+    likewise — Mosaic slices and reshapes the ring along that dimension.
+
+    Columns ``[W, w_out)`` see only zero padding and are sliced away.
+    """
+    w_out = _round_up(w_sp, _SUBLANE)
+    return w_out, _round_up(w_out + k - 1, _SUBLANE)
+
+
+def _pad_rows(x, h, h_pad, p, ring_w, c_pad):
+    """Zero-pad an (N,H,W,C) input into the ring layout as int32: the
+    halo plus band multiple in H, the halo plus sublane tile in W, the
+    channels to the lane tile — exact for integer conv."""
+    w_sp, c = x.shape[2], x.shape[3]
+    return jnp.pad(
+        x.astype(jnp.int32),
+        ((0, 0), (p, p + h_pad - h), (p, ring_w - w_sp - p), (0, c_pad - c)),
+    )
+
+
+def _pad_grad(g, h_pad, w_out, f_pad):
+    """Zero-pad an (N,H,W,F) gradient band source to the output layout."""
+    _, h, w_sp, f = g.shape
+    return jnp.pad(
+        g.astype(jnp.int32),
+        ((0, 0), (0, h_pad - h), (0, w_out - w_sp), (0, f_pad - f)),
+    )
+
+
+def _flat_weight(w, c_pad, f_pad):
+    """(K,K,C,F) → (K²·c_pad, f_pad), the patch block's row layout."""
+    k, _, c, f = w.shape
+    w = jnp.pad(w, ((0, 0), (0, 0), (0, c_pad - c), (0, f_pad - f)))
+    return w.reshape(k * k * c_pad, f_pad)
 
 
 def _load_band(x_hbm, rows_ref, sem, n, band_idx, band_rows: int):
@@ -83,50 +141,50 @@ def _form_patches(rows_ref, patches_ref, *, k: int, bh: int, w_out: int, c: int)
     """Implicit im2col: K² overlapping slices of the row ring → patch block.
 
     ``patches[(r·W + w), (ki·K + kj)·C + c] = rows[r + ki, w + kj, c]`` —
-    the ``core.layers.im2col`` layout, built from VMEM-resident rows.  The
-    patch block takes the scratch's dtype: int32 normally, int8 on the
-    int8-operand path (where the scratch is allocated int8 and the rows
-    are already int8 — a quarter of the patch VMEM footprint).
+    the ``core.layers.im2col`` layout (with C the lane-padded channel
+    count), built from VMEM-resident int32 rows.
     """
     for ki in range(k):
         for kj in range(k):
             seg = rows_ref[ki:ki + bh, kj:kj + w_out, :]
             patches_ref[:, (ki * k + kj) * c:(ki * k + kj + 1) * c] = (
-                seg.reshape(bh * w_out, c).astype(patches_ref.dtype)
+                seg.reshape(bh * w_out, c)
             )
 
 
 def _band_matmul(patches_ref, w_ref, *, bh: int, w_out: int, bf: int,
-                 int8_ops: bool = False):
-    """One MXU pass: (bh·W, K²C) @ (K²C, bf) → int32 (bh, W, bf).
+                 x_limbs: int):
+    """One band: (bh·W, K²C) @ (K²C, bf) → int32 (bh, W, bf).
 
-    ``int8_ops`` keeps both operands int8 (the MXU double-rate mode); the
-    ``preferred_element_type`` accumulator is int32 either way, so the
-    result is bit-identical.
+    The patch block is split into ``x_limbs`` int8 limbs (1 when the conv
+    input is int8, 4 otherwise) and ``w_ref`` carries the weight tile's
+    limb planes, so the dot is ``limb_dot``'s int8 MXU passes — bit-exact
+    with an int32 dot.
     """
-    w_tile = w_ref[...]
-    if not int8_ops:
-        w_tile = w_tile.astype(jnp.int32)
-    z = jax.lax.dot_general(
-        patches_ref[...], w_tile,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    z = limb_dot(split_limbs(patches_ref[...], x_limbs), planes(w_ref), _P_W)
     return z.reshape(bh, w_out, bf)
 
 
-def _maxpool_tile(a, *, bh: int, w_out: int):
-    """Fused 2×2 stride-2 max-pool epilogue on a (bh, W, bf) VMEM tile."""
+def _maxpool_tile(z, pool_ref, *, bh: int, w_out: int):
+    """Fused 2×2 stride-2 max-pool epilogue on a (bh, W, bf) tile.
+
+    The column pairs are read back from a VMEM scratch with stride-2
+    loads (the sublane dimension cannot be split in registers); the row
+    pairs split the untiled leading dimension.
+    """
     w2 = w_out // 2
-    a = a[:, : w2 * 2, :].reshape(bh, w2, 2, -1).max(axis=2)
+    pool_ref[...] = z
+    a = jnp.maximum(
+        pool_ref[:, pl.ds(0, w2, stride=2), :],
+        pool_ref[:, pl.ds(1, w2, stride=2), :],
+    )
     return a.reshape(bh // 2, 2, w2, -1).max(axis=1)
 
 
 def _stream_conv_kernel(
-    x_hbm, w_ref, out_ref, rows, patches, sem, *,
-    k, bh, w_out, c, bf,
+    x_hbm, w_ref, out_ref, rows, patches, sem, *pool_scratch,
+    k, bh, w_out, c, bf, x_limbs,
     sf_shift, sf_residual, alpha_inv, mu, apply_relu, pool, out_dtype,
-    int8_ops=False,
 ):
     """Activation-only streaming conv step (the inference plan's layer)."""
     n, band, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -137,18 +195,18 @@ def _stream_conv_kernel(
         _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
 
     z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf,
-                     int8_ops=int8_ops)
+                     x_limbs=x_limbs)
     z = _scale_tile(z, sf_shift, sf_residual)
     if apply_relu:
         z = _relu_tile(z, alpha_inv, mu)
     if pool:
-        z = _maxpool_tile(z, bh=bh, w_out=w_out)
+        z = _maxpool_tile(z, pool_scratch[0], bh=bh, w_out=w_out)
     out_ref[0] = z.astype(out_dtype)
 
 
 def _stream_conv_fwd_kernel(
     x_hbm, w_ref, a_ref, zstar_ref, rows, patches, sem, *,
-    k, bh, w_out, c, bf,
+    k, bh, w_out, c, bf, x_limbs,
     sf_shift, sf_residual, alpha_inv, mu, out_dtype,
 ):
     """Training-forward variant: ``(a, z_star)`` from one accumulation.
@@ -164,7 +222,8 @@ def _stream_conv_fwd_kernel(
         _load_band(x_hbm, rows, sem, n, band * bh, bh + k - 1)
         _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
 
-    z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf)
+    z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf,
+                     x_limbs=x_limbs)
     z_star = _scale_tile(z, sf_shift, sf_residual)
     zstar_ref[0] = z_star
     a_ref[0] = _relu_tile(z_star, alpha_inv, mu).astype(out_dtype)
@@ -172,16 +231,19 @@ def _stream_conv_fwd_kernel(
 
 def _grad_w_accumulate(
     x_hbm, g2d, out_ref, rows, patches, acc, sem, *,
-    k, bh, w_out, c, n_steps, flush=None,
+    k, bh, w_out, c, x_limbs, n_steps, flush=None,
 ):
     """Shared grad_w body: acc += patch_bandᵀ @ g2d per (image, band).
 
     Grid is ``(filter tile, image, band)`` — the filter tile is outermost so
     the (K²C, bf) VMEM accumulator runs over every image/band before its
-    single HBM write.  ``g2d`` is the (bh·W, bf) gradient band, already in
-    VMEM registers (masked by the caller on the fused path).  ``flush``
-    lets the caller transform the finished accumulator tile before the HBM
-    write (the IntegerSGD epilogue); ``None`` writes the raw gradient.
+    single HBM write.  ``g2d`` is the (bh·W, bf) int32 gradient band,
+    already in VMEM registers (masked by the caller on the fused path).
+    ``flush`` lets the caller transform the finished accumulator before
+    the HBM write (the IntegerSGD epilogue): it maps one row block of the
+    accumulator to the output rows; ``None`` writes the raw gradient.
+    The flush walks ``_FLUSH_ROWS`` rows at a time in a loop, so the
+    kernel's code stays small however many rows K²C has.
     """
     n, band = pl.program_id(1), pl.program_id(2)
     step = n * pl.num_programs(2) + band
@@ -192,32 +254,42 @@ def _grad_w_accumulate(
 
     _load_band(x_hbm, rows, sem, n, band * bh, bh + k - 1)
     _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
-    acc[...] += jax.lax.dot_general(
-        patches[...], g2d,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
+    acc[...] += limb_dot(
+        split_limbs(patches[...], x_limbs),
+        split_limbs(g2d, INT32_LIMBS),
+        _PT_G,
     )
 
     @pl.when(step == n_steps - 1)
     def _flush():
-        out_ref[...] = acc[...] if flush is None else flush(acc[...])
+        if flush is None:
+            out_ref[...] = acc[...]
+            return
+
+        def _rows(i, carry):
+            rows = pl.ds(pl.multiple_of(i * _FLUSH_ROWS, _FLUSH_ROWS),
+                         _FLUSH_ROWS)
+            out_ref[rows, :] = flush(rows, acc[rows, :])
+            return carry
+
+        jax.lax.fori_loop(0, acc.shape[0] // _FLUSH_ROWS, _rows, 0)
 
 
 def _stream_grad_w_kernel(
     x_hbm, g_ref, out_ref, rows, patches, acc, sem, *,
-    k, bh, w_out, c, bf, n_steps,
+    k, bh, w_out, c, bf, x_limbs, n_steps,
 ):
     """Conv weight gradient, plain δ (the ReLU backward already applied)."""
-    g2d = g_ref[0].reshape(bh * w_out, bf).astype(jnp.int32)
+    g2d = g_ref[0].reshape(bh * w_out, bf)
     _grad_w_accumulate(
         x_hbm, g2d, out_ref, rows, patches, acc, sem,
-        k=k, bh=bh, w_out=w_out, c=c, n_steps=n_steps,
+        k=k, bh=bh, w_out=w_out, c=c, x_limbs=x_limbs, n_steps=n_steps,
     )
 
 
 def _stream_grad_w_fused_kernel(
     x_hbm, g_ref, z_ref, out_ref, rows, patches, acc, sem, *,
-    k, bh, w_out, c, bf, n_steps, alpha_inv,
+    k, bh, w_out, c, bf, x_limbs, n_steps, alpha_inv,
 ):
     """Conv weight gradient with the fused NITRO-ReLU-bwd/STE prologue.
 
@@ -226,19 +298,19 @@ def _stream_grad_w_fused_kernel(
     this (bh·W, bf) register tile.
     """
     g2d = _relu_bwd_tile(
-        g_ref[0].reshape(bh * w_out, bf).astype(jnp.int32),
+        g_ref[0].reshape(bh * w_out, bf),
         z_ref[0].reshape(bh * w_out, bf),
         alpha_inv,
     )
     _grad_w_accumulate(
         x_hbm, g2d, out_ref, rows, patches, acc, sem,
-        k=k, bh=bh, w_out=w_out, c=c, n_steps=n_steps,
+        k=k, bh=bh, w_out=w_out, c=c, x_limbs=x_limbs, n_steps=n_steps,
     )
 
 
 def _stream_grad_w_opt_kernel(
     scalars_ref, x_hbm, g_ref, z_ref, w_ref, out_ref, rows, patches, acc,
-    sem, *, k, bh, w_out, c, bf, n_steps, alpha_inv,
+    sem, *, k, bh, w_out, c, bf, x_limbs, n_steps, alpha_inv,
 ):
     """Conv weight *update*: fused prologue + IntegerSGD flush epilogue.
 
@@ -248,15 +320,15 @@ def _stream_grad_w_opt_kernel(
     γ_inv/η_inv arrive in SMEM.
     """
     g2d = _relu_bwd_tile(
-        g_ref[0].reshape(bh * w_out, bf).astype(jnp.int32),
+        g_ref[0].reshape(bh * w_out, bf),
         z_ref[0].reshape(bh * w_out, bf),
         alpha_inv,
     )
     _grad_w_accumulate(
         x_hbm, g2d, out_ref, rows, patches, acc, sem,
-        k=k, bh=bh, w_out=w_out, c=c, n_steps=n_steps,
-        flush=lambda a: integer_sgd_tile(
-            w_ref[...], a, scalars_ref[0], scalars_ref[1]
+        k=k, bh=bh, w_out=w_out, c=c, x_limbs=x_limbs, n_steps=n_steps,
+        flush=lambda rows, a: integer_sgd_tile(
+            w_ref[rows, :], a, scalars_ref[0], scalars_ref[1]
         ),
     )
 
@@ -279,39 +351,57 @@ def _stream_grad_x_kernel(
     def _stage_band():
         _load_band(g_hbm, rows, sem, n, band * bh, bh + k - 1)
         _load_band(z_hbm, zrows, zsem, n, band * bh, bh + k - 1)
-        rows[...] = _relu_bwd_tile(
-            rows[...].astype(jnp.int32), zrows[...], alpha_inv
-        )
+        rows[...] = _relu_bwd_tile(rows[...], zrows[...], alpha_inv)
         _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
 
-    z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf)
-    out_ref[0] = z.astype(jnp.int32)
+    out_ref[0] = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf,
+                              x_limbs=INT32_LIMBS)
 
 
-def _pad_operands(x, w, bf, h_pad, p):
-    """Zero-pad input (halo + band multiple) and the filter dim — exact for
-    integer conv; garbage rows/filters are sliced away by the wrappers."""
-    n, h, w_sp, c = x.shape
-    k, f = w.shape[0], w.shape[-1]
-    xp = jnp.pad(x, ((0, 0), (p, p + h_pad - h), (p, p), (0, 0)))
-    f_pad = (-f) % bf
-    w_flat = w.reshape(k * k * c, f)
-    if f_pad:
-        w_flat = jnp.pad(w_flat, ((0, 0), (0, f_pad)))
-    return xp, w_flat, f + f_pad
+def _conv_layout(x_shape, k: int, bh: int, *, pool: bool):
+    """Every static size of one streaming conv call.
 
-
-def _conv_scratches(x, k, bh, w_sp, c, *, patch_dtype=jnp.int32):
-    """The kernel's VMEM working set: row ring, patch block, DMA semaphore.
-
-    ``patch_dtype=int8`` is the int8-operand path's patch block — 4× less
-    patch VMEM, feeding the MXU's double-rate int8 mode.
+    Returns ``(bh, h_pad, p, w_out, ring_w, c_pad)``: the band geometry
+    shared with the jnp oracle (``ref.conv_geometry``), the sublane-padded
+    widths (``_width_geometry``) and the lane-padded input channels.
     """
+    _, h, w_sp, c = x_shape
+    bh_, h_pad, p = conv_geometry(h, k, bh, pool=pool)
+    w_out, ring_w = _width_geometry(w_sp, k)
+    return bh_, h_pad, p, w_out, ring_w, _round_up(c, _LANE)
+
+
+def _filter_tiling(f: int, bf: int) -> tuple[int, int]:
+    """``(bf, f_pad)``: a lane-aligned filter tile and the padded count."""
+    bf_ = _round_up(min(bf, f), _LANE)
+    return bf_, _round_up(f, bf_)
+
+
+def _conv_scratches(k, bh, w_out, ring_w, c_pad):
+    """The kernel's VMEM working set: int32 row ring, int32 patch block,
+    DMA semaphore."""
     return [
-        pltpu.VMEM((bh + k - 1, w_sp + k - 1, c), x.dtype),
-        pltpu.VMEM((bh * w_sp, k * k * c), patch_dtype),
+        pltpu.VMEM((bh + k - 1, ring_w, c_pad), jnp.int32),
+        pltpu.VMEM((bh * w_out, k * k * c_pad), jnp.int32),
         pltpu.SemaphoreType.DMA,
     ]
+
+
+def _weight_spec(w_planes, bf, index_map):
+    """BlockSpec of one (L, K²C, bf) filter tile of the weight's limb planes."""
+    return pl.BlockSpec((w_planes.shape[0], w_planes.shape[1], bf), index_map)
+
+
+#: Scoped VMEM limit of the conv kernels.  The grad kernels at 512
+#: channels hold a (K²C, bf) accumulator, the double-buffered weight/output
+#: tiles and one (K²C, bf) partial product per limb pair — past Mosaic's
+#: 16 MiB default on v5e, well inside its 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+_ARBITRARY3 = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT_BYTES,
+)
 
 
 @functools.partial(
@@ -341,9 +431,10 @@ def stream_conv(
     (N,H//2,W//2,F) with ``pool=True``.  Bit-exact with the materialised
     im2col + ``nitro_matmul`` path (+ separate pool) on every shape.
 
-    ``operand_dtype='int8'`` keeps the VMEM row ring *and* the patch block
-    int8 and issues int8×int8→int32 MXU dots — both operands must already
-    be int8 (the dispatcher proves eligibility and narrows).
+    ``operand_dtype='int8'`` asserts both operands already *are* int8
+    (the dispatcher proves eligibility and narrows): one int8 MXU pass per
+    band.  Otherwise each operand is limb-split by its own dtype.  The row
+    ring is int32 either way; the limb count is fixed here, statically.
     """
     if operand_dtype == "int8" and not (
         x.dtype == jnp.int8 and w.dtype == jnp.int8
@@ -352,31 +443,36 @@ def stream_conv(
             f"operand_dtype='int8' requires int8 operands, got "
             f"{x.dtype}/{w.dtype} (the dispatcher narrows eligible inputs)"
         )
-    int8_ops = operand_dtype == "int8"
     n, h, w_sp, c = x.shape
     k, f = w.shape[0], w.shape[-1]
     if pool and (h < 2 or w_sp < 2):
         raise ValueError(f"2x2 pool epilogue needs H,W >= 2, got {h}x{w_sp}")
-    bh_, h_pad, p = conv_geometry(h, k, bh, pool=pool)
-    bf_ = min(bf, f)
-    xp, w_flat, f_pad = _pad_operands(x, w, bf_, h_pad, p)
+    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
+        x.shape, k, bh, pool=pool
+    )
+    bf_, f_pad = _filter_tiling(f, bf)
+    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
+    w_planes = stack_limbs(_flat_weight(w, c_pad, f_pad))
 
     shift, residual = pow2_split(sf)
     kernel = functools.partial(
         _stream_conv_kernel,
-        k=k, bh=bh_, w_out=w_sp, c=c, bf=bf_,
+        k=k, bh=bh_, w_out=w_out, c=c_pad, bf=bf_,
+        x_limbs=limb_count(x.dtype),
         sf_shift=shift, sf_residual=residual, alpha_inv=alpha_inv,
         mu=mu_int8(alpha_inv) if apply_relu else 0,
         apply_relu=apply_relu, pool=pool, out_dtype=out_dtype,
-        int8_ops=int8_ops,
     )
-    oh, ow = (bh_ // 2, w_sp // 2) if pool else (bh_, w_sp)
+    oh, ow = (bh_ // 2, w_out // 2) if pool else (bh_, w_out)
+    scratches = _conv_scratches(k, bh_, w_out, ring_w, c_pad)
+    if pool:
+        scratches.append(pltpu.VMEM((bh_, w_out, bf_), jnp.int32))
     out = pl.pallas_call(
         kernel,
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # rows DMA'd by the kernel
-            pl.BlockSpec((k * k * c, bf_), lambda ni, bi, fi: (0, fi)),
+            pl.BlockSpec(memory_space=pl.ANY),  # rows DMA'd by the kernel
+            _weight_spec(w_planes, bf_, lambda ni, bi, fi: (0, 0, fi)),
         ],
         out_specs=pl.BlockSpec(
             (1, oh, ow, bf_), lambda ni, bi, fi: (ni, bi, 0, fi)
@@ -384,16 +480,13 @@ def stream_conv(
         out_shape=jax.ShapeDtypeStruct(
             (n, (h_pad // bh_) * oh, ow, f_pad), out_dtype
         ),
-        scratch_shapes=_conv_scratches(
-            x, k, bh_, w_sp, c,
-            patch_dtype=jnp.int8 if int8_ops else jnp.int32,
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=scratches,
+        compiler_params=_ARBITRARY3,
         interpret=interpret,
-    )(xp, w_flat)
-    return out[:, : (h // 2 if pool else h), :, :f]
+    )(xp, w_planes)
+    if pool:
+        return out[:, : h // 2, : w_sp // 2, :f]
+    return out[:, :h, :w_sp, :f]
 
 
 @functools.partial(
@@ -414,43 +507,46 @@ def stream_conv_fwd(
     """Streaming *training* forward: ``(a, z_star)`` in one pass.
 
     The conv analogue of ``nitro_matmul_fwd`` — same two-output contract,
-    minus the HBM patch matrix on the input side.
+    minus the HBM patch matrix on the input side.  An int8 ``x`` (a
+    NITRO-ReLU activation narrowed by the caller) is one limb.
     """
     n, h, w_sp, c = x.shape
     k, f = w.shape[0], w.shape[-1]
-    bh_, h_pad, p = conv_geometry(h, k, bh, pool=False)
-    bf_ = min(bf, f)
-    xp, w_flat, f_pad = _pad_operands(x, w, bf_, h_pad, p)
+    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
+        x.shape, k, bh, pool=False
+    )
+    bf_, f_pad = _filter_tiling(f, bf)
+    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
+    w_planes = stack_limbs(_flat_weight(w, c_pad, f_pad))
 
     shift, residual = pow2_split(sf)
     kernel = functools.partial(
         _stream_conv_fwd_kernel,
-        k=k, bh=bh_, w_out=w_sp, c=c, bf=bf_,
+        k=k, bh=bh_, w_out=w_out, c=c_pad, bf=bf_,
+        x_limbs=limb_count(x.dtype),
         sf_shift=shift, sf_residual=residual, alpha_inv=alpha_inv,
         mu=mu_int8(alpha_inv), out_dtype=out_dtype,
     )
     out_spec = pl.BlockSpec(
-        (1, bh_, w_sp, bf_), lambda ni, bi, fi: (ni, bi, 0, fi)
+        (1, bh_, w_out, bf_), lambda ni, bi, fi: (ni, bi, 0, fi)
     )
     a, z_star = pl.pallas_call(
         kernel,
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((k * k * c, bf_), lambda ni, bi, fi: (0, fi)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            _weight_spec(w_planes, bf_, lambda ni, bi, fi: (0, 0, fi)),
         ],
         out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((n, h_pad, w_sp, f_pad), out_dtype),
-            jax.ShapeDtypeStruct((n, h_pad, w_sp, f_pad), jnp.int32),
+            jax.ShapeDtypeStruct((n, h_pad, w_out, f_pad), out_dtype),
+            jax.ShapeDtypeStruct((n, h_pad, w_out, f_pad), jnp.int32),
         ],
-        scratch_shapes=_conv_scratches(x, k, bh_, w_sp, c),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=_conv_scratches(k, bh_, w_out, ring_w, c_pad),
+        compiler_params=_ARBITRARY3,
         interpret=interpret,
-    )(xp, w_flat)
-    return a[:, :h, :, :f], z_star[:, :h, :, :f]
+    )(xp, w_planes)
+    return a[:, :h, :w_sp, :f], z_star[:, :h, :w_sp, :f]
 
 
 @functools.partial(
@@ -478,53 +574,48 @@ def stream_conv_grad_w(
     With ``z_star`` (same shape as ``grad_out``) the NITRO-ReLU-bwd/STE
     prologue masks each δ band in VMEM before the contraction — the fused
     backward path; without it the δ is consumed as-is (the caller already
-    applied the activation backward).
+    applied the activation backward).  Padded δ and z* are 0, and the
+    prologue maps (0, 0) → 0, so padding contributes nothing.
     """
     n, h, w_sp, c = x.shape
     k = kernel_size
     f = grad_out.shape[-1]
-    bh_, h_pad, p = conv_geometry(h, k, bh, pool=False)
-    bf_ = min(bf, f)
-    xp = jnp.pad(x, ((0, 0), (p, p + h_pad - h), (p, p), (0, 0)))
-    f_pad = (-f) % bf_
-    g_pad = ((0, 0), (0, h_pad - h), (0, 0), (0, f_pad))
-    gp = jnp.pad(grad_out, g_pad)
+    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
+        x.shape, k, bh, pool=False
+    )
+    bf_, f_pad = _filter_tiling(f, bf)
+    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
 
     n_bands = h_pad // bh_
     g_spec = pl.BlockSpec(
-        (1, bh_, w_sp, bf_), lambda fi, ni, bi: (ni, bi, 0, fi)
+        (1, bh_, w_out, bf_), lambda fi, ni, bi: (ni, bi, 0, fi)
     )
-    operands = [xp, gp]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY), g_spec]
+    operands = [xp, _pad_grad(grad_out, h_pad, w_out, f_pad)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY), g_spec]
+    geom = dict(k=k, bh=bh_, w_out=w_out, c=c_pad, bf=bf_,
+                x_limbs=limb_count(x.dtype), n_steps=n * n_bands)
     if z_star is None:
-        kernel = functools.partial(
-            _stream_grad_w_kernel,
-            k=k, bh=bh_, w_out=w_sp, c=c, bf=bf_, n_steps=n * n_bands,
-        )
+        kernel = functools.partial(_stream_grad_w_kernel, **geom)
     else:
         kernel = functools.partial(
-            _stream_grad_w_fused_kernel,
-            k=k, bh=bh_, w_out=w_sp, c=c, bf=bf_, n_steps=n * n_bands,
-            alpha_inv=alpha_inv,
+            _stream_grad_w_fused_kernel, alpha_inv=alpha_inv, **geom
         )
-        operands.append(jnp.pad(z_star.astype(jnp.int32), g_pad))
+        operands.append(_pad_grad(z_star, h_pad, w_out, f_pad))
         in_specs.append(g_spec)
     out = pl.pallas_call(
         kernel,
-        grid=((f + f_pad) // bf_, n, n_bands),
+        grid=(f_pad // bf_, n, n_bands),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((k * k * c, bf_), lambda fi, ni, bi: (0, fi)),
-        out_shape=jax.ShapeDtypeStruct((k * k * c, f + f_pad), jnp.int32),
-        scratch_shapes=_conv_scratches(x, k, bh_, w_sp, c)[:2] + [
-            pltpu.VMEM((k * k * c, bf_), jnp.int32),
+        out_specs=pl.BlockSpec((k * k * c_pad, bf_), lambda fi, ni, bi: (0, fi)),
+        out_shape=jax.ShapeDtypeStruct((k * k * c_pad, f_pad), jnp.int32),
+        scratch_shapes=_conv_scratches(k, bh_, w_out, ring_w, c_pad)[:2] + [
+            pltpu.VMEM((k * k * c_pad, bf_), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_ARBITRARY3,
         interpret=interpret,
     )(*operands)
-    return out[:, :f].reshape(k, k, c, f)
+    return out.reshape(k, k, c_pad, f_pad)[:, :, :c, :f]
 
 
 @functools.partial(
@@ -552,29 +643,26 @@ def stream_conv_grad_w_opt(
     ``stream_conv_grad_w`` — bitwise-identical grad_W by construction —
     then the flush applies ``W − (⌊acc/γ_inv⌋ + ⌊W/η_inv⌋)`` per filter
     tile.  ``w`` rides in VMEM flattened to the (K²C, bf) output layout.
-    Padded filter columns have acc = 0 and w = 0 → W′ = 0, sliced away.
+    Padded channels/filters have acc = 0 and w = 0 → W′ = 0, sliced away.
     """
     n, h, w_sp, c = x.shape
     k = kernel_size
     f = grad_out.shape[-1]
     assert w.shape == (k, k, c, f), f"w shape {w.shape} != {(k, k, c, f)}"
-    bh_, h_pad, p = conv_geometry(h, k, bh, pool=False)
-    bf_ = min(bf, f)
-    xp = jnp.pad(x, ((0, 0), (p, p + h_pad - h), (p, p), (0, 0)))
-    f_pad = (-f) % bf_
-    g_pad = ((0, 0), (0, h_pad - h), (0, 0), (0, f_pad))
-    gp = jnp.pad(grad_out, g_pad)
-    zp = jnp.pad(z_star.astype(jnp.int32), g_pad)
-    w_flat = jnp.pad(w.reshape(k * k * c, f), ((0, 0), (0, f_pad)))
+    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
+        x.shape, k, bh, pool=False
+    )
+    bf_, f_pad = _filter_tiling(f, bf)
 
     n_bands = h_pad // bh_
     g_spec = pl.BlockSpec(
-        (1, bh_, w_sp, bf_), lambda fi, ni, bi: (ni, bi, 0, fi)
+        (1, bh_, w_out, bf_), lambda fi, ni, bi: (ni, bi, 0, fi)
     )
-    w_spec = pl.BlockSpec((k * k * c, bf_), lambda fi, ni, bi: (0, fi))
+    w_spec = pl.BlockSpec((k * k * c_pad, bf_), lambda fi, ni, bi: (0, fi))
     kernel = functools.partial(
         _stream_grad_w_opt_kernel,
-        k=k, bh=bh_, w_out=w_sp, c=c, bf=bf_, n_steps=n * n_bands,
+        k=k, bh=bh_, w_out=w_out, c=c_pad, bf=bf_,
+        x_limbs=limb_count(x.dtype), n_steps=n * n_bands,
         alpha_inv=alpha_inv,
     )
     scalars = jnp.stack(
@@ -582,26 +670,30 @@ def stream_conv_grad_w_opt(
     )
     out = pl.pallas_call(
         kernel,
-        grid=((f + f_pad) // bf_, n, n_bands),
+        grid=(f_pad // bf_, n, n_bands),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             g_spec,
             g_spec,
             w_spec,
         ],
         out_specs=w_spec,
-        out_shape=jax.ShapeDtypeStruct((k * k * c, f + f_pad), jnp.int32),
-        scratch_shapes=_conv_scratches(x, k, bh_, w_sp, c)[:2] + [
-            pltpu.VMEM((k * k * c, bf_), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((k * k * c_pad, f_pad), jnp.int32),
+        scratch_shapes=_conv_scratches(k, bh_, w_out, ring_w, c_pad)[:2] + [
+            pltpu.VMEM((k * k * c_pad, bf_), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_ARBITRARY3,
         interpret=interpret,
-    )(scalars, xp, gp, zp, w_flat)
-    return out[:, :f].reshape(k, k, c, f)
+    )(
+        scalars,
+        _pad_rows(x, h, h_pad, p, ring_w, c_pad),
+        _pad_grad(grad_out, h_pad, w_out, f_pad),
+        _pad_grad(z_star, h_pad, w_out, f_pad),
+        _flat_weight(w, c_pad, f_pad),
+    )
+    return out.reshape(k, k, c_pad, f_pad)[:, :, :c, :f]
 
 
 @functools.partial(
@@ -625,6 +717,7 @@ def stream_conv_grad_x(
     kernel, streamed exactly like the forward conv — δ *and* z* rows are
     DMA'd per band, masked in the VMEM ring, and the patch block is built
     from the masked rows.  The post-ReLU-bwd δ tensor never exists in HBM.
+    The masked δ is a full int32 operand: four limbs.
 
     (The unfused input gradient stays ``stream_conv(δ_masked, rot180_swap(w),
     sf=1, apply_relu=False)`` — this kernel is that conv plus the prologue.)
@@ -632,43 +725,40 @@ def stream_conv_grad_x(
     n, h, w_sp, f = delta.shape
     k, c = w.shape[0], w.shape[2]
     assert delta.shape == z_star.shape, "delta/z_star shape mismatch"
-    w_rot = rot180_swap(w)  # (K, K, F, C)
-    bh_, h_pad, p = conv_geometry(h, k, bh, pool=False)
-    bc = min(bf, c)
-    dp, w_flat, c_pad = _pad_operands(
-        delta.astype(jnp.int32), w_rot, bc, h_pad, p
+    bh_, h_pad, p, w_out, ring_w, f_in = _conv_layout(
+        delta.shape, k, bh, pool=False
     )
-    zp = jnp.pad(
-        z_star.astype(jnp.int32),
-        ((0, 0), (p, p + h_pad - h), (p, p), (0, 0)),
-    )
+    bc, c_pad = _filter_tiling(c, bf)
+    w_planes = stack_limbs(_flat_weight(rot180_swap(w), f_in, c_pad))
     kernel = functools.partial(
         _stream_grad_x_kernel,
-        k=k, bh=bh_, w_out=w_sp, c=f, bf=bc, alpha_inv=alpha_inv,
+        k=k, bh=bh_, w_out=w_out, c=f_in, bf=bc, alpha_inv=alpha_inv,
     )
-    ring = pltpu.VMEM((bh_ + k - 1, w_sp + k - 1, f), jnp.int32)
+    ring = pltpu.VMEM((bh_ + k - 1, ring_w, f_in), jnp.int32)
     out = pl.pallas_call(
         kernel,
         grid=(n, h_pad // bh_, c_pad // bc),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # δ rows, DMA'd in-kernel
-            pl.BlockSpec(memory_space=pltpu.ANY),  # z* rows, ditto
-            pl.BlockSpec((k * k * f, bc), lambda ni, bi, fi: (0, fi)),
+            pl.BlockSpec(memory_space=pl.ANY),  # δ rows, DMA'd in-kernel
+            pl.BlockSpec(memory_space=pl.ANY),  # z* rows, ditto
+            _weight_spec(w_planes, bc, lambda ni, bi, fi: (0, 0, fi)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bh_, w_sp, bc), lambda ni, bi, fi: (ni, bi, 0, fi)
+            (1, bh_, w_out, bc), lambda ni, bi, fi: (ni, bi, 0, fi)
         ),
-        out_shape=jax.ShapeDtypeStruct((n, h_pad, w_sp, c_pad), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n, h_pad, w_out, c_pad), jnp.int32),
         scratch_shapes=[
             ring,                                       # masked δ row ring
             ring,                                       # z* row ring
-            pltpu.VMEM((bh_ * w_sp, k * k * f), jnp.int32),
+            pltpu.VMEM((bh_ * w_out, k * k * f_in), jnp.int32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_ARBITRARY3,
         interpret=interpret,
-    )(dp, zp, w_flat)
-    return out[:, :h, :, :c]
+    )(
+        _pad_rows(delta, h, h_pad, p, ring_w, f_in),
+        _pad_rows(z_star, h, h_pad, p, ring_w, f_in),
+        w_planes,
+    )
+    return out[:, :h, :w_sp, :c]

@@ -2,7 +2,7 @@
 
 Computes, in one pass over VMEM tiles::
 
-    z   = x @ w                      (int8/int32 inputs, int32 MXU accumulate)
+    z   = x @ w                      (int8 limb dots, int32 accumulate)
     z*  = ⌊z / SF⌋                   (NITRO Scaling Layer)
     out = NITRO-ReLU(z*)             (optional, fused on the VPU)
 
@@ -14,9 +14,11 @@ accumulator and writes only the int8 activation back to HBM:
 
     HBM bytes per layer:  unfused  M·N·(4+4+4+1)   →   fused  M·N·1 (+in/w)
 
-TPU adaptation notes (DESIGN.md §2):
-  * tiles are 128-aligned for the MXU systolic array; int8×int8→int32 is
-    the MXU's double-rate integer mode (394 TOP/s on v5e vs 197 TF/s bf16);
+TPU adaptation notes:
+  * tiles are 128-aligned for the MXU systolic array, which multiplies
+    int8×int8→int32 and has no int32 mode: every dot is ``kernels.limbs``'
+    limb split (one MXU pass for int8·int8, four for int8·int32, ten for
+    int32·int32), exact mod 2³²;
   * ⌊z/SF⌋ is split as SF = residual·2^shift — the 2^shift part is an
     arithmetic right shift (exact floor semantics for two's-complement),
     the odd residual is one VPU integer divide;
@@ -44,10 +46,13 @@ from repro.core.activations import mu_int8, nitro_relu_backward
 from repro.core.scaling import pow2_split
 from repro.kernels.autotune.tiles import DEFAULT_TILES
 from repro.kernels.integer_sgd.integer_sgd import integer_sgd_tile
-
-# jax renamed TPUCompilerParams → CompilerParams; support both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
+from repro.kernels.limbs import (
+    INT32_LIMBS,
+    limb_count,
+    limb_dot,
+    planes,
+    split_limbs,
+    stack_limbs,
 )
 
 # MXU-native tile sizes — aliases of the single definition in
@@ -92,29 +97,32 @@ def _relu_bwd_tile(g, z, alpha_inv: int):
     return nitro_relu_backward(z, g, alpha_inv)
 
 
-def _accumulate_tile(x_ref, w_ref, acc_ref, *, int8_ops: bool = False):
+#: ``dot_general`` dimension numbers of the three matmul shapes.
+_X_W = (((1,), (0,)), ((), ()))     # x @ w
+_XT_G = (((0,), (0,)), ((), ()))    # xᵀ @ g   (grad_W)
+_G_WT = (((1,), (1,)), ((), ()))    # g @ wᵀ   (grad_x)
+
+
+def _own_limbs(x):
+    """Split a VMEM tile by its own dtype (int8 → 1 limb, int32 → 4)."""
+    return split_limbs(x, limb_count(x.dtype))
+
+
+def _accumulate_tile(x_ref, w_ref, acc_ref):
     """Zero the VMEM accumulator at k == 0, then MXU-accumulate one
     (bm, bk)·(bk, bn) partial product — int32 accumulation.
 
-    ``int8_ops=True`` is the int8-operand MXU fast path: the VMEM tiles
-    stay int8 and the dot issues the MXU's double-rate
-    ``int8×int8→int32`` mode via ``preferred_element_type`` — bit-exact
-    with the lifted int32 dot, since the accumulator is int32 either way.
+    ``w_ref`` holds the weight tile as int8 limb planes (split once in
+    XLA by ``stack_limbs``); the x tile is split here by its dtype.  The
+    dot is ``limb_dot``'s int8×int8→int32 MXU passes — one when both
+    operands are int8 — bit-exact with an int32 dot.
     """
 
     @pl.when(pl.program_id(2) == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x, w = x_ref[...], w_ref[...]
-    if not int8_ops:
-        x, w = x.astype(jnp.int32), w.astype(jnp.int32)
-    acc_ref[...] += jax.lax.dot_general(
-        x,
-        w,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += limb_dot(_own_limbs(x_ref[...]), planes(w_ref), _X_W)
 
 
 def _nitro_matmul_kernel(
@@ -130,10 +138,9 @@ def _nitro_matmul_kernel(
     mu: int,
     apply_relu: bool,
     out_dtype,
-    int8_ops: bool = False,
 ):
     """One (bm, bn) output tile; accumulates over the K grid dimension."""
-    _accumulate_tile(x_ref, w_ref, acc_ref, int8_ops=int8_ops)
+    _accumulate_tile(x_ref, w_ref, acc_ref)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
@@ -201,11 +208,12 @@ def _launch(kernel, x, w, tiles, grid, *, out_dtypes, interpret):
     """
     bm_, bn_, bk_ = tiles
     gm, gn, gk = grid
+    w = stack_limbs(w)
     out_specs = [
         pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)) for _ in out_dtypes
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((x.shape[0], w.shape[1]), dt) for dt in out_dtypes
+        jax.ShapeDtypeStruct((x.shape[0], w.shape[2]), dt) for dt in out_dtypes
     ]
     single = len(out_dtypes) == 1
     return pl.pallas_call(
@@ -213,12 +221,14 @@ def _launch(kernel, x, w, tiles, grid, *, out_dtypes, interpret):
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk_, bn_), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec(
+                (w.shape[0], bk_, bn_), lambda i, j, kk: (0, kk, j)
+            ),
         ],
         out_specs=out_specs[0] if single else out_specs,
         out_shape=out_shape[0] if single else out_shape,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -251,10 +261,11 @@ def nitro_matmul(
     Pads every dimension up to its tile multiple (zero padding is exact for
     integer matmul) and slices the result back.
 
-    ``operand_dtype='int8'`` keeps the VMEM operand tiles int8 and issues
-    ``int8×int8→int32`` MXU dots (the double-rate mode); both operands
-    must already *be* int8 — narrowing/eligibility proofs live in the
-    dispatcher (``ops.fused_matmul``).  Bit-exact with the int32 path.
+    ``operand_dtype='int8'`` asserts both operands already *are* int8 —
+    one ``int8×int8→int32`` MXU pass per tile; narrowing/eligibility
+    proofs live in the dispatcher (``ops.fused_matmul``).  ``'int32'``
+    splits each operand by its own dtype (``kernels.limbs``).  Bit-exact
+    either way.
     """
     if operand_dtype == "int8" and not (
         x.dtype == jnp.int8 and w.dtype == jnp.int8
@@ -276,7 +287,6 @@ def nitro_matmul(
         mu=mu_int8(alpha_inv) if apply_relu else 0,
         apply_relu=apply_relu,
         out_dtype=out_dtype,
-        int8_ops=(operand_dtype == "int8"),
     )
     out = _launch(
         kernel, x, w, (bm_, bn_, bk_), (gm, gn, gk),
@@ -350,10 +360,8 @@ def _nitro_grad_w_kernel(x_ref, g_ref, z_ref, out_ref, acc_ref, *, n_k, alpha_in
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     g = _relu_bwd_tile(g_ref[...].astype(jnp.int32), z_ref[...], alpha_inv)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.int32), g,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
+    acc_ref[...] += limb_dot(
+        _own_limbs(x_ref[...]), split_limbs(g, INT32_LIMBS), _XT_G
     )
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -373,10 +381,8 @@ def _nitro_grad_x_kernel(g_ref, z_ref, w_ref, out_ref, acc_ref, *, n_k, alpha_in
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     g = _relu_bwd_tile(g_ref[...].astype(jnp.int32), z_ref[...], alpha_inv)
-    acc_ref[...] += jax.lax.dot_general(
-        g, w_ref[...].astype(jnp.int32),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
+    acc_ref[...] += limb_dot(
+        split_limbs(g, INT32_LIMBS), planes(w_ref), _G_WT
     )
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -433,7 +439,7 @@ def nitro_matmul_grad_w(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[1], delta.shape[1]), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -458,10 +464,8 @@ def _nitro_grad_w_opt_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     g = _relu_bwd_tile(g_ref[...].astype(jnp.int32), z_ref[...], alpha_inv)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.int32), g,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
+    acc_ref[...] += limb_dot(
+        _own_limbs(x_ref[...]), split_limbs(g, INT32_LIMBS), _XT_G
     )
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -532,7 +536,7 @@ def nitro_matmul_grad_w_opt(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct(w.shape, jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -577,18 +581,21 @@ def nitro_matmul_grad_x(
     kernel = functools.partial(
         _nitro_grad_x_kernel, n_k=gk, alpha_inv=alpha_inv
     )
+    w = stack_limbs(w)
     out = pl.pallas_call(
         kernel,
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn_, bk_), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec(
+                (w.shape[0], bn_, bk_), lambda i, j, kk: (0, j, kk)
+            ),
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((delta.shape[0], w.shape[0]), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((delta.shape[0], w.shape[1]), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

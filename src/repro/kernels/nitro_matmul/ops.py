@@ -52,14 +52,15 @@ from repro.kernels.nitro_matmul.ref import (
 BACKENDS = ("auto", "pallas", "interpret", "reference")
 
 #: Operand-dtype policy for the MXU dots (inference entry points):
-#:   * ``'auto'``  — int8 operands stay int8 (the double-rate MXU mode)
-#:                   whenever *both* operands already are; anything else
-#:                   lifts to int32.  Never changes results.
+#:   * ``'auto'``  — int8 operands stay int8 (one MXU pass) whenever
+#:                   *both* operands already are; anything else is
+#:                   limb-split by its dtype.  Never changes results.
 #:   * ``'int8'``  — force the int8 path: int8 operands pass through;
 #:                   concrete non-int8 operands are guarded (telemetry
 #:                   ``bit_width`` ≤ 7) and narrowed; traced non-int8
 #:                   operands raise.
-#:   * ``'int32'`` — the escape hatch: always lift (historical path).
+#:   * ``'int32'`` — split each operand by its own dtype
+#:                   (``kernels.limbs``: int8 one limb, wider four).
 OPERAND_DTYPES = ("auto", "int8", "int32")
 
 

@@ -16,19 +16,11 @@ from __future__ import annotations
 import jax
 
 
-# jax < 0.5 has neither jax.sharding.AxisType nor make_mesh(axis_types=…);
-# newer jax wants the explicit Auto axis type.  One compat entry point.
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
 def make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh`` with Auto axis types when supported."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of the Auto type."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -118,6 +118,9 @@ def _build_registry(args, metrics=None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vgg8b")
     ap.add_argument("--scale", type=float, default=0.125)

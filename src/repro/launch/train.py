@@ -329,11 +329,21 @@ def main():
                     help="tile-cache JSON path (default: tile_cache.json "
                          "next to the checkpoints)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.num_devices > 1 and jax.device_count() < args.num_devices:
+        if jax.default_backend() != "cpu":
+            # An accelerator host has the chips it has; a second process
+            # could not reach them anyway (one process per chip).
+            raise SystemExit(
+                f"--num-devices {args.num_devices}: this "
+                f"{jax.default_backend()} host has only "
+                f"{jax.device_count()} devices")
         # XLA only honours forced host devices before backend init — too
         # late in this process (device_count() just initialised it), so
-        # re-exec ourselves with the flag set.
+        # re-exec ourselves with the flag set (CPU only).
         if os.environ.get("_REPRO_DP_REEXEC"):
             raise SystemExit(
                 f"--num-devices {args.num_devices}: still only "

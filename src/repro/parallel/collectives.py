@@ -29,16 +29,9 @@ import jax.numpy as jnp
 
 
 def axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size where available (jax ≥ 0.5); psum(1) fallback.
-
-    Public version-compat shim — the ring schedules below and
-    ``pipeline.py`` need the named-axis extent as a *static* int (it
-    determines trip counts and permutations); use this, not jax.lax
-    directly.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
+    """The named-axis extent as a *static* int — the ring schedules below
+    and ``pipeline.py`` need it for trip counts and permutations."""
+    return jax.lax.axis_size(axis_name)
 
 
 def _ring_perm(n: int):

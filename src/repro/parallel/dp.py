@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import les
@@ -182,7 +181,7 @@ def dp_train_step(
     Bitwise identical, so cross-device-count trajectory identity holds
     with it on or off (test-enforced).
 
-    ``check_rep=False``: the ring reducer is built from ``ppermute``,
+    ``check_vma=False``: the ring reducer is built from ``ppermute``,
     whose per-device results shard_map cannot prove replicated (they are
     — by the all-gather's construction; the tests prove it bitwise).
     """
@@ -230,12 +229,12 @@ def dp_train_step(
             return new_state, metrics, telem
         return new_state, metrics
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _body,
         mesh=mesh,
         in_specs=(P(), batch_spec, batch_spec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return sharded(state, x, labels, key)
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 import itertools
 
 import jax
+from jax.extend import core as jex_core
 import numpy as np
 import pytest
 
@@ -84,9 +85,9 @@ def iter_eqns(jaxpr, *, skip_pallas: bool = False):
         for param in eqn.params.values():
             items = param if isinstance(param, (tuple, list)) else [param]
             for item in items:
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, jex_core.ClosedJaxpr):
                     yield from iter_eqns(item.jaxpr, skip_pallas=skip_pallas)
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, jex_core.Jaxpr):
                     yield from iter_eqns(item, skip_pallas=skip_pallas)
 
 

@@ -1,7 +1,6 @@
 """Property-based tests of the NITRO-ReLU paper identities (§3.2).
 
-Hypothesis-driven (through the ``tests/_compat`` shim when the real
-package is absent): ``segment_means`` / ``mu_int8`` / ``nitro_relu`` /
+Hypothesis-driven: ``segment_means`` / ``mu_int8`` / ``nitro_relu`` /
 ``nitro_relu_backward`` must satisfy their defining piecewise formulas
 across the ``alpha_inv`` range and int8/int32 carrying dtypes, and
 ``check_alpha_inv`` must enforce its ValueError contract.

@@ -18,10 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _gradcheck import (
+from _gradcheck import (  # noqa: F401 — kernel_backend is a fixture
     assert_bitwise_equal,
     assert_jaxpr_integer_only,
     collect_aval_shapes,
+    kernel_backend,
 )
 from repro.configs import paper
 from repro.core import activations, layers, les, model as M, scaling
@@ -536,3 +537,91 @@ class TestPlanStream:
         np.testing.assert_array_equal(
             np.asarray(jnp.max(win, axis=3)), np.asarray(out)
         )
+
+
+# ---------------------------------------------------------------------------
+# Limb-split conv dots at the int32 edges, odd widths and C = 3
+# ---------------------------------------------------------------------------
+
+_I32 = (-(2**31), 2**31 - 1)
+_CONV_LIMB_PATTERNS = {
+    "int32_extremes": [_I32[0], _I32[1], -1, 0, 1],
+    "wrapping_sums": [2**30, -(2**30), 2**16 + 1, -128, 127],
+    "random_words": None,
+}
+
+
+def _edge_tensor(pattern, shape, seed):
+    rng = np.random.default_rng(seed)
+    vals = _CONV_LIMB_PATTERNS[pattern]
+    if vals is None:
+        return jnp.asarray(
+            rng.integers(_I32[0], _I32[1] + 1, shape, dtype=np.int64),
+            jnp.int32)
+    return jnp.asarray(rng.choice(vals, shape), jnp.int32)
+
+
+def _wrap32(a):
+    return ((a + 2**31) % 2**32 - 2**31).astype(np.int32)
+
+
+def _np_patches(x, k):
+    """(N,H,W,C) → (N,H,W,K²·C) 'same' patches in int64, im2col layout."""
+    p = k // 2
+    xp = np.pad(np.asarray(x, np.int64), ((0, 0), (p, p), (p, p), (0, 0)))
+    n, h, w, _ = x.shape
+    return np.concatenate(
+        [xp[:, ki:ki + h, kj:kj + w, :] for ki in range(k) for kj in range(k)],
+        axis=-1)
+
+
+# (N, H, W, C, F): W never a multiple of 8, the 3-channel input layer,
+# and a filter count past one 128-lane tile.
+_LIMB_CONV_SHAPES = [(2, 5, 7, 3, 8), (1, 6, 9, 5, 4), (1, 4, 3, 3, 130)]
+
+
+class TestLimbConv:
+    @pytest.mark.parametrize("pattern", list(_CONV_LIMB_PATTERNS))
+    @pytest.mark.parametrize("shape", _LIMB_CONV_SHAPES,
+                             ids=lambda s: "x".join(map(str, s)))
+    def test_conv_grads_wrap_like_int32(self, kernel_backend, pattern,
+                                        shape):
+        """Both conv gradients on full-range int32 operands equal the
+        mod-2³² int64 ground truth on every backend (z* = 0: the ReLU
+        derivative is the identity)."""
+        n, h, w_sp, c, f = shape
+        k = 3
+        x = _edge_tensor(pattern, (n, h, w_sp, c), seed=1)
+        delta = _edge_tensor(pattern, (n, h, w_sp, f), seed=2)
+        w = _edge_tensor(pattern, (k, k, c, f), seed=3)
+        z = jnp.zeros_like(delta)
+
+        got_w = conv_grad_w(x, delta, kernel_size=k, z_star=z,
+                            backend=kernel_backend)
+        patches = _np_patches(x, k).reshape(-1, k * k * c)
+        want_w = patches.T @ np.asarray(delta, np.int64).reshape(-1, f)
+        np.testing.assert_array_equal(
+            np.asarray(got_w), _wrap32(want_w).reshape(k, k, c, f))
+
+        got_x = conv_grad_x(delta, w, z_star=z, backend=kernel_backend)
+        dp = np.pad(np.asarray(delta, np.int64),
+                    ((0, 0), (1, 1), (1, 1), (0, 0)))
+        want_x = sum(
+            dp[:, k - 1 - ki:k - 1 - ki + h, k - 1 - kj:k - 1 - kj + w_sp, :]
+            @ np.asarray(w, np.int64)[ki, kj].T
+            for ki in range(k) for kj in range(k))
+        np.testing.assert_array_equal(np.asarray(got_x), _wrap32(want_x))
+
+    @pytest.mark.parametrize("pattern", ["int32_extremes", "wrapping_sums"])
+    @pytest.mark.parametrize("shape", _LIMB_CONV_SHAPES[:2],
+                             ids=lambda s: "x".join(map(str, s)))
+    def test_fused_forward_wraps_like_reference(self, pattern, shape):
+        """The training forward's four-limb input path (int32 x) against
+        the reference backend, weights at the int32 edges."""
+        n, h, w_sp, c, f = shape
+        x = _edge_tensor(pattern, (n, h, w_sp, c), seed=4)
+        w = _edge_tensor(pattern, (3, 3, c, f), seed=5)
+        sf = conv_scale_factor(3, c)
+        got = fused_conv_fwd(x, w, sf=sf, backend="interpret")
+        want = fused_conv_fwd(x, w, sf=sf, backend="reference")
+        assert_bitwise_equal(got, want)

@@ -25,6 +25,7 @@ from __future__ import annotations
 import warnings
 
 import jax
+from jax.extend import core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -164,7 +165,7 @@ class TestDispatcherContract:
                 for param in e.params.values():
                     items = param if isinstance(param, (tuple, list)) else [param]
                     for it in items:
-                        if isinstance(it, jax.core.ClosedJaxpr):
+                        if isinstance(it, jex_core.ClosedJaxpr):
                             out |= all_prims(it.jaxpr)
             return out
 

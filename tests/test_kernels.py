@@ -8,8 +8,9 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from _gradcheck import assert_bitwise_equal
+from _gradcheck import assert_bitwise_equal, kernel_backend  # noqa: F401
 from repro.core.scaling import conv_scale_factor, linear_scale_factor
+from repro.kernels.autotune.tiles import TileConfig
 from repro.kernels.integer_sgd.integer_sgd import integer_sgd_update
 from repro.kernels.integer_sgd.ref import integer_sgd_ref
 from repro.kernels.nitro_matmul import ops
@@ -208,3 +209,102 @@ class TestIntegerSGDKernel:
         b = integer_sgd_update(w, g, jnp.int32(1536), jnp.int32(3000), interpret=True)
         assert int(a[0]) == 9000 - 100 - 3
         assert int(b[0]) == 9000 - 33 - 3
+
+
+# ---------------------------------------------------------------------------
+# Limb-split dots: int32 products from int8 MXU passes, exact mod 2³²
+# ---------------------------------------------------------------------------
+
+INT32_MIN, INT32_MAX = -(2**31), 2**31 - 1
+
+# Fill patterns that stress the limb algebra: the int32 extremes (every
+# digit and every carry at its limit), the int8 edges, values whose
+# products and sums wrap around int32, and plain random words.
+LIMB_PATTERNS = {
+    "int32_extremes": [INT32_MIN, INT32_MAX, -1, 0, 1],
+    "int8_edges": [-128, 127, -127, 0, -1],
+    "wrapping_sums": [2**30, -(2**30), 2**16 + 1, 65535, -65536],
+    "random_words": None,
+}
+
+
+def _limb_operand(pattern, shape, dtype, seed):
+    rng = np.random.default_rng(seed)
+    if dtype == jnp.int8:
+        vals = rng.integers(-128, 128, shape)
+        vals.flat[0], vals.flat[-1] = -128, 127
+    elif LIMB_PATTERNS[pattern] is None:
+        vals = rng.integers(INT32_MIN, INT32_MAX + 1, shape, dtype=np.int64)
+    else:
+        vals = rng.choice(LIMB_PATTERNS[pattern], shape)
+    return jnp.asarray(vals, dtype)
+
+
+def _wrapped_dot(a, b, dims):
+    """Ground truth: the int64 product reduced mod 2³² to int32."""
+    prod = np.tensordot(np.asarray(a, np.int64), np.asarray(b, np.int64),
+                        axes=dims[0])
+    return ((prod + 2**31) % 2**32 - 2**31).astype(np.int32)
+
+
+class TestLimbSplit:
+    @pytest.mark.parametrize("pattern", list(LIMB_PATTERNS))
+    def test_split_recombines_mod_2_32(self, pattern):
+        from repro.kernels.limbs import split_limbs
+
+        a = _limb_operand(pattern, (64,), jnp.int32, seed=3)
+        limbs = split_limbs(a, 4)
+        assert all(d.dtype == jnp.int8 for d in limbs)
+        total = sum(np.asarray(d, np.int64) << (8 * i)
+                    for i, d in enumerate(limbs))
+        want = np.asarray(a, np.int64) % 2**32
+        np.testing.assert_array_equal(total % 2**32, want)
+
+    @pytest.mark.parametrize("pattern", list(LIMB_PATTERNS))
+    @pytest.mark.parametrize("a_dtype,b_dtype", [
+        (jnp.int8, jnp.int8), (jnp.int8, jnp.int32),
+        (jnp.int32, jnp.int8), (jnp.int32, jnp.int32),
+    ], ids=["i8xi8", "i8xi32", "i32xi8", "i32xi32"])
+    def test_limb_dot_equals_wrapped_int32_dot(self, pattern, a_dtype,
+                                               b_dtype):
+        from repro.kernels.limbs import limb_count, limb_dot, split_limbs
+
+        a = _limb_operand(pattern, (16, 40), a_dtype, seed=4)
+        b = _limb_operand(pattern, (40, 24), b_dtype, seed=5)
+        dims = (((1,), (0,)), ((), ()))
+        got = limb_dot(split_limbs(a, limb_count(a.dtype)),
+                       split_limbs(b, limb_count(b.dtype)), dims)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), _wrapped_dot(a, b, dims))
+
+    @pytest.mark.parametrize("pattern", list(LIMB_PATTERNS))
+    def test_grad_kernels_wrap_like_int32(self, kernel_backend, pattern):
+        """grad_W and grad_x on full-range int32 δ and W, on every
+        backend: equal to the mod-2³² product.  z* = 0 keeps the ReLU
+        derivative the identity, so the dot is all that is checked."""
+        x = _limb_operand(pattern, (24, 40), jnp.int32, seed=6)
+        delta = _limb_operand(pattern, (24, 136), jnp.int32, seed=7)
+        w = _limb_operand(pattern, (40, 136), jnp.int32, seed=8)
+        z = jnp.zeros_like(delta)
+        tiles = TileConfig(bm=16, bn=128, bk=16)
+        got_w = ops.grad_w_matmul(x, delta, z, backend=kernel_backend,
+                                  tiles=tiles)
+        got_x = ops.grad_x_matmul(delta, z, w, backend=kernel_backend,
+                                  tiles=tiles)
+        np.testing.assert_array_equal(
+            np.asarray(got_w), _wrapped_dot(x, delta, (((0,), (0,)), ((), ()))))
+        np.testing.assert_array_equal(
+            np.asarray(got_x), _wrapped_dot(delta, w, (((1,), (1,)), ((), ()))))
+
+    @pytest.mark.parametrize("x_dtype", [jnp.int8, jnp.int32])
+    @pytest.mark.parametrize("pattern", ["int32_extremes", "wrapping_sums"])
+    def test_fused_forward_wraps_like_reference(self, x_dtype, pattern):
+        """The training forward (one limb for an int8 x, four for int32)
+        against the reference backend, with weights at the int32 edges."""
+        x = _limb_operand(pattern, (20, 72), x_dtype, seed=9)
+        w = _limb_operand(pattern, (72, 40), jnp.int32, seed=10)
+        got = ops.fused_matmul_fwd(x, w, sf=linear_scale_factor(72),
+                                   backend="interpret")
+        want = ops.fused_matmul_fwd(x, w, sf=linear_scale_factor(72),
+                                    backend="reference")
+        assert_bitwise_equal(got, want)

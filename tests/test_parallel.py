@@ -72,11 +72,10 @@ def _ranks(fn, stacked):
 
 class TestRingCollectives:
     def _shmap(self, fn, n, *args):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh((n,), ("x",))
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=P("x"), out_specs=P("x")
         )(*args)
 
@@ -164,12 +163,11 @@ class TestGradientCompression:
 
     def test_integer_gradients_sum_exactly(self):
         """NITRO path: int32 gradient reduction is exact (no compression)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh((1,), ("pod",))
         g = {"w": jnp.asarray([2**30, -(2**30), 123], jnp.int32)}
-        out = shard_map(
+        out = jax.shard_map(
             lambda t: compress.exact_integer_psum(t, "pod"),
             mesh=mesh, in_specs=P(), out_specs=P(),
         )(g)
