@@ -28,6 +28,7 @@ from repro.core import model as M
 from repro.core import optimizer as opt
 from repro.core.losses import ONE_HOT_VALUE, one_hot_int, rss_grad, rss_loss
 from repro.core.numerics import INT_DTYPE
+from repro.obs import layers as scopes
 
 
 class TrainState(NamedTuple):
@@ -130,32 +131,41 @@ def compute_gradients(
     )
 
     # ---- output layers ----------------------------------------------------
-    grad_o = rss_grad(y_hat, y)
-    out_grads = B.output_backward(params["output"], out_cache, grad_o)
+    with scopes.output():
+        grad_o = rss_grad(y_hat, y)
+        out_grads = B.output_backward(params["output"], out_cache, grad_o)
 
     # ---- per-block local gradients (independent → parallel) ---------------
     block_grads = []
     local_losses = []
-    for spec, p, a_l, fw_cache in zip(
-        cfg.blocks, params["blocks"], acts, fw_caches
+    for i, (spec, p, a_l, fw_cache) in enumerate(
+        zip(cfg.blocks, params["blocks"], acts, fw_caches)
     ):
-        y_hat_l, lr_cache = B.learning_layers(p, spec, a_l)
-        grad_l = B.local_gradient(y_hat_l, y)
-        local_losses.append(rss_loss(y_hat_l, y))
-        delta_fw, lr_grads = B.learning_layers_backward(p, spec, lr_cache, grad_l)
-        fw_grads = B.forward_layers_backward(
-            p, spec, fw_cache, delta_fw,
-            conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
-        )
+        with scopes.block(i, scopes.LOCAL_LOSS):
+            y_hat_l, lr_cache = B.learning_layers(p, spec, a_l)
+            grad_l = B.local_gradient(y_hat_l, y)
+            local_losses.append(rss_loss(y_hat_l, y))
+            delta_fw, lr_grads = B.learning_layers_backward(
+                p, spec, lr_cache, grad_l)
+        with scopes.block(i, scopes.BACKWARD):
+            fw_grads = B.forward_layers_backward(
+                p, spec, fw_cache, delta_fw,
+                conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
+            )
         block_grads.append({"fw": fw_grads, "lr": lr_grads})
 
     grads = StepGrads(blocks=tuple(block_grads), output=out_grads)
-    metrics = StepMetrics(
+    with scopes.output():
+        metrics = _step_metrics(y_hat, y, labels, local_losses)
+    return grads, metrics, StepAux(fw_caches=tuple(fw_caches))
+
+
+def _step_metrics(y_hat, y, labels, local_losses) -> StepMetrics:
+    return StepMetrics(
         loss=rss_loss(y_hat, y),
         correct=jnp.sum(jnp.argmax(y_hat, axis=-1) == labels),
         local_losses=jnp.stack(local_losses),
     )
-    return grads, metrics, StepAux(fw_caches=tuple(fw_caches))
 
 
 def apply_gradients(
@@ -190,16 +200,18 @@ def apply_gradients(
         def _apply(p, g, s):
             return opt.apply_tree(p, g, s)
 
-    new_blocks = [
-        {
-            "fw": _apply(p["fw"], g["fw"], state.opt_fw),
-            "lr": _apply(p["lr"], g["lr"], state.opt_lr),
-        }
-        for p, g in zip(state.params["blocks"], grads.blocks)
-    ]
-    new_output = _apply(state.params["output"], grads.output, state.opt_lr)
-    new_params = {"blocks": new_blocks, "output": new_output}
-    return state._replace(params=new_params, step=state.step + 1)
+    with scopes.update():
+        new_blocks = [
+            {
+                "fw": _apply(p["fw"], g["fw"], state.opt_fw),
+                "lr": _apply(p["lr"], g["lr"], state.opt_lr),
+            }
+            for p, g in zip(state.params["blocks"], grads.blocks)
+        ]
+        new_output = _apply(state.params["output"], grads.output,
+                            state.opt_lr)
+        new_params = {"blocks": new_blocks, "output": new_output}
+        return state._replace(params=new_params, step=state.step + 1)
 
 
 def _fused_opt_step(
@@ -234,31 +246,34 @@ def _fused_opt_step(
         conv_mode=conv_mode,
     )
 
-    grad_o = rss_grad(y_hat, y)
-    out_grads = B.output_backward(params["output"], out_cache, grad_o)
-    new_output = opt.apply_tree(params["output"], out_grads, state.opt_lr)
+    with scopes.output():
+        grad_o = rss_grad(y_hat, y)
+        out_grads = B.output_backward(params["output"], out_cache, grad_o)
+    with scopes.update():
+        new_output = opt.apply_tree(params["output"], out_grads, state.opt_lr)
 
     new_blocks = []
     local_losses = []
-    for spec, p, a_l, fw_cache in zip(
-        cfg.blocks, params["blocks"], acts, fw_caches
+    for i, (spec, p, a_l, fw_cache) in enumerate(
+        zip(cfg.blocks, params["blocks"], acts, fw_caches)
     ):
-        y_hat_l, lr_cache = B.learning_layers(p, spec, a_l)
-        grad_l = B.local_gradient(y_hat_l, y)
-        local_losses.append(rss_loss(y_hat_l, y))
-        delta_fw, lr_grads = B.learning_layers_backward(p, spec, lr_cache, grad_l)
-        new_fw = B.forward_layers_update(
-            p, spec, fw_cache, delta_fw, state.opt_fw,
-            conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
-        )
-        new_lr = opt.apply_tree(p["lr"], lr_grads, state.opt_lr)
+        with scopes.block(i, scopes.LOCAL_LOSS):
+            y_hat_l, lr_cache = B.learning_layers(p, spec, a_l)
+            grad_l = B.local_gradient(y_hat_l, y)
+            local_losses.append(rss_loss(y_hat_l, y))
+            delta_fw, lr_grads = B.learning_layers_backward(
+                p, spec, lr_cache, grad_l)
+        with scopes.block(i, scopes.BACKWARD):
+            new_fw = B.forward_layers_update(
+                p, spec, fw_cache, delta_fw, state.opt_fw,
+                conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
+            )
+        with scopes.update():
+            new_lr = opt.apply_tree(p["lr"], lr_grads, state.opt_lr)
         new_blocks.append({"fw": new_fw, "lr": new_lr})
 
-    metrics = StepMetrics(
-        loss=rss_loss(y_hat, y),
-        correct=jnp.sum(jnp.argmax(y_hat, axis=-1) == labels),
-        local_losses=jnp.stack(local_losses),
-    )
+    with scopes.output():
+        metrics = _step_metrics(y_hat, y, labels, local_losses)
     new_params = {"blocks": new_blocks, "output": new_output}
     return state._replace(params=new_params, step=state.step + 1), metrics
 

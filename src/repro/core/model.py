@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.core import blocks as B
 from repro.core.activations import relu_fits_int8
 from repro.core.numerics import INT_DTYPE
+from repro.obs import layers as scopes
 
 
 @dataclass(frozen=True)
@@ -90,12 +91,15 @@ def forward(
     else:
         drop_keys = [None] * cfg.num_blocks
     fits_int8 = False  # the network input is not a NITRO-ReLU output
-    for spec, p, dk in zip(cfg.blocks, params["blocks"], drop_keys):
-        a, cache = B.forward_layers(
-            p, spec, a, dropout_key=dk, train=train,
-            fused=fused, backend=backend, conv_mode=conv_mode,
-            dp_axis=dp_axis, dp_shards=dp_shards, x_fits_int8=fits_int8,
-        )
+    for i, (spec, p, dk) in enumerate(
+        zip(cfg.blocks, params["blocks"], drop_keys)
+    ):
+        with scopes.block(i, scopes.FORWARD):
+            a, cache = B.forward_layers(
+                p, spec, a, dropout_key=dk, train=train,
+                fused=fused, backend=backend, conv_mode=conv_mode,
+                dp_axis=dp_axis, dp_shards=dp_shards, x_fits_int8=fits_int8,
+            )
         acts.append(a)
         caches.append(cache)
         # The next block's input is this block's NITRO-ReLU output (max-pool
@@ -103,7 +107,8 @@ def forward(
         fits_int8 = relu_fits_int8(spec.alpha_inv) and not (
             train and spec.dropout > 0.0
         )
-    y_hat, out_cache = B.output_forward(params["output"], a)
+    with scopes.output():
+        y_hat, out_cache = B.output_forward(params["output"], a)
     return y_hat, acts, caches, out_cache
 
 

@@ -86,6 +86,7 @@ def integer_sgd_update(
     )
     out = pl.pallas_call(
         _integer_sgd_kernel,
+        name="integer_sgd_update",
         grid=(grid_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -469,6 +469,7 @@ def stream_conv(
         scratches.append(pltpu.VMEM((bh_, w_out, bf_), jnp.int32))
     out = pl.pallas_call(
         kernel,
+        name="stream_conv",
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # rows DMA'd by the kernel
@@ -532,6 +533,7 @@ def stream_conv_fwd(
     )
     a, z_star = pl.pallas_call(
         kernel,
+        name="stream_conv_fwd",
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -604,6 +606,7 @@ def stream_conv_grad_w(
         in_specs.append(g_spec)
     out = pl.pallas_call(
         kernel,
+        name="stream_conv_grad_w",
         grid=(f_pad // bf_, n, n_bands),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((k * k * c_pad, bf_), lambda fi, ni, bi: (0, fi)),
@@ -670,6 +673,7 @@ def stream_conv_grad_w_opt(
     )
     out = pl.pallas_call(
         kernel,
+        name="stream_conv_grad_w_opt",
         grid=(f_pad // bf_, n, n_bands),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -737,6 +741,7 @@ def stream_conv_grad_x(
     ring = pltpu.VMEM((bh_ + k - 1, ring_w, f_in), jnp.int32)
     out = pl.pallas_call(
         kernel,
+        name="stream_conv_grad_x",
         grid=(n, h_pad // bh_, c_pad // bc),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # δ rows, DMA'd in-kernel

@@ -197,14 +197,15 @@ def _tile_geometry(x: jax.Array, w: jax.Array, bm: int, bn: int, bk: int):
     return x, w, (bm_, bn_, bk_), (gm, gn, gk)
 
 
-def _launch(kernel, x, w, tiles, grid, *, out_dtypes, interpret):
+def _launch(kernel, x, w, tiles, grid, *, name, out_dtypes, interpret):
     """Shared ``pallas_call`` scaffolding for both kernel variants.
 
     Everything that must stay in lockstep between the single-output and
     fused-forward kernels lives here — grid, BlockSpecs/index maps, the
     VMEM accumulator scratch, and dimension semantics.  The variants
-    differ only in kernel body and the number of (bm, bn) outputs, given
-    by ``out_dtypes``.
+    differ only in kernel body, the number of (bm, bn) outputs, given
+    by ``out_dtypes``, and ``name``, the kernel's name in the compiled
+    program and in a profile.
     """
     bm_, bn_, bk_ = tiles
     gm, gn, gk = grid
@@ -218,6 +219,7 @@ def _launch(kernel, x, w, tiles, grid, *, out_dtypes, interpret):
     single = len(out_dtypes) == 1
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
@@ -290,7 +292,7 @@ def nitro_matmul(
     )
     out = _launch(
         kernel, x, w, (bm_, bn_, bk_), (gm, gn, gk),
-        out_dtypes=[out_dtype], interpret=interpret,
+        name="nitro_matmul", out_dtypes=[out_dtype], interpret=interpret,
     )
     return out[:m, :n]
 
@@ -337,7 +339,8 @@ def nitro_matmul_fwd(
     )
     a, z_star = _launch(
         kernel, x, w, (bm_, bn_, bk_), (gm, gn, gk),
-        out_dtypes=[out_dtype, jnp.int32], interpret=interpret,
+        name="nitro_matmul_fwd", out_dtypes=[out_dtype, jnp.int32],
+        interpret=interpret,
     )
     return a[:m, :n], z_star[:m, :n]
 
@@ -430,6 +433,7 @@ def nitro_matmul_grad_w(
     )
     out = pl.pallas_call(
         kernel,
+        name="nitro_matmul_grad_w",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bk_, bm_), lambda i, j, kk: (kk, i)),
@@ -525,6 +529,7 @@ def nitro_matmul_grad_w_opt(
     )
     out = pl.pallas_call(
         kernel,
+        name="nitro_matmul_grad_w_opt",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -584,6 +589,7 @@ def nitro_matmul_grad_x(
     w = stack_limbs(w)
     out = pl.pallas_call(
         kernel,
+        name="nitro_matmul_grad_x",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),

@@ -12,6 +12,10 @@ trace.py      monotonic-clock span tracer with thread-local nesting,
               JSONL export, optional jax.profiler bridge — wrapped
               around train-step phases and the FleetEngine batch
               lifecycle
+layers.py     the jax.named_scope names of the LES train step's layers
+              (block{i}/forward|local_loss|backward, output, update,
+              dp/reduce_gradients), which every compiled instruction
+              carries into a device profile
 health.py     training-health rule engine over the telemetry records:
               saturation trends, int32 headroom early warning, dead-unit
               growth, optimiser-scalar stall — windowed, hysteretic,

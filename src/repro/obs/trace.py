@@ -26,8 +26,9 @@ Design points:
     pre-bind the span name once (``bound = tracer.bind("fleet.fetch")``,
     then ``with bound(model=...)``) so the per-call cost is one object
     allocation + two clock reads + one lock acquisition at exit —
-    what lets the fleet batch loop trace every phase inside the <3%
-    overhead budget (``BENCH_obs.json``);
+    what lets the fleet batch loop trace every phase inside a 3%
+    overhead budget, as ``benchmarks/obs_overhead.py`` measures it on
+    the CPU;
   * **profiler bridge** — ``annotate=True`` additionally wraps each span
     in ``jax.profiler.TraceAnnotation`` (when available), making the
     spans visible inside an XLA profile without a second instrumentation
@@ -180,19 +181,10 @@ class Tracer:
         """Pre-bind ``name``: hot paths call the result as ``bound(**attrs)``."""
         return _BoundSpan(self, name)
 
-    def event(self, name: str, **attrs) -> None:
-        """Record an instantaneous (zero-duration) span."""
-        with self.span(name, **attrs):
-            pass
-
     def snapshot(self) -> list[Span]:
         """The retained spans, oldest first (a consistent copy)."""
         with self._lock:
             return list(self._spans)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._spans.clear()
 
     def export_jsonl(self, path: str) -> int:
         """Write one JSON line per span, start-ordered; returns the count."""
@@ -236,14 +228,8 @@ class _NullTracer:
     def _null_bound(**attrs):
         return _NullTracer._NULL_CM
 
-    def event(self, name: str, **attrs) -> None:
-        pass
-
     def snapshot(self) -> list[Span]:
         return []
-
-    def clear(self) -> None:
-        pass
 
     def export_jsonl(self, path: str) -> int:
         with open(path, "w"):

@@ -47,6 +47,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import les
 from repro.core import model as M
+from repro.obs import layers as scopes
 from repro.parallel import collectives, compress, sharding
 
 DP_AXIS = "data"
@@ -207,11 +208,12 @@ def dp_train_step(
         if telemetry:
             # pre-reduce: the shard-local widths are what hit the wire
             fits16 = _grads_fit_int16(grads, DP_AXIS)
-        with jax.named_scope("dp/reduce_gradients"):
+        with scopes.reduce_gradients():
             grads = reduce_gradients(grads, DP_AXIS, dp_reduce)
-        metrics = les.StepMetrics(
-            *(jax.lax.psum(m, DP_AXIS) for m in metrics)
-        )
+        with scopes.output():
+            metrics = les.StepMetrics(
+                *(jax.lax.psum(m, DP_AXIS) for m in metrics)
+            )
         new_state = les.apply_gradients(
             state, grads, fuse_opt=fuse_opt, backend=backend
         )

@@ -516,12 +516,12 @@ class TestTracer:
     def test_capacity_and_event_and_clear(self):
         tr = Tracer(capacity=3)
         for i in range(5):
-            tr.event("e", i=i)
+            with tr.span("e", i=i):
+                pass
         spans = tr.snapshot()
         assert len(spans) == 3 and tr.recorded == 5
         assert [s.attrs["i"] for s in spans] == [2, 3, 4]  # oldest evicted
-        tr.clear()
-        assert tr.snapshot() == [] and tr.recorded == 5
+        assert all(s.duration_ns >= 0 for s in spans)
 
     def test_export_jsonl_round_trip(self, tmp_path):
         tr = Tracer()
@@ -547,9 +547,9 @@ class TestTracer:
     def test_null_tracer_surface(self, tmp_path):
         with NULL_TRACER.span("x", a=1) as sid:
             assert sid == 0
-        NULL_TRACER.event("y")
+        with NULL_TRACER.bind("y")(a=2) as sid:
+            assert sid == 0
         assert NULL_TRACER.snapshot() == []
-        NULL_TRACER.clear()
         path = str(tmp_path / "empty.jsonl")
         assert NULL_TRACER.export_jsonl(path) == 0
         with open(path) as f:
