@@ -200,16 +200,18 @@ def apply_gradients(
         def _apply(p, g, s):
             return opt.apply_tree(p, g, s)
 
+    blocks = state.params["blocks"]
     with scopes.update():
-        new_blocks = [
-            {
-                "fw": _apply(p["fw"], g["fw"], state.opt_fw),
-                "lr": _apply(p["lr"], g["lr"], state.opt_lr),
-            }
-            for p, g in zip(state.params["blocks"], grads.blocks)
-        ]
-        new_output = _apply(state.params["output"], grads.output,
-                            state.opt_lr)
+        # one call per optimiser group, so each group's reciprocals are
+        # computed once a step (``opt.apply_tree``)
+        new_fw = _apply([p["fw"] for p in blocks],
+                        [g["fw"] for g in grads.blocks], state.opt_fw)
+        new_lr, new_output = _apply(
+            ([p["lr"] for p in blocks], state.params["output"]),
+            ([g["lr"] for g in grads.blocks], grads.output),
+            state.opt_lr,
+        )
+        new_blocks = [{"fw": fw, "lr": lr} for fw, lr in zip(new_fw, new_lr)]
         new_params = {"blocks": new_blocks, "output": new_output}
         return state._replace(params=new_params, step=state.step + 1)
 
@@ -249,10 +251,9 @@ def _fused_opt_step(
     with scopes.output():
         grad_o = rss_grad(y_hat, y)
         out_grads = B.output_backward(params["output"], out_cache, grad_o)
-    with scopes.update():
-        new_output = opt.apply_tree(params["output"], out_grads, state.opt_lr)
 
-    new_blocks = []
+    new_fws = []
+    all_lr_grads = []
     local_losses = []
     for i, (spec, p, a_l, fw_cache) in enumerate(
         zip(cfg.blocks, params["blocks"], acts, fw_caches)
@@ -268,9 +269,17 @@ def _fused_opt_step(
                 p, spec, fw_cache, delta_fw, state.opt_fw,
                 conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
             )
-        with scopes.update():
-            new_lr = opt.apply_tree(p["lr"], lr_grads, state.opt_lr)
-        new_blocks.append({"fw": new_fw, "lr": new_lr})
+        new_fws.append(new_fw)
+        all_lr_grads.append(lr_grads)
+
+    with scopes.update():
+        # the learning and output layers as one group (see apply_gradients)
+        new_lr, new_output = opt.apply_tree(
+            ([p["lr"] for p in params["blocks"]], params["output"]),
+            (all_lr_grads, out_grads),
+            state.opt_lr,
+        )
+    new_blocks = [{"fw": fw, "lr": lr} for fw, lr in zip(new_fws, new_lr)]
 
     with scopes.output():
         metrics = _step_metrics(y_hat, y, labels, local_losses)

@@ -26,6 +26,19 @@ the faithful integer semantics, not a bug — but it means decay is *not*
 by a hypothesis property test over negative weights
 (``tests/test_integer_sgd.py``).
 
+Both floor divisions are by a divisor that is the same for every element
+of every tensor of a group within a step, so they run as a multiply by a
+precomputed integer reciprocal (division by an invariant integer,
+Granlund & Montgomery, PLDI 1994: ``numerics.reciprocal`` /
+``numerics.floor_div_by``), never as a per-element integer divide — the
+v5e vector unit has none, and XLA's expansion of ``jnp.floor_divide``'s
+div + rem is what set the update's cost.  The reciprocals are computed
+once per ``apply_tree`` call (one parameter group, one step).  Exact for
+every divisor d ≥ 1 and every int32 numerator: bitwise equal to
+``jnp.floor_divide``, pinned by ``tests/test_numerics.py``
+(``TestFloorDivBy``) and, for the whole update after lr-schedule steps,
+by ``tests/test_integer_sgd.py`` (``TestReciprocalUpdate``).
+
 NITRO Amplification Factor: a block's *forward layers* receive the local
 gradient amplified by the learning layers' matmul (bit-width
 O(13 + log₂ G)).  AF = 2⁶·G normalises that amplification, so the effective
@@ -49,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import numerics
-from repro.core.numerics import floor_div
+from repro.core.numerics import floor_div_by
 
 
 def amplification_factor(num_classes: int) -> int:
@@ -72,6 +85,24 @@ def init_state(gamma_inv: int, eta_inv: int = 0) -> IntegerSGDState:
     )
 
 
+def _reciprocals(state: IntegerSGDState) -> tuple[numerics.Reciprocal, ...]:
+    """The integer reciprocals of γ_inv and max(η_inv, 1): one prologue,
+    vectorised over both divisors, for every leaf of a group to share."""
+    r = numerics.reciprocal(
+        jnp.stack([state.gamma_inv, jnp.maximum(state.eta_inv, 1)])
+    )
+    return tuple(numerics.Reciprocal(r.m[i], r.sh[i]) for i in range(2))
+
+
+def _update(w, grad, recips, decay_on) -> jax.Array:
+    numerics.assert_int(w, "weights")
+    numerics.assert_int(grad, "gradient")
+    gamma_r, eta_r = recips
+    delta = floor_div_by(grad, gamma_r)
+    decay = jnp.where(decay_on, floor_div_by(w, eta_r), jnp.zeros_like(w))
+    return w - (delta + decay)
+
+
 def apply_update(
     w: jax.Array, grad: jax.Array, state: IntegerSGDState
 ) -> jax.Array:
@@ -81,21 +112,16 @@ def apply_update(
     ``−η_inv ≤ w < 0`` (the asymmetry documented in the module
     docstring); ``η_inv == 0`` disables decay entirely.
     """
-    numerics.assert_int(w, "weights")
-    numerics.assert_int(grad, "gradient")
-    delta = floor_div(grad, state.gamma_inv)
-    decay = jnp.where(
-        state.eta_inv != 0,
-        floor_div(w, jnp.maximum(state.eta_inv, 1)),
-        jnp.zeros_like(w),
-    )
-    return w - (delta + decay)
+    return _update(w, grad, _reciprocals(state), state.eta_inv != 0)
 
 
 def apply_tree(params, grads, state: IntegerSGDState):
-    """Apply IntegerSGD across a whole parameter pytree."""
+    """Apply IntegerSGD across a whole parameter pytree: the reciprocals
+    are computed once for the group, not once per leaf."""
+    recips = _reciprocals(state)
+    decay_on = state.eta_inv != 0
     return jax.tree_util.tree_map(
-        lambda w, g: apply_update(w, g, state), params, grads
+        lambda w, g: _update(w, g, recips, decay_on), params, grads
     )
 
 

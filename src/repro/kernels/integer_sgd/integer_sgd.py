@@ -2,11 +2,16 @@
 
     W ← W − ( ⌊g/γ_inv⌋ + ⌊W/η_inv⌋ )
 
-A memory-bound elementwise op: the fused kernel reads W and g once and
-writes W once (3 HBM streams), where the naive lowering materialises the
-two floor-division temporaries (5 streams) — a 1.67× traffic cut on the
-optimiser step, which at LES's per-block update frequency is a measurable
-slice of the training step's memory term.
+An elementwise op that reads W and g once and writes W once (3 HBM
+streams), but on v5e it is not bound by those streams: its two floor
+divisions by runtime scalars set its cost, because the vector unit has no
+integer divide and each ``jnp.floor_divide`` expands into a div, a rem
+and their fix-up.  The jnp update (``core.optimizer.apply_tree``, what
+every benchmarked step runs) divides by a precomputed integer reciprocal
+instead; the epilogue here (``integer_sgd_tile``) deliberately stays on
+``jnp.floor_divide`` — it is the independent cross-check the parity tests
+hold the reciprocal path to, until Mosaic's lowering of the uint32
+multiply-high has been checked on the chip.
 
 γ_inv/η_inv arrive as scalars in SMEM so one compiled kernel serves every
 (layer-group, schedule-step) combination — the lr schedule (γ_inv ×3 on

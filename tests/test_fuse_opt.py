@@ -259,10 +259,12 @@ class TestTrainStepFuseOptParity:
 # ---------------------------------------------------------------------------
 
 
-# floor_divide lowers to div/rem/select_n; any IntegerSGD update running
-# *outside* a Pallas kernel body betrays itself with one of these at the
-# updated tensor's full shape.
-_UPDATE_PRIMS = ("div", "rem", "select_n")
+# floor_divide lowers to div/rem/select_n, and the jnp update's
+# reciprocal division (``numerics.floor_div_by``) to mul and shifts beside
+# its decay select_n; any IntegerSGD update running *outside* a Pallas
+# kernel body betrays itself with one of these at the updated tensor's
+# full shape.
+_UPDATE_PRIMS = ("div", "rem", "select_n", "mul")
 
 
 def _structural_cfg():

@@ -22,6 +22,7 @@ ISSUE 10 fixed:
 
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
@@ -32,7 +33,15 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from _gradcheck import assert_bitwise_equal, backend_pair, kernel_backend  # noqa: F401
+from _gradcheck import (  # noqa: F401
+    assert_bitwise_equal,
+    assert_jaxpr_integer_only,
+    backend_pair,
+    iter_eqns,
+    kernel_backend,
+)
+from repro.configs import paper
+from repro.core import les
 from repro.core import optimizer as opt
 from repro.core.numerics import floor_div
 from repro.kernels.integer_sgd.integer_sgd import (
@@ -245,3 +254,98 @@ class TestDecayAsymmetry:
         got = floor_div(jnp.asarray([-1, -2999, 1, 2999], jnp.int32),
                         jnp.asarray(3000, jnp.int32))
         np.testing.assert_array_equal(np.asarray(got), [-1, -1, 0, 0])
+
+
+@functools.lru_cache(maxsize=None)
+def _param_shapes(arch: str, scale: float = 1.0):
+    """The parameters of a paper architecture, as shapes only."""
+    cfg = paper.get(arch, scale)
+    with jax.ensure_compile_time_eval():  # the init's bounds are Python ints
+        return jax.eval_shape(
+            lambda k: les.create_train_state(k, cfg).params,
+            jax.random.PRNGKey(0),
+        )
+
+
+@jax.jit
+def _floor_divide_tree(params, grads, state):
+    """Algorithm 1 written with ``jnp.floor_divide`` (the kernels'
+    epilogue ``integer_sgd_tile``) over a tree: the yardstick."""
+    return jax.tree_util.tree_map(
+        lambda w, g: integer_sgd_tile(w, g, state.gamma_inv, state.eta_inv),
+        params, grads)
+
+
+_apply_tree = jax.jit(opt.apply_tree)
+_apply_gradients = jax.jit(les.apply_gradients)
+
+
+class TestReciprocalUpdate:
+    """The update divides by precomputed integer reciprocals: no
+    elementwise integer divide, and bitwise the floor-divide result."""
+
+    @pytest.mark.parametrize("arch", ["mlp4", "vgg8b"])
+    def test_update_jaxpr_has_no_elementwise_divide(self, arch):
+        """At full width (shapes only): no ``div``/``rem`` on a
+        non-scalar operand and no float dtype anywhere in the update."""
+        params = _param_shapes(arch)
+        jaxpr = jax.make_jaxpr(opt.apply_tree)(
+            params, params, opt.init_state(327680, 19000))
+        assert_jaxpr_integer_only(jaxpr.jaxpr)
+        for eqn in iter_eqns(jaxpr.jaxpr):
+            if eqn.primitive.name in ("div", "rem"):
+                shapes = [v.aval.shape for v in eqn.invars]
+                assert all(s == () for s in shapes), (eqn, shapes)
+
+    @pytest.mark.parametrize("decay", [False, True], ids=["eta0", "eta"])
+    @pytest.mark.parametrize("plateaus", [0, 1, 3])
+    @pytest.mark.parametrize("arch", ["mlp4", "vgg8b"])
+    def test_apply_tree_matches_floor_divide(self, arch, plateaus, decay):
+        """Both optimiser groups of a paper architecture (1/8 width, the
+        recipe's γ_inv, γ_inv^fw = γ_inv·AF and η_inv) after 0, 1 and 3
+        plateaus (γ_inv × 3ᵏ), decay off and on — through
+        ``opt.apply_tree`` and through ``les.apply_gradients``."""
+        cfg = paper.get(arch, 1 / 8)
+        rng = np.random.default_rng(plateaus + 10 * decay)
+
+        def draw(s, lo, hi):
+            x = rng.integers(lo, hi, s.shape, endpoint=True)
+            x.flat[:4] = [lo, hi, 0, -1][: x.size]
+            return jnp.asarray(x, jnp.int32)
+
+        shapes = _param_shapes(arch, 1 / 8)
+        params = jax.tree_util.tree_map(
+            lambda s: draw(s, -(2 ** 15), 2 ** 15), shapes)
+        grads = jax.tree_util.tree_map(
+            lambda s: draw(s, -(2 ** 31), 2 ** 31 - 1), shapes)
+        af = opt.amplification_factor(cfg.num_classes)
+        state = les.TrainState(
+            params=params,
+            opt_lr=opt.init_state(cfg.gamma_inv, cfg.eta_lr * decay),
+            opt_fw=opt.init_state(cfg.gamma_inv * af, cfg.eta_fw * decay),
+            step=jnp.int32(0),
+        )
+        for _ in range(plateaus):
+            state = les.reduce_lr_on_plateau(state, True)
+        assert int(state.opt_fw.gamma_inv) == 327680 * 3 ** plateaus
+
+        def group(tree, name):
+            return [b[name] for b in tree["blocks"]]
+
+        got_fw = _apply_tree(group(params, "fw"), group(grads, "fw"),
+                             state.opt_fw)
+        assert_bitwise_equal(got_fw, _floor_divide_tree(
+            group(params, "fw"), group(grads, "fw"), state.opt_fw))
+        lr_out = (group(params, "lr"), params["output"])
+        lr_out_grads = (group(grads, "lr"), grads["output"])
+        got_lr_out = _apply_tree(lr_out, lr_out_grads, state.opt_lr)
+        assert_bitwise_equal(got_lr_out, _floor_divide_tree(
+            lr_out, lr_out_grads, state.opt_lr))
+
+        stepped = _apply_gradients(
+            state, les.StepGrads(blocks=tuple(grads["blocks"]),
+                                 output=grads["output"]))
+        assert_bitwise_equal(
+            (group(stepped.params, "fw"),
+             (group(stepped.params, "lr"), stepped.params["output"])),
+            (got_fw, got_lr_out))

@@ -88,9 +88,11 @@ def test_every_layer_scope_reaches_the_compiled_step(arch, fuse_opt):
 @pytest.mark.parametrize("arch", sorted(CONFIGS))
 def test_optimizer_ops_sit_under_update(arch):
     """IntegerSGD's ``W - ⌊g/γ⌋ - ⌊W/η⌋``: its subtract and its floor
-    division's divide are instructions of the ``update`` scope."""
+    divisions' reciprocal multiply are instructions of the ``update``
+    scope, and no integer divide or remainder is left there."""
     text = _compiled_text(CONFIGS[arch])
-    under = {op: False for op in ("subtract", "divide")}
+    under = {op: False for op in ("subtract", "multiply", "divide",
+                                  "remainder")}
     for line in text.splitlines():
         path = OP_NAME.search(line)
         if path is None or "/update/" not in path.group(1):
@@ -98,7 +100,8 @@ def test_optimizer_ops_sit_under_update(arch):
         for op in under:
             if re.search(rf"=\s*\S+\s+{op}\(", line):
                 under[op] = True
-    assert under == {"subtract": True, "divide": True}
+    assert under == {"subtract": True, "multiply": True, "divide": False,
+                     "remainder": False}
 
 
 def test_block_scope_names():

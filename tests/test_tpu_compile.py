@@ -1,5 +1,5 @@
-"""Compile every Pallas kernel of the default train and serve path for a
-described TPU v5e chip.
+"""Compile every Pallas kernel of the default train and serve path, and
+the jnp IntegerSGD update, for a described TPU v5e chip.
 
 Nothing runs here: each test lowers one kernel at a published width of
 VGG8B, VGG11B or MLP3 for one chip of a described ``v5e:2x2`` topology and
@@ -20,12 +20,14 @@ from __future__ import annotations
 
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import optimizer as opt
 from repro.kernels.integer_sgd.integer_sgd import integer_sgd_update
 
 NM = importlib.import_module("repro.kernels.nitro_matmul.nitro_matmul")
@@ -196,6 +198,25 @@ def test_integer_sgd_update(one_chip, shape):
         one_chip, (shape, jnp.int32), (shape, jnp.int32),
         ((), jnp.int32), ((), jnp.int32),
     )
+
+
+def test_jnp_update_has_no_integer_divide(one_chip):
+    """The update every training step runs (``opt.apply_tree``, jnp) at
+    MLP4's widths: the chip's program holds no integer divide or
+    remainder — the floor divisions are reciprocal multiplies — and one
+    loop fusion per weight, so the update streams W and g once."""
+    shapes = [(3072, 3000), (3000, 3000)]
+    ws = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+          for s in shapes]
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = jax.jit(opt.apply_tree, donate_argnums=0).lower(
+        ws, ws, opt.IntegerSGDState(scalar, scalar)).compile().as_text()
+    assert not re.search(r" (divide|remainder)\(", text)
+    entry = re.search(r"^ENTRY .*?^}", text, re.S | re.M).group(0)
+    for rows, cols in shapes:
+        fusions = re.findall(
+            rf"= s32\[{rows},{cols}\]\S* fusion\(", entry)
+        assert len(fusions) == 1, (rows, cols, fusions)
 
 
 def test_no_int32_mxu_dot(one_chip):
