@@ -1,4 +1,5 @@
 from repro.kernels.nitro_conv.nitro_conv import (
+    folds_taps,
     stream_conv,
     stream_conv_fwd,
     stream_conv_grad_w,
@@ -26,6 +27,7 @@ __all__ = [
     "conv_grad_w",
     "conv_grad_w_opt",
     "conv_grad_x",
+    "folds_taps",
     "fused_conv",
     "fused_conv_fwd",
     "resolve_conv_mode",

@@ -24,6 +24,16 @@ HBM bytes on the conv input:  materialised  ~(1 + 2·K²)·H·W·C
                               DMA'd once, at filter-tile 0, and the VMEM
                               ring is reused across the filter grid)
 
+A shallow input whose K²·C taps fit one 128-lane tile (``folds_taps``:
+the 3-channel network input, 27 lanes) would pad each of the K² patch
+segments to 128 lanes, a K²·128-deep contraction of mostly zeros.  Such a
+call folds its taps instead: the wrapper builds the 'same' patches
+``im2col(x)`` (N,H,W,K²·C) in HBM and runs the kernels as a 1×1 conv over
+them with the weight ``w.reshape(1, 1, K²·C, F)`` — one lane tile of
+contraction, the same integer result.  A 1×1 call has no halo, so its
+input bands are pipelined blocks, lane-padded in VMEM.  The folded calls
+are named ``<kernel>_folded``.
+
 The kernel bodies share the scaffolding:
 
   ``_stream_conv_kernel``         activation only (+ optional fused pool) —
@@ -54,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.activations import mu_int8
+from repro.core.layers import im2col
 from repro.core.scaling import pow2_split
 from repro.kernels.autotune.tiles import DEFAULT_TILES
 from repro.kernels.nitro_conv.ref import DEFAULT_BH, conv_geometry, rot180_swap
@@ -88,6 +99,26 @@ _PT_G = (((0,), (0,)), ((), ()))
 
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
+
+
+def folds_taps(k: int, c: int) -> bool:
+    """Whether a K×K conv over C input channels runs folded, as a 1×1 conv
+    over its K²·C-channel patches: K > 1 and the taps fit one lane tile."""
+    return k > 1 and k * k * c <= _LANE
+
+
+def _fold(x, w, k: int, name: str):
+    """The operands, kernel size and Pallas call name a streaming conv
+    call runs with: ``(im2col(x), w as (1,1,K²C,F), 1, name_folded)``
+    where ``folds_taps(K, C)``, else the call as given.  ``im2col``'s
+    channel order ``(ki·K + kj)·C + c`` is that of ``w.reshape(K²C, F)``,
+    so the folded conv is bit-identical.  ``w`` may be None (grad_W)."""
+    c = x.shape[-1]
+    if not folds_taps(k, c):
+        return x, w, k, name
+    if w is not None:
+        w = w.reshape(1, 1, k * k * c, w.shape[-1])
+    return im2col(x, k, k // 2), w, 1, f"{name}_folded"
 
 
 def _width_geometry(w_sp: int, k: int) -> tuple[int, int]:
@@ -152,6 +183,23 @@ def _form_patches(rows_ref, patches_ref, *, k: int, bh: int, w_out: int, c: int)
             )
 
 
+def _stage_patches(x_ref, rows, patches, sem, n, band, *, k: int, bh: int,
+                   w_out: int, c: int):
+    """Fill one band's patch block.  A K×K call DMAs the band's rows, halo
+    included, into the VMEM ring and forms the K² slices.  A 1×1 call (a
+    folded one) has no halo: its ``x_ref`` is the band's pipelined
+    (1, bh, W, C_in) block (``_conv_input``), already the patch block but
+    for the zero lanes past C_in."""
+    if k == 1:
+        c_in = x_ref.shape[-1]
+        if c_in < c:
+            patches[...] = jnp.zeros_like(patches)
+        patches[:, :c_in] = x_ref[0].reshape(bh * w_out, c_in)
+        return
+    _load_band(x_ref, rows, sem, n, band * bh, bh + k - 1)
+    _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
+
+
 def _band_matmul(patches_ref, w_ref, *, bh: int, w_out: int, bf: int,
                  x_limbs: int):
     """One band: (bh·W, K²C) @ (K²C, bf) → int32 (bh, W, bf).
@@ -191,8 +239,8 @@ def _stream_conv_kernel(
 
     @pl.when(f == 0)  # rows + patches are reused across filter tiles
     def _stage_band():
-        _load_band(x_hbm, rows, sem, n, band * bh, bh + k - 1)
-        _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
+        _stage_patches(x_hbm, rows, patches, sem, n, band,
+                       k=k, bh=bh, w_out=w_out, c=c)
 
     z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf,
                      x_limbs=x_limbs)
@@ -219,8 +267,8 @@ def _stream_conv_fwd_kernel(
 
     @pl.when(f == 0)
     def _stage_band():
-        _load_band(x_hbm, rows, sem, n, band * bh, bh + k - 1)
-        _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
+        _stage_patches(x_hbm, rows, patches, sem, n, band,
+                       k=k, bh=bh, w_out=w_out, c=c)
 
     z = _band_matmul(patches, w_ref, bh=bh, w_out=w_out, bf=bf,
                      x_limbs=x_limbs)
@@ -252,8 +300,8 @@ def _grad_w_accumulate(
     def _zero():
         acc[...] = jnp.zeros_like(acc)
 
-    _load_band(x_hbm, rows, sem, n, band * bh, bh + k - 1)
-    _form_patches(rows, patches, k=k, bh=bh, w_out=w_out, c=c)
+    _stage_patches(x_hbm, rows, patches, sem, n, band,
+                   k=k, bh=bh, w_out=w_out, c=c)
     acc[...] += limb_dot(
         split_limbs(patches[...], x_limbs),
         split_limbs(g2d, INT32_LIMBS),
@@ -387,6 +435,22 @@ def _conv_scratches(k, bh, w_out, ring_w, c_pad):
     ]
 
 
+def _conv_input(x, k: int, layout, index_map):
+    """The conv input operand and its spec.  A K×K call's input stays in
+    HBM in the ring layout, channels lane-padded, for the kernel's halo
+    DMA.  A 1×1 call (a folded one) has no halo: Pallas fetches each
+    band's (1, bh, W, C) block ahead of the step that reads it, at the
+    input's own channel width, which ``_stage_patches`` lane-pads in VMEM,
+    so no lane-padded copy of the input is written to HBM."""
+    bh, h_pad, p, w_out, ring_w, c_pad = layout
+    if k > 1:
+        return (_pad_rows(x, x.shape[1], h_pad, p, ring_w, c_pad),
+                pl.BlockSpec(memory_space=pl.ANY))
+    c = x.shape[-1]
+    return (_pad_rows(x, x.shape[1], h_pad, p, ring_w, c),
+            pl.BlockSpec((1, bh, w_out, c), index_map))
+
+
 def _weight_spec(w_planes, bf, index_map):
     """BlockSpec of one (L, K²C, bf) filter tile of the weight's limb planes."""
     return pl.BlockSpec((w_planes.shape[0], w_planes.shape[1], bf), index_map)
@@ -443,15 +507,15 @@ def stream_conv(
             f"operand_dtype='int8' requires int8 operands, got "
             f"{x.dtype}/{w.dtype} (the dispatcher narrows eligible inputs)"
         )
-    n, h, w_sp, c = x.shape
-    k, f = w.shape[0], w.shape[-1]
+    n, h, w_sp, _ = x.shape
+    f = w.shape[-1]
     if pool and (h < 2 or w_sp < 2):
         raise ValueError(f"2x2 pool epilogue needs H,W >= 2, got {h}x{w_sp}")
-    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
-        x.shape, k, bh, pool=pool
-    )
+    x, w, k, name = _fold(x, w, w.shape[0], "stream_conv")
+    layout = _conv_layout(x.shape, k, bh, pool=pool)
+    bh_, h_pad, _, w_out, ring_w, c_pad = layout
     bf_, f_pad = _filter_tiling(f, bf)
-    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
+    xp, x_spec = _conv_input(x, k, layout, lambda ni, bi, fi: (ni, bi, 0, 0))
     w_planes = stack_limbs(_flat_weight(w, c_pad, f_pad))
 
     shift, residual = pow2_split(sf)
@@ -469,10 +533,10 @@ def stream_conv(
         scratches.append(pltpu.VMEM((bh_, w_out, bf_), jnp.int32))
     out = pl.pallas_call(
         kernel,
-        name="stream_conv",
+        name=name,
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # rows DMA'd by the kernel
+            x_spec,
             _weight_spec(w_planes, bf_, lambda ni, bi, fi: (0, 0, fi)),
         ],
         out_specs=pl.BlockSpec(
@@ -511,13 +575,13 @@ def stream_conv_fwd(
     minus the HBM patch matrix on the input side.  An int8 ``x`` (a
     NITRO-ReLU activation narrowed by the caller) is one limb.
     """
-    n, h, w_sp, c = x.shape
-    k, f = w.shape[0], w.shape[-1]
-    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
-        x.shape, k, bh, pool=False
-    )
+    n, h, w_sp, _ = x.shape
+    f = w.shape[-1]
+    x, w, k, name = _fold(x, w, w.shape[0], "stream_conv_fwd")
+    layout = _conv_layout(x.shape, k, bh, pool=False)
+    bh_, h_pad, _, w_out, ring_w, c_pad = layout
     bf_, f_pad = _filter_tiling(f, bf)
-    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
+    xp, x_spec = _conv_input(x, k, layout, lambda ni, bi, fi: (ni, bi, 0, 0))
     w_planes = stack_limbs(_flat_weight(w, c_pad, f_pad))
 
     shift, residual = pow2_split(sf)
@@ -533,10 +597,10 @@ def stream_conv_fwd(
     )
     a, z_star = pl.pallas_call(
         kernel,
-        name="stream_conv_fwd",
+        name=name,
         grid=(n, h_pad // bh_, f_pad // bf_),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
+            x_spec,
             _weight_spec(w_planes, bf_, lambda ni, bi, fi: (0, 0, fi)),
         ],
         out_specs=[out_spec, out_spec],
@@ -579,21 +643,20 @@ def stream_conv_grad_w(
     applied the activation backward).  Padded δ and z* are 0, and the
     prologue maps (0, 0) → 0, so padding contributes nothing.
     """
-    n, h, w_sp, c = x.shape
-    k = kernel_size
+    n, _, _, c = x.shape
     f = grad_out.shape[-1]
-    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
-        x.shape, k, bh, pool=False
-    )
+    x, _, k, name = _fold(x, None, kernel_size, "stream_conv_grad_w")
+    layout = _conv_layout(x.shape, k, bh, pool=False)
+    bh_, h_pad, _, w_out, ring_w, c_pad = layout
     bf_, f_pad = _filter_tiling(f, bf)
-    xp = _pad_rows(x, h, h_pad, p, ring_w, c_pad)
+    xp, x_spec = _conv_input(x, k, layout, lambda fi, ni, bi: (ni, bi, 0, 0))
 
     n_bands = h_pad // bh_
     g_spec = pl.BlockSpec(
         (1, bh_, w_out, bf_), lambda fi, ni, bi: (ni, bi, 0, fi)
     )
     operands = [xp, _pad_grad(grad_out, h_pad, w_out, f_pad)]
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY), g_spec]
+    in_specs = [x_spec, g_spec]
     geom = dict(k=k, bh=bh_, w_out=w_out, c=c_pad, bf=bf_,
                 x_limbs=limb_count(x.dtype), n_steps=n * n_bands)
     if z_star is None:
@@ -606,7 +669,7 @@ def stream_conv_grad_w(
         in_specs.append(g_spec)
     out = pl.pallas_call(
         kernel,
-        name="stream_conv_grad_w",
+        name=name,
         grid=(f_pad // bf_, n, n_bands),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((k * k * c_pad, bf_), lambda fi, ni, bi: (0, fi)),
@@ -618,7 +681,9 @@ def stream_conv_grad_w(
         compiler_params=_ARBITRARY3,
         interpret=interpret,
     )(*operands)
-    return out.reshape(k, k, c_pad, f_pad)[:, :, :c, :f]
+    # a folded call's K²·C taps are the channels of its one 1×1 tap
+    return out.reshape(k, k, c_pad, f_pad)[:, :, :x.shape[-1], :f].reshape(
+        kernel_size, kernel_size, c, f)
 
 
 @functools.partial(
@@ -648,14 +713,15 @@ def stream_conv_grad_w_opt(
     tile.  ``w`` rides in VMEM flattened to the (K²C, bf) output layout.
     Padded channels/filters have acc = 0 and w = 0 → W′ = 0, sliced away.
     """
-    n, h, w_sp, c = x.shape
+    n, _, _, c = x.shape
     k = kernel_size
     f = grad_out.shape[-1]
     assert w.shape == (k, k, c, f), f"w shape {w.shape} != {(k, k, c, f)}"
-    bh_, h_pad, p, w_out, ring_w, c_pad = _conv_layout(
-        x.shape, k, bh, pool=False
-    )
+    x, w, k, name = _fold(x, w, k, "stream_conv_grad_w_opt")
+    layout = _conv_layout(x.shape, k, bh, pool=False)
+    bh_, h_pad, _, w_out, ring_w, c_pad = layout
     bf_, f_pad = _filter_tiling(f, bf)
+    xp, x_spec = _conv_input(x, k, layout, lambda fi, ni, bi: (ni, bi, 0, 0))
 
     n_bands = h_pad // bh_
     g_spec = pl.BlockSpec(
@@ -673,11 +739,11 @@ def stream_conv_grad_w_opt(
     )
     out = pl.pallas_call(
         kernel,
-        name="stream_conv_grad_w_opt",
+        name=name,
         grid=(f_pad // bf_, n, n_bands),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
+            x_spec,
             g_spec,
             g_spec,
             w_spec,
@@ -692,12 +758,14 @@ def stream_conv_grad_w_opt(
         interpret=interpret,
     )(
         scalars,
-        _pad_rows(x, h, h_pad, p, ring_w, c_pad),
+        xp,
         _pad_grad(grad_out, h_pad, w_out, f_pad),
         _pad_grad(z_star, h_pad, w_out, f_pad),
         _flat_weight(w, c_pad, f_pad),
     )
-    return out.reshape(k, k, c_pad, f_pad)[:, :, :c, :f]
+    # a folded call's K²·C taps are the channels of its one 1×1 tap
+    return out.reshape(k, k, c_pad, f_pad)[:, :, :x.shape[-1], :f].reshape(
+        kernel_size, kernel_size, c, f)
 
 
 @functools.partial(

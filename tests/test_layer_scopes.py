@@ -156,8 +156,10 @@ def test_dp_step_carries_the_scopes_and_the_exchange():
 
 I32 = jnp.int32
 S = jax.ShapeDtypeStruct
-CONV_X, CONV_W, CONV_G = S((2, 8, 8, 8), I32), S((3, 3, 8, 16), I32), \
+CONV_X, CONV_W, CONV_G = S((2, 8, 8, 16), I32), S((3, 3, 16, 16), I32), \
     S((2, 8, 8, 16), I32)
+# a 3-channel input: its 27 taps fold into one lane tile (``folds_taps``)
+IN_X, IN_W = S((2, 8, 8, 3), I32), S((3, 3, 3, 16), I32)
 MM_X, MM_W, MM_G = S((8, 128), I32), S((128, 128), I32), S((8, 128), I32)
 SCALAR = S((), I32)
 
@@ -175,6 +177,17 @@ KERNELS = [
      (CONV_X, CONV_G, CONV_G, CONV_W, SCALAR, SCALAR)),
     ("stream_conv_grad_x", lambda g, z, w: NC.stream_conv_grad_x(g, z, w),
      (CONV_G, CONV_G, CONV_W)),
+    ("stream_conv_folded", lambda x, w: NC.stream_conv(x, w, sf=64),
+     (IN_X, IN_W)),
+    ("stream_conv_fwd_folded", lambda x, w: NC.stream_conv_fwd(x, w, sf=64),
+     (IN_X, IN_W)),
+    ("stream_conv_grad_w_folded",
+     lambda x, g, z: NC.stream_conv_grad_w(x, g, kernel_size=3, z_star=z),
+     (IN_X, CONV_G, CONV_G)),
+    ("stream_conv_grad_w_opt_folded",
+     lambda x, g, z, w, gi, ei: NC.stream_conv_grad_w_opt(
+         x, g, z, w, gi, ei, kernel_size=3),
+     (IN_X, CONV_G, CONV_G, IN_W, SCALAR, SCALAR)),
     ("nitro_matmul", lambda x, w: NM.nitro_matmul(x, w, sf=64), (MM_X, MM_W)),
     ("nitro_matmul_fwd", lambda x, w: NM.nitro_matmul_fwd(x, w, sf=64),
      (MM_X, MM_W)),

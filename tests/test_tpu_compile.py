@@ -87,6 +87,14 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
+def _assert_folded(text: str, kernel: str, c: int) -> None:
+    """The compiled program holds the folded 1×1 kernel exactly where the
+    input's 3×3 taps fit one lane tile (the 3-channel network input)."""
+    folds = NC.folds_taps(3, c)
+    assert bool(re.search(rf"%{kernel}_folded(\.\d+)? =", text)) == folds
+    assert bool(re.search(rf"%{kernel}(\.\d+)? =", text)) != folds
+
+
 def _act_dtype(c: int):
     """The conv input as training feeds it: the 3-channel network input is
     int32, every later block input is an int8-narrowed activation."""
@@ -99,48 +107,53 @@ class TestConvKernels:
     def test_serve(self, one_chip, layer):
         _, h, w, c, f, pool = layer
         x_dtype = jnp.int32 if c == 3 else jnp.int8
-        _compile(
+        text = _compile(
             lambda x, wt: NC.stream_conv(
                 x, wt, sf=SF, pool=pool, out_dtype=jnp.int8,
                 operand_dtype="int8" if c != 3 else "int32"),
             one_chip, ((BATCH, h, w, c), x_dtype),
             ((3, 3, c, f), jnp.int8 if c != 3 else jnp.int32),
         )
+        _assert_folded(text, "stream_conv", c)
 
     def test_fwd(self, one_chip, layer):
         _, h, w, c, f, _ = layer
-        _compile(
+        text = _compile(
             lambda x, wt: NC.stream_conv_fwd(x, wt, sf=SF),
             one_chip, ((BATCH, h, w, c), _act_dtype(c)),
             ((3, 3, c, f), jnp.int32),
         )
+        _assert_folded(text, "stream_conv_fwd", c)
 
     def test_grad_w(self, one_chip, layer):
         _, h, w, c, f, _ = layer
-        _compile(
+        text = _compile(
             lambda x, g, z: NC.stream_conv_grad_w(
                 x, g, kernel_size=3, z_star=z),
             one_chip, ((BATCH, h, w, c), _act_dtype(c)),
             ((BATCH, h, w, f), jnp.int32), ((BATCH, h, w, f), jnp.int32),
         )
+        _assert_folded(text, "stream_conv_grad_w", c)
 
     def test_grad_w_opt(self, one_chip, layer):
         _, h, w, c, f, _ = layer
-        _compile(
+        text = _compile(
             lambda x, g, z, wt, gi, ei: NC.stream_conv_grad_w_opt(
                 x, g, z, wt, gi, ei, kernel_size=3),
             one_chip, ((BATCH, h, w, c), _act_dtype(c)),
             ((BATCH, h, w, f), jnp.int32), ((BATCH, h, w, f), jnp.int32),
             ((3, 3, c, f), jnp.int32), ((), jnp.int32), ((), jnp.int32),
         )
+        _assert_folded(text, "stream_conv_grad_w_opt", c)
 
     def test_grad_x(self, one_chip, layer):
         _, h, w, c, f, _ = layer
-        _compile(
+        text = _compile(
             lambda g, z, wt: NC.stream_conv_grad_x(g, z, wt),
             one_chip, ((BATCH, h, w, f), jnp.int32),
             ((BATCH, h, w, f), jnp.int32), ((3, 3, c, f), jnp.int32),
         )
+        assert "_folded" not in text  # its contraction runs over F
 
 
 @pytest.mark.parametrize(
