@@ -46,8 +46,7 @@ from benchmarks.common import emit, time_paired, tiny_smoke_cfg
 
 JSON_PATH = "BENCH_autotune.json"
 
-# (arch, scale, batch) — the two paper CNN topologies, matching
-# benchmarks/conv_stream.py so the suites describe the same models
+# (arch, scale, batch) — the two paper CNN topologies
 CONFIGS = [
     ("vgg8b", 0.5, 8),
     ("vgg11b", 0.5, 4),
@@ -70,10 +69,9 @@ def _tile_search(plan, batch: int, cache, iters: int, rows: list) -> None:
     for p in plan_shapes(plan, batch):
         winner, times = tune(
             p["op"], p["shape"], dtype=p["dtype"], backend=plan.backend,
-            conv_mode=p["conv_mode"], fuse_bwd=p["fuse_bwd"], cache=cache,
-            iters=iters)
+            prologue=p["prologue"], cache=cache, iters=iters)
         if winner is None:
-            continue  # no tile knobs on this (op, backend, mode)
+            continue  # no tile knobs on this (op, backend)
         default_us = next(iter(times.values()))  # default probes first
         tuned_us = times[winner]
         emit(f"autotune/{plan.name}/{p['op']}/{'x'.join(map(str, p['shape']))}",
@@ -83,7 +81,6 @@ def _tile_search(plan, batch: int, cache, iters: int, rows: list) -> None:
         rows.append({
             "op": p["op"],
             "shape": list(p["shape"]),
-            "conv_mode": p["conv_mode"] or None,
             "configs_timed": len(times),
             "default_us": default_us,
             "tuned_us": tuned_us,
@@ -108,8 +105,7 @@ def _cache_proof(plan, batch: int, cache, iters: int) -> dict:
     try:
         for p in plan_shapes(plan, batch):
             resolve_tiles(p["op"], p["shape"], dtype=p["dtype"],
-                          backend=plan.backend, conv_mode=p["conv_mode"],
-                          fuse_bwd=p["fuse_bwd"])
+                          backend=plan.backend, prologue=p["prologue"])
         hits = _counter(reg, "kernel_tile_cache_hits_total")
         misses = _counter(reg, "kernel_tile_cache_misses_total")
     finally:
@@ -152,8 +148,7 @@ def _int8_layers(plan, batch: int, iters: int, rows: list) -> None:
             run = functools.partial(
                 fused_conv, sf=meta.sf, alpha_inv=meta.alpha_inv,
                 apply_relu=meta.apply_relu, pool=meta.pool,
-                out_dtype=jnp.dtype(meta.out_dtype), backend=plan.backend,
-                conv_mode=meta.conv_mode)
+                out_dtype=jnp.dtype(meta.out_dtype), backend=plan.backend)
         else:
             run = functools.partial(
                 fused_matmul, sf=meta.sf, alpha_inv=meta.alpha_inv,

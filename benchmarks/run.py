@@ -23,7 +23,6 @@ def main() -> None:
     from benchmarks import (
         ablations,
         autotune_gain,
-        conv_stream,
         dp_scaling,
         kernel_bench,
         obs_overhead,
@@ -41,7 +40,6 @@ def main() -> None:
     suites = [
         ("kernel", lambda: kernel_bench.run()),
         ("train", lambda: train_step.run(quick=q)),
-        ("conv", lambda: conv_stream.run(quick=q)),
         ("autotune", lambda: autotune_gain.run(quick=q)),
         ("infer", lambda: serve_infer.run(quick=q)),
         ("serve", lambda: serve_fleet.run(quick=q)),

@@ -1,7 +1,7 @@
-"""Training-step benchmark: fused vs unfused forward, δ path, and optimiser.
+"""Training-step benchmark: fused vs unfused step, and the fused optimiser.
 
 Times one jit-compiled ``les.train_step`` (the full fwd+bwd step) in
-four variants at a CPU-feasible scale of the paper's VGG8B/VGG11B
+three variants at a CPU-feasible scale of the paper's VGG8B/VGG11B
 configs:
 
   * ``fused_opt``   — everything fused *including the optimiser*
@@ -10,15 +10,12 @@ configs:
                       materialises in HBM — 3 HBM streams per forward-layer
                       weight update (x, δ in; W′ out) instead of the split
                       path's 5+ (grad_W out, then W + grad_W in, W′ out);
-  * ``fused``       — fused forward + fused backward (``fuse_bwd=True``):
-                      the default path, with the NITRO-ReLU-bwd/STE
-                      prologue inside the gradient kernels, optimiser
-                      applied from the materialised gradient;
-  * ``bwd_unfused`` — fused forward, unfused δ path (``fuse_bwd=False``):
-                      the jnp ReLU-bwd + STE materialise the masked δ
-                      before the gradient matmuls;
-  * ``unfused``     — the fully unfused matmul → Scaling → ReLU reference
-                      composition on both passes.
+  * ``fused``       — fused forward + fused backward: the default path,
+                      with the NITRO-ReLU-bwd/STE prologue inside the
+                      gradient kernels, optimiser applied from the
+                      materialised gradient;
+  * ``unfused``     — the unfused matmul → Scaling → ReLU reference
+                      composition in the forward (``fused=False``).
 
 Timing is interleaved min-of-N with ABBA ordering (``common.time_paired``)
 — this container's CPU swings ~2× with co-tenant load, and the minimum
@@ -58,12 +55,11 @@ CONFIGS = [
     ("vgg11b", 0.0625, 8),
 ]
 
-# variant → (fused forward, fused backward, fused optimiser)
+# variant → (fused forward, fused optimiser)
 VARIANTS = {
-    "fused_opt": (True, True, True),
-    "fused": (True, True, False),
-    "bwd_unfused": (True, False, False),
-    "unfused": (False, False, False),
+    "fused_opt": (True, True),
+    "fused": (True, False),
+    "unfused": (False, False),
 }
 
 #: HBM tensor streams per forward-layer weight update: the fused epilogue
@@ -85,8 +81,8 @@ def _bench_config(cfg, batch: int, iters: int, results: list) -> None:
 
     steps = {
         mode: jax.jit(functools.partial(
-            les.train_step, cfg=cfg, fused=fwd, fuse_bwd=bwd, fuse_opt=fopt))
-        for mode, (fwd, bwd, fopt) in VARIANTS.items()
+            les.train_step, cfg=cfg, fused=fwd, fuse_opt=fopt))
+        for mode, (fwd, fopt) in VARIANTS.items()
     }
 
     # parity gate: one step, bit-identical parameters across all variants
@@ -96,19 +92,16 @@ def _bench_config(cfg, batch: int, iters: int, results: list) -> None:
         for pv, pr in zip(jax.tree_util.tree_leaves(st.params), ref):
             np.testing.assert_array_equal(np.asarray(pv), np.asarray(pr),
                                           err_msg=m)
-    del out  # keep the timed heap free of three full parameter trees
+    del out  # keep the timed heap free of the full parameter trees
 
     us = time_paired(steps, state, x=x, labels=labels, key=key, iters=iters)
     speedup = us["unfused"] / us["fused"] if us["fused"] else 0.0
-    bwd_speedup = us["bwd_unfused"] / us["fused"] if us["fused"] else 0.0
     opt_speedup = us["fused"] / us["fused_opt"] if us["fused_opt"] else 0.0
     for m in VARIANTS:
         emit(f"train/{cfg.name}/{m}", us[m],
              f"batch {batch}; {us[m] / batch:.1f} us/sample")
     emit(f"train/{cfg.name}/speedup", 0.0,
          f"{speedup:.2f}x fused/unfused (interleaved min-of-N)")
-    emit(f"train/{cfg.name}/bwd_speedup", 0.0,
-         f"{bwd_speedup:.2f}x fused-δ/unfused-δ path")
     emit(f"train/{cfg.name}/opt_speedup", 0.0,
          f"{opt_speedup:.2f}x fused-opt/split-opt path")
 
@@ -119,7 +112,6 @@ def _bench_config(cfg, batch: int, iters: int, results: list) -> None:
         "us_per_step": {m: us[m] for m in us},
         "us_per_sample": {m: us[m] / batch for m in us},
         "speedup_fused_over_unfused": speedup,
-        "speedup_fused_bwd_over_unfused_bwd": bwd_speedup,
         "speedup_fused_opt_over_fused": opt_speedup,
         "hbm_streams_per_weight_update": dict(HBM_STREAMS),
         # timing outcome — shape-checked only (like meets_target), never
@@ -145,8 +137,8 @@ def run(quick: bool = False, smoke: bool = False) -> None:
         "benchmark": "train_step",
         "backend": jax.default_backend(),
         "kernel_backend_auto": resolve_backend("auto"),
-        "variants": {m: {"fused_fwd": f, "fuse_bwd": b, "fuse_opt": o}
-                     for m, (f, b, o) in VARIANTS.items()},
+        "variants": {m: {"fused_fwd": f, "fuse_opt": o}
+                     for m, (f, o) in VARIANTS.items()},
         "speedup_estimator": (
             "interleaved min-of-N, ABBA order — co-tenant CPU noise only "
             "inflates samples, so the per-variant minimum bounds the "

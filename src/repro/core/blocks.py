@@ -116,7 +116,6 @@ def forward_layers(
     train: bool = True,
     fused: bool = True,
     backend: str = "auto",
-    conv_mode: str = "stream",
     dp_axis: str | None = None,
     dp_shards: int = 1,
     x_fits_int8: bool = False,
@@ -128,15 +127,14 @@ def forward_layers(
     already uses: one VMEM pass emitting both the activation ``a`` and the
     pre-ReLU ``z_star`` the backward needs, instead of three HBM
     round-trips of the int32 pre-activation.  Conv blocks go through the
-    ``nitro_conv`` dispatcher; ``conv_mode='stream'`` (default) forms
-    im2col patches implicitly from row bands so the ``(N·H·W, K²·C)``
-    patch matrix never touches HBM, ``'materialise'`` is the historical
-    explicit-im2col route.  ``fused=False`` is the unfused reference
-    composition — bit-exact with every fused variant (the tests enforce
-    it), kept as the escape hatch/oracle.
+    ``nitro_conv`` dispatcher, which forms im2col patches implicitly from
+    row bands so the ``(N·H·W, K²·C)`` patch matrix never touches HBM.
+    ``fused=False`` is the unfused reference composition (explicit im2col
+    + ``int_matmul``) — bit-exact with the fused path (the tests enforce
+    it), kept as the oracle.
 
-    The cache contract is identical in all modes (``z_star`` + the
-    layer input), so ``forward_layers_backward`` is unchanged.
+    The cache contract is identical either way (``z_star`` + the layer
+    input), so ``forward_layers_backward`` is unchanged.
 
     ``dp_axis``/``dp_shards`` (a shard_map axis name + its static size)
     make IntegerDropout draw the global-batch mask and slice this
@@ -159,7 +157,7 @@ def forward_layers(
             numerics.assert_int(x, "conv input")
             a, cache["z_star"] = _conv_ops().fused_conv_fwd(
                 x, params["fw"]["w"], sf=sf, alpha_inv=spec.alpha_inv,
-                backend=backend, conv_mode=conv_mode,
+                backend=backend,
             )
             cache["conv"] = layers.ConvCache(x=x)
         else:
@@ -200,9 +198,7 @@ def forward_layers_backward(
     cache: dict,
     delta_fw: jax.Array,
     *,
-    conv_mode: str = "stream",
     backend: str = "auto",
-    fuse_bwd: bool = True,
 ) -> dict:
     """Backward through the forward layers from δ_l^fw; returns weight grads.
 
@@ -210,11 +206,8 @@ def forward_layers_backward(
     LES confines gradients to the block.  The dropout/pool backwards stay
     jnp; the NITRO-ReLU derivative + scaling STE that follow them are
     handed to the ``kernels.grad_ops`` dispatcher together with the cached
-    ``z_star``: with ``fuse_bwd=True`` (default) they run as a prologue
-    inside the gradient kernels, so the post-ReLU-bwd δ never round-trips
-    through HBM; ``fuse_bwd=False`` is the unfused jnp escape hatch —
-    bit-identical, test-enforced.  ``conv_mode`` selects how the conv
-    gradients source their patches (streamed row bands vs explicit im2col).
+    ``z_star``: they run as a prologue inside the gradient kernels, so
+    the post-ReLU-bwd δ never round-trips through HBM.
     """
     g = delta_fw
     if "dropout" in cache:
@@ -225,13 +218,13 @@ def forward_layers_backward(
         _, grads = layers.conv_backward(
             params["fw"], cache["conv"], g,
             z_star=cache["z_star"], alpha_inv=spec.alpha_inv,
-            fuse_bwd=fuse_bwd, conv_mode=conv_mode, backend=backend,
+            backend=backend,
         )
     else:
         _, grads = layers.linear_backward(
             params["fw"], cache["linear"], g,
             z_star=cache["z_star"], alpha_inv=spec.alpha_inv,
-            fuse_bwd=fuse_bwd, backend=backend,
+            backend=backend,
         )
     return grads
 
@@ -243,9 +236,7 @@ def forward_layers_update(
     delta_fw: jax.Array,
     opt_state,
     *,
-    conv_mode: str = "stream",
     backend: str = "auto",
-    fuse_bwd: bool = True,
 ) -> dict:
     """``forward_layers_backward`` + IntegerSGD: returns updated fw params.
 
@@ -264,13 +255,13 @@ def forward_layers_update(
         _, new_fw = layers.conv_update(
             params["fw"], cache["conv"], g, opt_state,
             z_star=cache["z_star"], alpha_inv=spec.alpha_inv,
-            fuse_bwd=fuse_bwd, conv_mode=conv_mode, backend=backend,
+            backend=backend,
         )
     else:
         _, new_fw = layers.linear_update(
             params["fw"], cache["linear"], g, opt_state,
             z_star=cache["z_star"], alpha_inv=spec.alpha_inv,
-            fuse_bwd=fuse_bwd, backend=backend,
+            backend=backend,
         )
     return new_fw
 

@@ -7,10 +7,11 @@ all closed over ℤ.  Layout is NHWC / (batch, features), weights are
 (fan_in, fan_out) for linear and (K, K, C_in, C_out) for conv — the
 TPU-native layouts.
 
-Conv2D is realised as im2col + integer matmul: patch extraction followed by
-an int8×int8→int32 ``dot_general``.  On TPU this is the idiomatic mapping of
-convolution onto the MXU and lets the Pallas ``nitro_matmul`` kernel serve
-conv and linear layers alike (see kernels/nitro_matmul).
+``conv_forward`` realises Conv2D as explicit im2col + integer matmul: patch
+extraction followed by an int32 ``dot_general``.  It is the unfused
+oracle; the fused path streams the same patches through VMEM in the
+``nitro_conv`` kernels, and ``conv_backward`` / ``conv_update`` route both
+conv gradients there (see kernels/nitro_conv).
 """
 
 from __future__ import annotations
@@ -48,24 +49,20 @@ def linear_backward(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
 ) -> tuple[jax.Array, dict]:
     """grad_x = g @ Wᵀ, grad_W = xᵀ @ g — both integer matmuls.
 
     Routed through the shared ``kernels.grad_ops`` dispatcher.  With
     ``z_star`` (the cached pre-ReLU tensor) the NITRO-ReLU-bwd/STE step
-    runs as a prologue *inside* the gradient kernels (``fuse_bwd=True``,
-    default) or as the unfused jnp composition (``fuse_bwd=False``) —
-    bit-identical either way.  Learning/output layers pass no ``z_star``:
-    their scaling STE backward is the identity.
+    runs as a prologue *inside* the gradient kernels.  Learning/output
+    layers pass no ``z_star``: their scaling STE backward is the identity.
     """
     from repro.kernels import grad_ops  # lazy: cycle-free (see blocks.py)
 
     grad_x, grad_w = grad_ops.linear_grads(
         cache, params["w"], grad_out,
-        z_star=z_star, alpha_inv=alpha_inv, fuse_bwd=fuse_bwd,
-        backend=backend,
+        z_star=z_star, alpha_inv=alpha_inv, backend=backend,
     )
     return grad_x, {"w": grad_w}
 
@@ -78,7 +75,6 @@ def linear_update(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
 ) -> tuple[jax.Array, dict]:
     """``linear_backward`` + IntegerSGD in one pass: (grad_x, {'w': W′}).
@@ -91,8 +87,7 @@ def linear_update(
 
     grad_x, w_new = grad_ops.linear_weight_update(
         cache, params["w"], grad_out, opt_state,
-        z_star=z_star, alpha_inv=alpha_inv, fuse_bwd=fuse_bwd,
-        backend=backend,
+        z_star=z_star, alpha_inv=alpha_inv, backend=backend,
     )
     return grad_x, {"w": w_new}
 
@@ -162,8 +157,6 @@ def conv_backward(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
-    conv_mode: str = "stream",
     backend: str = "auto",
 ) -> tuple[jax.Array, dict]:
     """Integer conv backward, routed through ``kernels.grad_ops``.
@@ -172,20 +165,17 @@ def conv_backward(
     grad_x : 'full' correlation of grad_out with the spatially-flipped,
              channel-transposed kernel — one more conv on the MXU path.
 
-    ``conv_mode='stream'`` (default) feeds both matmuls with patches formed
-    on the fly from row bands — the ``(N·H·W, K²·C)`` patch matrix is never
-    materialised; ``'materialise'`` is the historical im2col formulation.
-    With ``z_star`` the NITRO-ReLU-bwd/STE step is fused into the kernels'
-    δ prologue (``fuse_bwd=True``) or applied as jnp pre-masking
-    (``fuse_bwd=False``).  Integer accumulation is order-exact, so every
-    combination agrees bit-for-bit.
+    Both gradients stream their patches from row bands — the
+    ``(N·H·W, K²·C)`` patch matrix is never materialised.  With ``z_star``
+    the NITRO-ReLU-bwd/STE step is fused into the kernels' δ prologue.
+    Integer accumulation is order-exact, so every backend agrees
+    bit-for-bit.
     """
     from repro.kernels import grad_ops  # lazy: cycle-free
 
     grad_x, grad_w = grad_ops.conv_grads(
         cache.x, params["w"], grad_out,
-        z_star=z_star, alpha_inv=alpha_inv, fuse_bwd=fuse_bwd,
-        backend=backend, conv_mode=conv_mode,
+        z_star=z_star, alpha_inv=alpha_inv, backend=backend,
     )
     return grad_x, {"w": grad_w}
 
@@ -198,23 +188,19 @@ def conv_update(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
-    conv_mode: str = "stream",
     backend: str = "auto",
 ) -> tuple[jax.Array, dict]:
     """``conv_backward`` + IntegerSGD in one pass: (grad_x, {'w': W′}).
 
-    Stream mode applies the update in the streaming grad_W kernel's flush
-    (``grad_ops.conv_weight_update``); materialise mode composes the
-    escape hatch.  Bitwise identical to ``conv_backward`` →
-    ``optimizer.apply_update`` on every (mode, backend) combination.
+    The update runs in the streaming grad_W kernel's flush
+    (``grad_ops.conv_weight_update``).  Bitwise identical to
+    ``conv_backward`` → ``optimizer.apply_update`` on every backend.
     """
     from repro.kernels import grad_ops  # lazy: cycle-free
 
     grad_x, w_new = grad_ops.conv_weight_update(
         cache.x, params["w"], grad_out, opt_state,
-        z_star=z_star, alpha_inv=alpha_inv, fuse_bwd=fuse_bwd,
-        backend=backend, conv_mode=conv_mode,
+        z_star=z_star, alpha_inv=alpha_inv, backend=backend,
     )
     return grad_x, {"w": w_new}
 

@@ -98,9 +98,7 @@ def compute_gradients(
     key: jax.Array,
     *,
     fused: bool = True,
-    fuse_bwd: bool = True,
     backend: str = "auto",
-    conv_mode: str = "stream",
     dp_axis: str | None = None,
     dp_shards: int = 1,
 ) -> tuple[StepGrads, StepMetrics, StepAux]:
@@ -127,7 +125,7 @@ def compute_gradients(
     # ---- forward ----------------------------------------------------------
     y_hat, acts, fw_caches, out_cache = M.forward(
         params, cfg, x, train=True, key=key, fused=fused, backend=backend,
-        conv_mode=conv_mode, dp_axis=dp_axis, dp_shards=dp_shards,
+        dp_axis=dp_axis, dp_shards=dp_shards,
     )
 
     # ---- output layers ----------------------------------------------------
@@ -149,8 +147,7 @@ def compute_gradients(
                 p, spec, lr_cache, grad_l)
         with scopes.block(i, scopes.BACKWARD):
             fw_grads = B.forward_layers_backward(
-                p, spec, fw_cache, delta_fw,
-                conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
+                p, spec, fw_cache, delta_fw, backend=backend,
             )
         block_grads.append({"fw": fw_grads, "lr": lr_grads})
 
@@ -224,9 +221,7 @@ def _fused_opt_step(
     key: jax.Array,
     *,
     fused: bool,
-    fuse_bwd: bool,
     backend: str,
-    conv_mode: str,
 ) -> tuple[TrainState, StepMetrics]:
     """The monolithic fast path behind ``train_step(fuse_opt=True)``.
 
@@ -245,7 +240,6 @@ def _fused_opt_step(
 
     y_hat, acts, fw_caches, out_cache = M.forward(
         params, cfg, x, train=True, key=key, fused=fused, backend=backend,
-        conv_mode=conv_mode,
     )
 
     with scopes.output():
@@ -266,8 +260,7 @@ def _fused_opt_step(
                 p, spec, lr_cache, grad_l)
         with scopes.block(i, scopes.BACKWARD):
             new_fw = B.forward_layers_update(
-                p, spec, fw_cache, delta_fw, state.opt_fw,
-                conv_mode=conv_mode, backend=backend, fuse_bwd=fuse_bwd,
+                p, spec, fw_cache, delta_fw, state.opt_fw, backend=backend,
             )
         new_fws.append(new_fw)
         all_lr_grads.append(lr_grads)
@@ -295,10 +288,8 @@ def train_step(
     key: jax.Array,
     *,
     fused: bool = True,
-    fuse_bwd: bool = True,
     fuse_opt: bool = False,
     backend: str = "auto",
-    conv_mode: str = "stream",
     telemetry: bool = False,
 ):
     """One integer-only NITRO-D step over a batch. jit-able (cfg static).
@@ -312,13 +303,11 @@ def train_step(
     The forward pass runs on the fused kernels by default (the same entry
     points the inference plan compiles to); ``fused=False`` is the unfused
     reference escape hatch, bit-exact with the fused step.  The backward
-    is fused too: ``fuse_bwd=True`` (default) folds the NITRO-ReLU
-    derivative + scaling STE into the gradient kernels' δ prologue via
-    ``kernels.grad_ops``; ``fuse_bwd=False`` is the unfused jnp δ path —
-    both bit-exact with each other.  ``conv_mode`` selects the conv data
-    path for the fused forward *and* the conv gradients: ``'stream'``
-    (implicit im2col — default) or ``'materialise'`` (explicit HBM patch
-    matrices, the historical route).
+    is fused too: the NITRO-ReLU derivative + scaling STE run as the
+    gradient kernels' δ prologue (``kernels.grad_ops``), and every conv
+    streams its patches (implicit im2col).  ``backend="reference"`` runs
+    the same step on the pure-jnp oracles, whose δ path is the unfused
+    composition — the bitwise bar for the kernels.
 
     ``fuse_opt=True`` takes the monolithic fast path (``_fused_opt_step``):
     the IntegerSGD update of each forward-layer weight runs as the grad_W
@@ -341,12 +330,11 @@ def train_step(
     if fuse_opt and not telemetry:
         return _fused_opt_step(
             state, cfg, x, labels, key,
-            fused=fused, fuse_bwd=fuse_bwd, backend=backend,
-            conv_mode=conv_mode,
+            fused=fused, backend=backend,
         )
     grads, metrics, aux = compute_gradients(
         state, cfg, x, labels, key,
-        fused=fused, fuse_bwd=fuse_bwd, backend=backend, conv_mode=conv_mode,
+        fused=fused, backend=backend,
     )
     new_state = apply_gradients(state, grads)
     if telemetry:

@@ -60,7 +60,6 @@ def forward(
     key: jax.Array | None = None,
     fused: bool = True,
     backend: str = "auto",
-    conv_mode: str = "stream",
     dp_axis: str | None = None,
     dp_shards: int = 1,
 ) -> tuple[jax.Array, list[jax.Array], list[dict], dict]:
@@ -71,13 +70,11 @@ def forward(
 
     ``fused`` selects the block-layer implementation: the fused kernel
     entry points shared with the inference plan (default), or the unfused
-    matmul → scale → relu reference composition.  ``conv_mode`` picks the
-    fused conv route: ``'stream'`` (implicit im2col, no HBM patch matrix)
-    or ``'materialise'`` (explicit im2col escape hatch).  All combinations
-    are bit-exact with each other, test-enforced.  (The backward mirror —
-    the ``fuse_bwd`` δ-path knob — lives on ``les.train_step``, which
-    threads the same ``backend``/``conv_mode`` into the gradient
-    dispatcher ``kernels.grad_ops``.)
+    matmul → scale → relu reference composition (explicit im2col for
+    convs); the fused convs stream their patches, so no HBM patch matrix
+    exists.  Both are bit-exact with each other, test-enforced.  (The
+    backward mirror lives on ``les.train_step``, which threads the same
+    ``backend`` into the gradient dispatcher ``kernels.grad_ops``.)
 
     ``dp_axis``/``dp_shards`` describe an enclosing data-parallel
     shard_map context; they only affect IntegerDropout (global-batch
@@ -97,7 +94,7 @@ def forward(
         with scopes.block(i, scopes.FORWARD):
             a, cache = B.forward_layers(
                 p, spec, a, dropout_key=dk, train=train,
-                fused=fused, backend=backend, conv_mode=conv_mode,
+                fused=fused, backend=backend,
                 dp_axis=dp_axis, dp_shards=dp_shards, x_fits_int8=fits_int8,
             )
         acts.append(a)

@@ -11,14 +11,11 @@ activations (see ``docs/ARCHITECTURE.md``).
 
     HBM traffic per layer:  unfused  M·N·(4+4+4) bytes  →  fused  M·N·1
 
-Conv layers stream: the default ``conv_mode='stream'`` runs the implicit
-im2col kernel — input rows are staged through VMEM and the
-``(N·H·W, K²·C)`` patch matrix is never materialised (~K²× less conv-input
-traffic) — with the 2×2 max-pool folded into the kernel epilogue for
-``pool=True`` layers, so pooled convs write H/2·W/2 activations straight
-away.  ``conv_mode='materialise'`` is the explicit-im2col escape hatch
-(patch matrix + ``nitro_matmul`` + separate jnp pool), bit-exact with the
-streaming path.
+Conv layers stream: the implicit im2col kernel stages input rows through
+VMEM and never materialises the ``(N·H·W, K²·C)`` patch matrix (~K²× less
+conv-input traffic), with the 2×2 max-pool folded into the kernel epilogue
+for ``pool=True`` layers, so pooled convs write H/2·W/2 activations
+straight away.
 
 Backends (static at compile time):
 
@@ -29,7 +26,7 @@ Backends (static at compile time):
                        conv oracle runs the same row-band algorithm);
   * ``'auto'``       — pallas on TPU, reference elsewhere.
 
-Every backend and conv mode is bit-exact with ``model.frozen_forward`` on
+Every backend is bit-exact with ``model.frozen_forward`` on
 the same frozen params — asserted by tests/test_infer.py and
 tests/test_conv_stream.py over the paper configs.
 """
@@ -65,7 +62,6 @@ class StepMeta(NamedTuple):
     pool: bool
     kernel_size: int    # conv only (0 otherwise)
     out_dtype: str      # 'int8' | 'int32' — inter-layer activation dtype
-    conv_mode: str = "" # conv only: 'stream' | 'materialise'
     fused_pool: bool = False  # pool folded into the conv kernel epilogue
     operand_dtype: str = "int32"  # MXU operand path: 'int8' | 'int32'
 
@@ -95,8 +91,7 @@ def _execute(weights, x, *, metas: tuple[StepMeta, ...], backend: str):
                 a, w, sf=meta.sf, alpha_inv=meta.alpha_inv,
                 apply_relu=meta.apply_relu, pool=meta.pool,
                 out_dtype=jnp.dtype(meta.out_dtype),
-                backend=backend, conv_mode=meta.conv_mode,
-                operand_dtype=meta.operand_dtype,
+                backend=backend, operand_dtype=meta.operand_dtype,
             )
         else:  # 'linear' | 'output' — flatten anything spatial entering
             if a.ndim > 2:
@@ -114,7 +109,6 @@ class ExecutionPlan:
         fm: FrozenModel,
         *,
         backend: str = "auto",
-        conv_mode: str = "stream",
         operand_dtype: str = "auto",
     ):
         """``operand_dtype`` selects the MXU operand path per step:
@@ -136,7 +130,6 @@ class ExecutionPlan:
                 f"one of {nitro_ops.OPERAND_DTYPES}"
             )
         self.backend = nitro_ops.resolve_backend(backend)
-        self.conv_mode = conv_ops.resolve_conv_mode(conv_mode)
         self.operand_dtype = operand_dtype
         self.input_shape = fm.input_shape
         self.num_classes = fm.num_classes
@@ -162,10 +155,7 @@ class ExecutionPlan:
                 apply_relu=layer.apply_relu, pool=layer.pool,
                 kernel_size=layer.w.shape[0] if is_conv else 0,
                 out_dtype=out_dtype,
-                conv_mode=self.conv_mode if is_conv else "",
-                fused_pool=bool(
-                    is_conv and layer.pool and self.conv_mode == "stream"
-                ),
+                fused_pool=bool(is_conv and layer.pool),
                 operand_dtype=step_od,
             ))
             act_dtype = out_dtype
@@ -195,18 +185,9 @@ class ExecutionPlan:
     def summary(self) -> list[dict]:
         """Per-step introspection incl. per-sample HBM-traffic estimates.
 
-        For conv steps both routes are estimated so the streaming delta is
-        visible whatever mode the plan compiled with:
-
-          * ``materialise`` — read the input, write *and* read back the
-            (H·W, K²·C) im2col patch matrix, write the full activation,
-            and (for pooled layers) round-trip it once more through the
-            separate pool pass;
-          * ``stream``      — read the input once, write the (pooled)
-            activation; patches only ever exist as VMEM row bands.
-
-        The ratio is ~K² on the conv-input term, which dominates wide
-        layers.  Linear steps are identical under both modes.
+        ``hbm_per_sample_bytes`` reads the step's input once and writes its
+        (pooled) activation once: conv patches only ever exist as VMEM row
+        bands, and the pool runs in the conv kernel's epilogue.
         """
         rows = []
         shape = tuple(int(d) for d in self.input_shape)
@@ -215,16 +196,10 @@ class ExecutionPlan:
             out_itemsize = jnp.dtype(meta.out_dtype).itemsize
             if meta.kind == "conv":
                 h, w_sp, c = shape
-                k, f = meta.kernel_size, int(w.shape[-1])
+                f = int(w.shape[-1])
                 in_bytes = h * w_sp * c * in_itemsize
-                patch_bytes = in_bytes * k * k
-                full_out = h * w_sp * f * out_itemsize
                 out_shape = (h // 2, w_sp // 2, f) if meta.pool else (h, w_sp, f)
-                final_out = out_shape[0] * out_shape[1] * f * out_itemsize
-                materialise = in_bytes + 2 * patch_bytes + full_out
-                if meta.pool:
-                    materialise += full_out + final_out
-                stream = in_bytes + final_out  # pool fused ⇒ one write
+                out_bytes = out_shape[0] * out_shape[1] * f * out_itemsize
                 shape = out_shape
             else:
                 feat = 1
@@ -232,7 +207,6 @@ class ExecutionPlan:
                     feat *= d
                 in_bytes = feat * in_itemsize
                 out_bytes = int(w.shape[-1]) * out_itemsize
-                materialise = stream = in_bytes + out_bytes
                 shape = (int(w.shape[-1]),)
             rows.append({
                 "kind": meta.kind,
@@ -242,7 +216,6 @@ class ExecutionPlan:
                 "activation_dtype": meta.out_dtype,
                 "operand_dtype": meta.operand_dtype,
                 "pool": meta.pool,
-                "conv_mode": meta.conv_mode or None,
                 "fused_pool": meta.fused_pool,
                 # per output element: unfused writes z(int32) + z*(int32) +
                 # act(int32); fused writes only the narrowed activation
@@ -250,13 +223,7 @@ class ExecutionPlan:
                     "unfused": 12,
                     "fused": out_itemsize,
                 },
-                # per-sample traffic incl. im2col patches (conv): the
-                # streaming-vs-materialise delta this plan's mode realises
-                "hbm_per_sample_bytes": {
-                    "materialise": int(materialise),
-                    "stream": int(stream),
-                },
-                "stream_saving_ratio": round(materialise / stream, 2),
+                "hbm_per_sample_bytes": int(in_bytes + out_bytes),
             })
             in_itemsize = out_itemsize
         return rows
@@ -266,10 +233,7 @@ def compile_plan(
     fm: FrozenModel,
     *,
     backend: str = "auto",
-    conv_mode: str = "stream",
     operand_dtype: str = "auto",
 ) -> ExecutionPlan:
     """FrozenModel → jit-compiled fused ExecutionPlan."""
-    return ExecutionPlan(
-        fm, backend=backend, conv_mode=conv_mode, operand_dtype=operand_dtype
-    )
+    return ExecutionPlan(fm, backend=backend, operand_dtype=operand_dtype)

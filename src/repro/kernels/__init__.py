@@ -14,9 +14,9 @@ nitro_conv/    streaming implicit-im2col conv: row bands DMA'd into a
                with the same fused ReLU-bwd delta prologue
 integer_sgd/   fused IntegerSGD update (Algorithm 1; 3 HBM streams vs 5)
 grad_ops.py    the unified backward dispatcher: linear_grads/conv_grads
-               own the ReLU-bwd/STE step (fuse_bwd=True folds it into the
-               kernel prologues; False is the unfused jnp escape hatch) —
-               core.layers.{linear,conv}_backward and
+               own the ReLU-bwd/STE step and fold it into the kernel
+               prologues (z_star=None is the no-activation route: plain
+               integer matmuls) — core.layers.{linear,conv}_backward and
                core.blocks.forward_layers_backward all route through it
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd

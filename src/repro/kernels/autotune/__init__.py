@@ -6,7 +6,7 @@
 ``measure``  the ABBA min-of-N paired timing harness (shared with
              ``benchmarks/``).
 ``cache``    the on-disk JSON cache: ``(op, shape, dtype, backend,
-             conv_mode, fuse_bwd)`` keys + a build fingerprint; atomic
+             prologue, fuse_opt)`` keys + a build fingerprint; atomic
              writes, corruption-tolerant reads.
 ``state``    process-wide resolution: ``configure`` a cache, dispatchers
              call ``resolve_tiles`` per launch; cache hit/miss counters

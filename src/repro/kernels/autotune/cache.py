@@ -5,18 +5,20 @@ One cache file holds every tuned entry for one build fingerprint::
     {
       "fingerprint": "repro=0.8.0|jax=0.4.xx|backend=cpu",
       "entries": {
-        "matmul|256x1152x128|int8,int8|pallas|-|0|0": {"bm": 64, ...},
+        "matmul|256x1152x128|int8,int8|pallas|0|0": {"bm": 64, ...},
         ...
       }
     }
 
 Design points:
 
-* **Keyed** by ``(op, shape, dtype, backend, conv_mode, fuse_bwd,
-  fuse_opt)`` —
+* **Keyed** by ``(op, shape, dtype, backend, prologue, fuse_opt)`` —
   every axis that changes which kernel runs or how its grid is laid
-  out.  Tile choice never changes *results* (integer accumulation is
-  order-exact), only speed, so a stale entry is a perf bug at worst —
+  out.  ``prologue`` says a gradient kernel masks δ against a cached
+  ``z_star`` (the NITRO-ReLU-bwd/STE prologue); with and without it a
+  grad kernel is a different program.  Tile choice never changes
+  *results* (integer accumulation is order-exact), only speed, so a
+  stale entry is a perf bug at worst —
   but the **fingerprint** still invalidates the whole file when the
   repro version, jax version, or jax backend changes, because a timing
   measured under a different compiler is meaningless.
@@ -59,12 +61,11 @@ def build_fingerprint() -> str:
 
 
 def cache_key(op: str, shape, dtype: str, backend: str,
-              conv_mode: str = "", fuse_bwd: bool = False,
-              fuse_opt: bool = False) -> str:
+              prologue: bool = False, fuse_opt: bool = False) -> str:
     """The canonical string key for one tuning problem."""
     dims = "x".join(str(int(d)) for d in shape)
-    return (f"{op}|{dims}|{dtype}|{backend}|{conv_mode or '-'}"
-            f"|{int(fuse_bwd)}|{int(fuse_opt)}")
+    return (f"{op}|{dims}|{dtype}|{backend}"
+            f"|{int(prologue)}|{int(fuse_opt)}")
 
 
 class TileCache:

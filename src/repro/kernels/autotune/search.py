@@ -1,7 +1,7 @@
 """The measured tile search: candidates → parity gate → ABBA timing → cache.
 
 ``tune()`` solves one problem — one ``(op, shape, dtype, backend,
-conv_mode, fuse_bwd)`` cache key:
+prologue)`` cache key:
 
 1. build deterministic integer operands for the op (fixed PRNG seed —
    values don't affect timing, and integer kernels have no data-dependent
@@ -34,8 +34,7 @@ op                    shape                      dispatcher
 ====================  =========================  =========================
 
 Untunable combinations return ``(None, {})``: the reference matmul has no
-tile knobs, and the materialise conv gradients are plain ``int_matmul``
-calls.  ``tune_plan`` / ``tune_training`` enumerate a whole inference
+tile knobs.  ``tune_plan`` / ``tune_training`` enumerate a whole inference
 plan / training config and tune every not-yet-cached problem.
 
 Kernel dispatchers are imported lazily inside functions: the dispatchers
@@ -107,8 +106,8 @@ def _operands(op: str, shape, dtype: str, seed: int):
     raise ValueError(f"unknown op {op!r}; one of {MATMUL_OPS + CONV_OPS}")
 
 
-def _build(op: str, operands, *, shape, backend: str, conv_mode: str,
-           fuse_bwd: bool, tiles):
+def _build(op: str, operands, *, shape, backend: str, prologue: bool,
+           tiles):
     """A zero-arg callable running one dispatcher variant (blocks on it)."""
     from repro.core.scaling import conv_scale_factor, linear_scale_factor
     from repro.kernels.nitro_conv import ops as conv_ops
@@ -137,35 +136,28 @@ def _build(op: str, operands, *, shape, backend: str, conv_mode: str,
         x, w = operands
         sf = conv_scale_factor(w.shape[0], x.shape[-1])
         entry = conv_ops.fused_conv if op == "conv" else conv_ops.fused_conv_fwd
-        return lambda: entry(
-            x, w, sf=sf, backend=backend, conv_mode=conv_mode, tiles=tiles)
+        return lambda: entry(x, w, sf=sf, backend=backend, tiles=tiles)
     if op == "conv_grad_w":
         x, delta, z_star = operands
         k = shape[4]
-        if not fuse_bwd:
+        if not prologue:
             delta, z_star = masked_delta(delta, z_star, 10), None
         return lambda: conv_ops.conv_grad_w(
             x, delta, kernel_size=k, z_star=z_star, backend=backend,
-            conv_mode=conv_mode, tiles=tiles)
+            tiles=tiles)
     delta, z_star, w = operands  # conv_grad_x
-    if not fuse_bwd:
+    if not prologue:
         delta, z_star = masked_delta(delta, z_star, 10), None
     return lambda: conv_ops.conv_grad_x(
-        delta, w, z_star=z_star, backend=backend, conv_mode=conv_mode,
-        tiles=tiles)
+        delta, w, z_star=z_star, backend=backend, tiles=tiles)
 
 
-def _untunable(op: str, backend: str, conv_mode: str) -> bool:
-    if op in MATMUL_OPS:
-        return backend == "reference"  # pure jnp matmul: no tile knobs
-    if conv_mode == "materialise":
-        # Forward materialise routes through fused_matmul (tunable off the
-        # reference backend); the gradients are plain int_matmul calls.
-        return op in ("conv_grad_w", "conv_grad_x") or backend == "reference"
-    return False
+def _untunable(op: str, backend: str) -> bool:
+    # the pure jnp matmul has no tile knobs
+    return op in MATMUL_OPS and backend == "reference"
 
 
-def _default_config(op: str, shape, backend: str, conv_mode: str) -> TileConfig:
+def _default_config(op: str, shape, backend: str) -> TileConfig:
     """The tiles the dispatcher uses when the cache has no entry.
 
     The reference streaming conv's untuned band height is
@@ -173,7 +165,7 @@ def _default_config(op: str, shape, backend: str, conv_mode: str) -> TileConfig:
     ``DEFAULT_TILES.bh`` — the probe must time what the fallback
     actually runs.
     """
-    if op in CONV_OPS and conv_mode != "materialise" and backend == "reference":
+    if op in CONV_OPS and backend == "reference":
         from repro.kernels.nitro_conv.ref import conv_geometry
 
         h, k = shape[1], shape[4]  # K sits at index 4 in both conv layouts
@@ -182,8 +174,7 @@ def _default_config(op: str, shape, backend: str, conv_mode: str) -> TileConfig:
     return DEFAULT_TILES
 
 
-def _candidates(op: str, shape, dtype: str, backend: str,
-                conv_mode: str) -> list[TileConfig]:
+def _candidates(op: str, shape, dtype: str, backend: str) -> list[TileConfig]:
     itemsize = max(jnp.dtype(s).itemsize for s in dtype.split(","))
     if op in MATMUL_OPS:
         m, k, n = shape if op != "matmul_grad_w" else (
@@ -191,9 +182,6 @@ def _candidates(op: str, shape, dtype: str, backend: str,
         if op == "matmul_grad_x":
             m, k, n = shape[0], shape[2], shape[1]
         return matmul_candidates(m, k, n, itemsize=itemsize)
-    if conv_mode == "materialise":  # inner fused_matmul over the patch matrix
-        n, h, w, c, k, f = shape
-        return matmul_candidates(n * h * w, k * k * c, f, itemsize=itemsize)
     if op == "conv_grad_x":
         n, h, w, f, k, c = shape
         return conv_candidates(h, w, f, k, c, itemsize=itemsize)
@@ -214,8 +202,7 @@ def tune(
     *,
     dtype: str = "int32,int32",
     backend: str = "auto",
-    conv_mode: str = "stream",
-    fuse_bwd: bool | None = None,
+    prologue: bool | None = None,
     cache: TileCache | None = None,
     iters: int = 5,
     seed: int = 0,
@@ -223,39 +210,39 @@ def tune(
     """Tune one problem; returns ``(winner, {config: best_us})``.
 
     ``(None, {})`` means the combination has no tile knobs (reference
-    matmul, materialise conv gradients) — the fallback is already optimal.
+    matmul) — the fallback is already optimal.  ``prologue`` (default:
+    on for the gradient ops) tunes the grad kernel that masks δ against
+    ``z_star``; off, the one fed a pre-masked δ.
     """
     from repro.kernels.nitro_matmul.ops import resolve_backend
 
     backend = resolve_backend(backend)
-    conv_mode = conv_mode if op in CONV_OPS else ""
-    if fuse_bwd is None:
-        fuse_bwd = op in GRAD_OPS
-    if _untunable(op, backend, conv_mode):
+    if prologue is None:
+        prologue = op in GRAD_OPS
+    if _untunable(op, backend):
         return None, {}
     operands = _operands(op, shape, dtype, seed)
 
     configs: dict[TileConfig, object] = {}
-    default = _default_config(op, shape, backend, conv_mode)
-    for cfg in [default, *_candidates(op, shape, dtype, backend, conv_mode)]:
+    default = _default_config(op, shape, backend)
+    for cfg in [default, *_candidates(op, shape, dtype, backend)]:
         if cfg not in configs:
             configs[cfg] = _build(
                 op, operands, shape=shape, backend=backend,
-                conv_mode=conv_mode, fuse_bwd=fuse_bwd, tiles=cfg)
+                prologue=prologue, tiles=cfg)
 
     # Parity gate: every candidate must reproduce the reference oracle
     # bitwise before it is allowed into the timing pool.
     want = jax.block_until_ready(_build(
         op, operands, shape=shape, backend="reference",
-        conv_mode=conv_mode, fuse_bwd=fuse_bwd, tiles=None)())
+        prologue=prologue, tiles=None)())
     for cfg, fn in configs.items():
         _assert_parity(jax.block_until_ready(fn()), want, op, cfg)
 
     times = time_paired(configs, iters=iters)
     winner = min(times, key=times.get)
     if cache is not None:
-        cache.put(cache_key(op, shape, dtype, backend, conv_mode, fuse_bwd),
-                  winner)
+        cache.put(cache_key(op, shape, dtype, backend, prologue), winner)
     return winner, times
 
 
@@ -281,8 +268,7 @@ def plan_shapes(plan, batch: int) -> list[dict]:
             k, f = meta.kernel_size, int(w.shape[-1])
             problems.append(dict(
                 op="conv", shape=(batch, h, w_sp, c, k, f),
-                dtype=f"{act_dt},{w_dt}", conv_mode=meta.conv_mode,
-                fuse_bwd=False))
+                dtype=f"{act_dt},{w_dt}", prologue=False))
             shape = (h // 2, w_sp // 2, f) if meta.pool else (h, w_sp, f)
         else:
             feat = 1
@@ -290,13 +276,13 @@ def plan_shapes(plan, batch: int) -> list[dict]:
                 feat *= d
             problems.append(dict(
                 op="matmul", shape=(batch, feat, int(w.shape[-1])),
-                dtype=f"{act_dt},{w_dt}", conv_mode="", fuse_bwd=False))
+                dtype=f"{act_dt},{w_dt}", prologue=False))
             shape = (int(w.shape[-1]),)
         act_dt = meta.out_dtype
     return problems
 
 
-def training_shapes(cfg, batch: int, *, conv_mode: str = "stream") -> list[dict]:
+def training_shapes(cfg, batch: int) -> list[dict]:
     """The fused fwd/bwd kernel problems one train step resolves.
 
     Enumerates each block's forward (``*_fwd``) and both gradient matmuls/
@@ -312,12 +298,11 @@ def training_shapes(cfg, batch: int, *, conv_mode: str = "stream") -> list[dict]
             k, f = spec.kernel_size, spec.out_features
             problems += [
                 dict(op="conv_fwd", shape=(batch, h, w_sp, c, k, f),
-                     dtype="int32,int32", conv_mode=conv_mode,
-                     fuse_bwd=False),
+                     dtype="int32,int32", prologue=False),
                 dict(op="conv_grad_w", shape=(batch, h, w_sp, c, k, f),
-                     dtype="int32,int32", conv_mode=conv_mode, fuse_bwd=True),
+                     dtype="int32,int32", prologue=True),
                 dict(op="conv_grad_x", shape=(batch, h, w_sp, f, k, c),
-                     dtype="int32,int32", conv_mode=conv_mode, fuse_bwd=True),
+                     dtype="int32,int32", prologue=True),
             ]
             shape = (h // 2, w_sp // 2, f) if spec.pool else (h, w_sp, f)
         else:
@@ -327,11 +312,11 @@ def training_shapes(cfg, batch: int, *, conv_mode: str = "stream") -> list[dict]
             n = spec.out_features
             problems += [
                 dict(op="matmul_fwd", shape=(batch, m, n),
-                     dtype="int32,int32", conv_mode="", fuse_bwd=False),
+                     dtype="int32,int32", prologue=False),
                 dict(op="matmul_grad_w", shape=(batch, m, n),
-                     dtype="int32,int32", conv_mode="", fuse_bwd=True),
+                     dtype="int32,int32", prologue=True),
                 dict(op="matmul_grad_x", shape=(batch, n, m),
-                     dtype="int32,int32", conv_mode="", fuse_bwd=True),
+                     dtype="int32,int32", prologue=True),
             ]
             shape = (n,)
     return problems
@@ -345,13 +330,13 @@ def _tune_problems(problems, *, backend: str, cache: TileCache,
     tuned = {}
     for p in problems:
         key = cache_key(p["op"], p["shape"], p["dtype"], backend,
-                        p["conv_mode"], p["fuse_bwd"])
+                        p["prologue"])
         if key in cache:
             tuned[key] = cache.get(key)  # measurement-free: already tuned
             continue
         winner, _ = tune(
             p["op"], p["shape"], dtype=p["dtype"], backend=backend,
-            conv_mode=p["conv_mode"], fuse_bwd=p["fuse_bwd"], cache=cache,
+            prologue=p["prologue"], cache=cache,
             iters=iters, seed=seed)
         if winner is not None:
             tuned[key] = winner
@@ -370,9 +355,8 @@ def tune_plan(plan, batch: int, *, cache: TileCache, iters: int = 3,
 
 
 def tune_training(cfg, batch: int, *, cache: TileCache, backend: str = "auto",
-                  conv_mode: str = "stream", iters: int = 3,
-                  seed: int = 0) -> dict:
+                  iters: int = 3, seed: int = 0) -> dict:
     """Tune every not-yet-cached fused fwd/bwd problem of one train config."""
     return _tune_problems(
-        training_shapes(cfg, batch, conv_mode=conv_mode), backend=backend,
+        training_shapes(cfg, batch), backend=backend,
         cache=cache, iters=iters, seed=seed)

@@ -76,15 +76,14 @@ def note_int8_path(layer: str, active: bool) -> None:
 
 
 def resolve_tiles(op: str, shape, *, dtype: str, backend: str,
-                  conv_mode: str = "",
-                  fuse_bwd: bool = False,
+                  prologue: bool = False,
                   fuse_opt: bool = False) -> TileConfig | None:
     """The tuned tiles for one problem, or ``None`` for the defaults."""
     cache = _active_cache
     if cache is None:
         return None
     tiles = cache.get(cache_key(op, shape, dtype, backend,
-                                conv_mode, fuse_bwd, fuse_opt))
+                                prologue, fuse_opt))
     if _metrics is not None:
         _metrics[0 if tiles is not None else 1].inc()
     return tiles

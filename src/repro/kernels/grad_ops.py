@@ -1,10 +1,10 @@
 """Unified backward dispatcher for the integer gradient paths.
 
 Every hand-derived backward in ``repro.core`` funnels through this module,
-the backward mirror of PR 2's forward dispatchers:
+the backward mirror of the forward dispatchers:
 
   * ``linear_grads`` — the two gradient matmuls of an IntegerLinear layer;
-  * ``conv_grads``   — the two conv gradients (streamed or materialised);
+  * ``conv_grads``   — the two streamed conv gradients;
   * ``linear_weight_update`` / ``conv_weight_update`` — grad_x plus the
     *updated weight*: the IntegerSGD step is applied in the grad_W
     kernels' flush (``fuse_opt``), so grad_W never materialises in HBM
@@ -13,27 +13,20 @@ the backward mirror of PR 2's forward dispatchers:
 Both take the *raw* block gradient δ (after the jnp dropout/pool
 backwards, which stay outside the kernels) plus the cached pre-ReLU
 ``z_star``, and own the NITRO-ReLU-derivative + scaling-STE step that
-precedes the gradient matmuls:
-
-``fuse_bwd=True`` (default)
-    the ReLU-bwd/STE runs as a *prologue inside* the gradient kernels —
-    each δ tile/band is masked in VMEM just before it enters the MXU, so
-    the full-size post-ReLU-bwd δ tensor never round-trips through HBM
-    (on the reference backend the oracle composes the same ops in jnp).
-
-``fuse_bwd=False``
-    the unfused escape hatch: ``activations.nitro_relu_backward`` +
-    ``scaling.scale_backward`` materialise the masked δ, then the plain
-    integer matmuls run — the historical composition, kept as the oracle.
+precedes the gradient matmuls: it runs as a *prologue inside* the
+gradient kernels — each δ tile/band is masked in VMEM just before it
+enters the MXU, so the full-size post-ReLU-bwd δ tensor never
+round-trips through HBM (on the reference backend the oracle composes
+the same ops in jnp).
 
 ``z_star=None`` selects the no-activation backward (learning/output
-layers: scaling STE only, which is the identity) — plain integer matmuls
-on any backend.  All combinations are bit-identical; the test-suite's
-shared parity harness (``tests/_gradcheck.py``) sweeps them.
+layers: scaling STE only, which is the identity) — plain integer
+matmuls on any backend.  ``masked_delta`` followed by that route is the
+unfused composition every fused δ path is tested against
+(``tests/_gradcheck.py``).
 
 Backend vocabulary is ``nitro_matmul.ops.resolve_backend``'s
-(``pallas | interpret | reference | auto``); ``conv_mode`` is
-``nitro_conv.ops``'s (``stream | materialise``).
+(``pallas | interpret | reference | auto``).
 """
 
 from __future__ import annotations
@@ -45,7 +38,6 @@ from repro.core.numerics import int_matmul
 from repro.kernels.autotune.tiles import TileConfig
 from repro.kernels.nitro_conv import ops as conv_ops
 from repro.kernels.nitro_matmul import ops as mm_ops
-from repro.kernels.nitro_matmul.ref import masked_delta
 
 
 def linear_grads(
@@ -55,7 +47,6 @@ def linear_grads(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
     tiles: TileConfig | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -63,15 +54,12 @@ def linear_grads(
 
     ``grad_w = xᵀ @ f(δ)`` and ``grad_x = f(δ) @ wᵀ`` where ``f`` is the
     NITRO-ReLU-bwd/STE when ``z_star`` is given (fused into the kernel
-    prologues by default) and the identity otherwise.  ``tiles`` overrides
+    prologues) and the identity otherwise.  ``tiles`` overrides
     the kernel tile sizes (``None`` → per-gradient autotune-cache lookup).
     """
-    if z_star is not None and not fuse_bwd:
-        delta = masked_delta(delta, z_star, alpha_inv)
-        z_star = None
     if z_star is None:
-        # No-activation backward (or the unfused escape hatch): two plain
-        # integer matmuls — already a single XLA op each, nothing to fuse.
+        # No-activation backward: two plain integer matmuls — already a
+        # single XLA op each, nothing to fuse.
         return int_matmul(delta, w.T), int_matmul(x.T, delta)
     grad_w = mm_ops.grad_w_matmul(
         x, delta, z_star, alpha_inv=alpha_inv, backend=backend, tiles=tiles
@@ -89,37 +77,24 @@ def conv_grads(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """IntegerConv2D backward: returns ``(grad_x, grad_w)``.
 
-    Both gradients stream their patches (``conv_mode='stream'``) or fall
-    back to explicit im2col (``'materialise'``); with ``z_star`` the
+    Both gradients stream their patches; with ``z_star`` the
     ReLU-bwd/STE prologue masks the δ bands inside the streaming kernels,
     so conv blocks never materialise the post-ReLU-bwd δ in HBM at all.
     """
-    if z_star is not None and (
-        not fuse_bwd
-        or conv_ops.resolve_conv_mode(conv_mode) == "materialise"
-    ):
-        # Unfused escape hatch — or materialise mode, whose explicit
-        # im2col reads the full δ from HBM regardless (no fusion site):
-        # pre-mask ONCE here rather than letting both conv gradients
-        # repeat the jnp mask downstream.
-        delta = masked_delta(delta, z_star, alpha_inv)
-        z_star = None
     grad_w = conv_ops.conv_grad_w(
         x, delta, kernel_size=w.shape[0],
         z_star=z_star, alpha_inv=alpha_inv,
-        backend=backend, conv_mode=conv_mode, tiles=tiles,
+        backend=backend, tiles=tiles,
     )
     grad_x = conv_ops.conv_grad_x(
         delta, w,
         z_star=z_star, alpha_inv=alpha_inv,
-        backend=backend, conv_mode=conv_mode, tiles=tiles,
+        backend=backend, tiles=tiles,
     )
     return grad_x, grad_w
 
@@ -137,7 +112,6 @@ def linear_weight_update(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
     tiles: TileConfig | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -145,15 +119,13 @@ def linear_weight_update(
 
     The fused path runs ``grad_w_opt_matmul`` — the IntegerSGD update is
     the grad_W kernel's flush epilogue, so grad_W never exists in HBM.
-    The escape hatches (``z_star=None`` or ``fuse_bwd=False``) compose
-    the materialised gradient with ``optimizer.apply_update`` — bitwise
-    identical, because integer floor-div over an order-exact int32
-    accumulation is exact.
+    Without ``z_star`` the materialised gradient is composed with
+    ``optimizer.apply_update`` — bitwise identical, because integer
+    floor-div over an order-exact int32 accumulation is exact.
     """
-    if z_star is None or not fuse_bwd:
+    if z_star is None:
         grad_x, grad_w = linear_grads(
-            x, w, delta, z_star=z_star, alpha_inv=alpha_inv,
-            fuse_bwd=fuse_bwd, backend=backend, tiles=tiles,
+            x, w, delta, alpha_inv=alpha_inv, backend=backend, tiles=tiles,
         )
         return grad_x, opt.apply_update(w, grad_w, opt_state)
     w_new = mm_ops.grad_w_opt_matmul(
@@ -174,34 +146,27 @@ def conv_weight_update(
     *,
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
-    fuse_bwd: bool = True,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """IntegerConv2D backward + optimiser: returns ``(grad_x, w_new)``.
 
-    Stream mode fuses the IntegerSGD step into the grad_W kernel's flush
-    (``conv_grad_w_opt``); materialise mode — whose gradient is an HBM
-    matmul result with no flush — takes the unfused escape hatch, as do
-    ``fuse_bwd=False`` and ``z_star=None``.
+    The IntegerSGD step runs in the grad_W kernel's flush
+    (``conv_grad_w_opt``).  Without ``z_star`` the gradient is composed
+    with ``optimizer.apply_update`` instead.
     """
-    if z_star is None or not fuse_bwd or (
-        conv_ops.resolve_conv_mode(conv_mode) == "materialise"
-    ):
+    if z_star is None:
         grad_x, grad_w = conv_grads(
-            x, w, delta, z_star=z_star, alpha_inv=alpha_inv,
-            fuse_bwd=fuse_bwd, backend=backend, conv_mode=conv_mode,
-            tiles=tiles,
+            x, w, delta, alpha_inv=alpha_inv, backend=backend, tiles=tiles,
         )
         return grad_x, opt.apply_update(w, grad_w, opt_state)
     w_new = conv_ops.conv_grad_w_opt(
         x, delta, w, opt_state.gamma_inv, opt_state.eta_inv,
         kernel_size=w.shape[0], z_star=z_star, alpha_inv=alpha_inv,
-        backend=backend, conv_mode=conv_mode, tiles=tiles,
+        backend=backend, tiles=tiles,
     )
     grad_x = conv_ops.conv_grad_x(
         delta, w, z_star=z_star, alpha_inv=alpha_inv,
-        backend=backend, conv_mode=conv_mode, tiles=tiles,
+        backend=backend, tiles=tiles,
     )
     return grad_x, w_new

@@ -1,7 +1,7 @@
 """Streaming implicit-im2col conv Pallas TPU kernel.
 
-The materialised conv path (``layers.conv_im2col_operands`` + the fused
-``nitro_matmul``) pays a hidden ~K²× input-bandwidth tax: the full
+Explicit im2col (``layers.conv_im2col_operands`` + an integer matmul, the
+unfused oracle's route) pays a hidden ~K²× input-bandwidth tax: the full
 ``(N·H·W, K²·C)`` patch matrix is written to HBM and read back before the
 matmul starts.  This kernel never forms that matrix.  Instead it
 
@@ -19,7 +19,7 @@ matmul starts.  This kernel never forms that matrix.  Instead it
     write ``H/2·W/2`` activations instead of ``H·W`` plus a separate jnp
     pool pass.
 
-HBM bytes on the conv input:  materialised  ~(1 + 2·K²)·H·W·C
+HBM bytes on the conv input:  explicit      ~(1 + 2·K²)·H·W·C
                               streaming     ~H·W·C   (each band's rows are
                               DMA'd once, at filter-tile 0, and the VMEM
                               ring is reused across the filter grid)
@@ -492,8 +492,8 @@ def stream_conv(
     """Streaming fused 'same' conv: ``relu(⌊conv(x, w)/sf⌋)`` (+2×2 pool).
 
     x: (N,H,W,C) int, w: (K,K,C,F) int, K odd → (N,H,W,F) activations, or
-    (N,H//2,W//2,F) with ``pool=True``.  Bit-exact with the materialised
-    im2col + ``nitro_matmul`` path (+ separate pool) on every shape.
+    (N,H//2,W//2,F) with ``pool=True``.  Bit-exact with explicit im2col +
+    an integer matmul (+ separate pool) on every shape.
 
     ``operand_dtype='int8'`` asserts both operands already *are* int8
     (the dispatcher proves eligibility and narrows): one int8 MXU pass per

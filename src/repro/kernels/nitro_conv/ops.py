@@ -1,4 +1,4 @@
-"""Public wrappers + mode/backend dispatch for the streaming conv kernel.
+"""Public wrappers + backend dispatch for the streaming conv kernel.
 
 This module is the **single conv entry point** every consumer shares:
 
@@ -11,16 +11,10 @@ This module is the **single conv entry point** every consumer shares:
   * inference — ``infer.plan`` calls ``fused_conv`` (activation only,
     optionally int8-narrowed, optionally with the fused 2×2 pool).
 
-Two orthogonal static knobs:
-
-``conv_mode``
-  * ``'stream'``      — implicit im2col: row bands are staged through
-                        VMEM (Pallas) or band-local patch blocks (jnp);
-                        the ``(N·H·W, K²·C)`` patch matrix never exists.
-  * ``'materialise'`` — the original path: ``conv_im2col_operands`` +
-                        the fused ``nitro_matmul`` (+ separate jnp pool).
-                        Kept as the bit-exact escape hatch/oracle,
-                        mirroring ``fused=False`` one level up.
+Every entry point runs one data path, implicit im2col: row bands are
+staged through VMEM (Pallas) or band-local patch blocks (jnp), and the
+``(N·H·W, K²·C)`` patch matrix never exists.  One static knob picks who
+runs it:
 
 ``backend`` (same vocabulary as ``nitro_matmul.ops``)
   * ``'pallas'``     — the real TPU kernel;
@@ -28,9 +22,10 @@ Two orthogonal static knobs:
   * ``'reference'``  — the pure-jnp streaming oracle from ``ref.py``;
   * ``'auto'``       — pallas on TPU, reference elsewhere.
 
-Every (mode, backend) combination is bit-identical — integer arithmetic
-makes the tiling/accumulation order irrelevant — and the tests sweep them
-all against each other.
+Every backend is bit-identical — integer arithmetic makes the
+tiling/accumulation order irrelevant.  The tests hold them to the
+explicit-im2col composition (``core.layers.conv_forward``) and to
+``jax.lax.conv_general_dilated`` on int32 operands.
 """
 
 from __future__ import annotations
@@ -38,8 +33,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.layers import conv_im2col_operands, im2col, window_view_2x2
-from repro.core.numerics import int_matmul
 from repro.kernels.autotune import state as autotune
 from repro.kernels.autotune.tiles import TileConfig
 from repro.kernels.nitro_conv import ref as conv_ref
@@ -56,22 +49,11 @@ from repro.kernels.nitro_matmul.ops import (
     resolve_backend,
     resolve_operand_dtype,
 )
-from repro.kernels.nitro_matmul.ref import masked_delta
-
-CONV_MODES = ("stream", "materialise")
 
 
 def _stream_tile_kw(tiles: TileConfig | None) -> dict:
     """bh/bf kwargs for the streaming kernels (defaults when untuned)."""
     return {} if tiles is None else dict(bh=tiles.bh, bf=tiles.bf)
-
-
-def resolve_conv_mode(conv_mode: str) -> str:
-    if conv_mode not in CONV_MODES:
-        raise ValueError(
-            f"unknown conv_mode {conv_mode!r}; one of {CONV_MODES}"
-        )
-    return conv_mode
 
 
 # ---------------------------------------------------------------------------
@@ -89,25 +71,20 @@ def fused_conv(
     pool: bool = False,
     out_dtype=jnp.int32,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
     operand_dtype: str = "auto",
 ) -> jax.Array:
     """One fused conv+scale(+relu)(+2×2 pool) — the inference plan step.
 
     (N,H,W,C) int × (K,K,C,F) int → (N,H,W,F), or (N,H//2,W//2,F) when
-    ``pool=True``.  On the streaming path the pool runs in the kernel
-    epilogue; the materialised path pools with a separate jnp pass (its
-    historical behaviour) — bit-identical either way.
+    ``pool=True``; the pool runs in the kernel epilogue.
 
     ``tiles``/``operand_dtype`` mirror ``fused_matmul``'s knobs: both are
     perf-only and bitwise result-invariant.  ``tiles=None`` consults the
-    autotune cache under the conv's own key; a materialise-mode miss then
-    falls through to the inner matmul's own resolution.
+    autotune cache under the conv's own key.
     """
     alpha_inv = check_alpha_inv(alpha_inv, apply_relu)
     backend = resolve_backend(backend)
-    conv_mode = resolve_conv_mode(conv_mode)
     od = resolve_operand_dtype(operand_dtype, x, w)
     if od == "int8":
         x = _guard_int8(x, "x")
@@ -117,19 +94,7 @@ def fused_conv(
             "conv", (x.shape[0], x.shape[1], x.shape[2], x.shape[3],
                      w.shape[0], w.shape[-1]),
             dtype=f"{x.dtype},{w.dtype}", backend=backend,
-            conv_mode=conv_mode,
         )
-    if conv_mode == "materialise":
-        from repro.kernels.nitro_matmul.ops import fused_matmul
-
-        n, h, w_sp, _ = x.shape
-        patches, w_flat = conv_im2col_operands(w, x)
-        out = fused_matmul(
-            patches, w_flat, sf=sf, alpha_inv=alpha_inv,
-            apply_relu=apply_relu, out_dtype=out_dtype, backend=backend,
-            tiles=tiles, operand_dtype=od,
-        ).reshape(n, h, w_sp, w.shape[-1])
-        return jnp.max(window_view_2x2(out), axis=3) if pool else out
     if backend == "reference":
         return conv_ref.stream_conv_ref(
             x, w, sf=sf, alpha_inv=alpha_inv, apply_relu=apply_relu,
@@ -150,7 +115,6 @@ def fused_conv_fwd(
     sf: int,
     alpha_inv: int = 10,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused conv *training* forward: ``(a, z_star)``, both (N,H,W,F).
@@ -161,25 +125,12 @@ def fused_conv_fwd(
     """
     alpha_inv = check_alpha_inv(alpha_inv, True)
     backend = resolve_backend(backend)
-    conv_mode = resolve_conv_mode(conv_mode)
     if tiles is None:
         tiles = autotune.resolve_tiles(
             "conv_fwd", (x.shape[0], x.shape[1], x.shape[2], x.shape[3],
                          w.shape[0], w.shape[-1]),
             dtype=f"{x.dtype},{w.dtype}", backend=backend,
-            conv_mode=conv_mode,
         )
-    if conv_mode == "materialise":
-        from repro.kernels.nitro_matmul.ops import fused_matmul_fwd
-
-        n, h, w_sp, _ = x.shape
-        f = w.shape[-1]
-        patches, w_flat = conv_im2col_operands(w, x)
-        a2, z2 = fused_matmul_fwd(
-            patches, w_flat, sf=sf, alpha_inv=alpha_inv, backend=backend,
-            tiles=tiles,
-        )
-        return a2.reshape(n, h, w_sp, f), z2.reshape(n, h, w_sp, f)
     if backend == "reference":
         return conv_ref.stream_conv_fwd_ref(
             x, w, sf=sf, alpha_inv=alpha_inv,
@@ -204,42 +155,29 @@ def conv_grad_w(
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> jax.Array:
     """Conv weight gradient: correlate input patches with ``grad_out``.
 
-    (N,H,W,C) × (N,H,W,F) → (K,K,C,F) int32.  Streaming forms patch bands
-    on the fly (VMEM accumulator in the kernel, band loop in the jnp
-    oracle); materialise is the historical ``im2colᵀ @ g`` matmul.
+    (N,H,W,C) × (N,H,W,F) → (K,K,C,F) int32.  Patch bands are formed on
+    the fly (VMEM accumulator in the kernel, band loop in the jnp oracle).
 
     ``z_star`` (same shape as ``grad_out``) enables the fused backward:
     the NITRO-ReLU-derivative/STE prologue is applied to the δ bands in
-    VMEM (stream) or as a jnp pre-mask (materialise — its patches live in
-    HBM anyway, so there is no fusion site).  Without it the caller has
-    already applied the activation backward.
+    VMEM.  Without it the caller has already applied the activation
+    backward.
     """
     backend = resolve_backend(backend)
     if z_star is not None:
         alpha_inv = check_alpha_inv(alpha_inv, True)
-    conv_mode = resolve_conv_mode(conv_mode)
-    if tiles is None and conv_mode != "materialise":
+    if tiles is None:
         tiles = autotune.resolve_tiles(
             "conv_grad_w",
             (x.shape[0], x.shape[1], x.shape[2], x.shape[3],
              kernel_size, grad_out.shape[-1]),
             dtype=f"{x.dtype},{grad_out.dtype}", backend=backend,
-            conv_mode=conv_mode, fuse_bwd=z_star is not None,
+            prologue=z_star is not None,
         )
-    if conv_mode == "materialise":
-        if z_star is not None:
-            grad_out = masked_delta(grad_out, z_star, alpha_inv)
-        n, h, w_sp, c = x.shape
-        f = grad_out.shape[-1]
-        k = kernel_size
-        patches = im2col(x, k, k // 2).reshape(n * h * w_sp, k * k * c)
-        g_flat = grad_out.reshape(n * h * w_sp, f)
-        return int_matmul(patches.T, g_flat).reshape(k, k, c, f)
     if backend == "reference":
         return conv_ref.stream_conv_grad_w_ref(
             x, grad_out, kernel_size=kernel_size,
@@ -264,34 +202,24 @@ def conv_grad_w_opt(
     z_star: jax.Array,
     alpha_inv: int = 10,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> jax.Array:
     """Conv weight *update*: ``conv_grad_w`` with IntegerSGD applied in the
     streaming kernel's flush — returns W′ (K,K,C,F), grad_W never in HBM.
 
-    Stream-only: the materialise path's gradient is an HBM matmul result
-    with no flush to fuse into — callers (``grad_ops.conv_weight_update``)
-    take the unfused escape hatch there instead of calling this.
     ``z_star`` is required; a caller without it has pre-masked δ and no
-    prologue, which is also the escape hatch's job.
+    prologue, and composes ``conv_grad_w`` with ``optimizer.apply_update``
+    instead (``grad_ops.conv_weight_update``).
     """
     backend = resolve_backend(backend)
     alpha_inv = check_alpha_inv(alpha_inv, True)
-    conv_mode = resolve_conv_mode(conv_mode)
-    if conv_mode == "materialise":
-        raise ValueError(
-            "conv_grad_w_opt is stream-only: the materialise path has no "
-            "kernel flush to fuse the optimiser into — compute conv_grad_w "
-            "and apply optimizer.apply_update instead"
-        )
     if tiles is None:
         tiles = autotune.resolve_tiles(
             "conv_grad_w",
             (x.shape[0], x.shape[1], x.shape[2], x.shape[3],
              kernel_size, grad_out.shape[-1]),
             dtype=f"{x.dtype},{grad_out.dtype}", backend=backend,
-            conv_mode=conv_mode, fuse_bwd=True, fuse_opt=True,
+            prologue=True, fuse_opt=True,
         )
     if backend == "reference":
         from repro.kernels.integer_sgd.ref import integer_sgd_ref
@@ -316,7 +244,6 @@ def conv_grad_x(
     z_star: jax.Array | None = None,
     alpha_inv: int = 10,
     backend: str = "auto",
-    conv_mode: str = "stream",
     tiles: TileConfig | None = None,
 ) -> jax.Array:
     """Conv input gradient: 'full' correlation of ``grad_out`` with the
@@ -324,28 +251,19 @@ def conv_grad_x(
     activation).  (N,H,W,F) × (K,K,C,F) → (N,H,W,C) int32.
 
     With ``z_star`` the streaming kernel/oracle masks each δ band by the
-    NITRO-ReLU derivative *before* patch formation (the fused backward);
-    the materialise escape hatch pre-masks with jnp, since its im2col
-    reads the full δ from HBM regardless.
+    NITRO-ReLU derivative *before* patch formation (the fused backward).
     """
     backend = resolve_backend(backend)
     if z_star is not None:
         alpha_inv = check_alpha_inv(alpha_inv, True)
-    conv_mode = resolve_conv_mode(conv_mode)
-    if tiles is None and conv_mode != "materialise":
+    if tiles is None:
         tiles = autotune.resolve_tiles(
             "conv_grad_x",
             (grad_out.shape[0], grad_out.shape[1], grad_out.shape[2],
              grad_out.shape[3], w.shape[0], w.shape[2]),
             dtype=f"{grad_out.dtype},{w.dtype}", backend=backend,
-            conv_mode=conv_mode, fuse_bwd=z_star is not None,
+            prologue=z_star is not None,
         )
-    if conv_mode == "materialise":
-        if z_star is not None:
-            grad_out = masked_delta(grad_out, z_star, alpha_inv)
-        n, h, w_sp, _ = grad_out.shape
-        g_patches, w_rot_flat = conv_im2col_operands(conv_ref.rot180_swap(w), grad_out)
-        return int_matmul(g_patches, w_rot_flat).reshape(n, h, w_sp, w.shape[2])
     if backend == "reference":
         return conv_ref.stream_conv_grad_x_ref(
             grad_out, w, z_star=z_star, alpha_inv=alpha_inv,

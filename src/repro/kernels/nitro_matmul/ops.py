@@ -245,7 +245,7 @@ def grad_w_matmul(
         tiles = autotune.resolve_tiles(
             "matmul_grad_w", (x2.shape[0], x2.shape[1], delta2.shape[1]),
             dtype=f"{x2.dtype},{delta2.dtype}", backend=backend,
-            fuse_bwd=True,
+            prologue=True,
         )
     if backend == "reference":
         return nitro_matmul_grad_w_ref(x2, delta2, z_star2, alpha_inv=alpha_inv)
@@ -284,7 +284,7 @@ def grad_w_opt_matmul(
         tiles = autotune.resolve_tiles(
             "matmul_grad_w", (x2.shape[0], x2.shape[1], delta2.shape[1]),
             dtype=f"{x2.dtype},{delta2.dtype}", backend=backend,
-            fuse_bwd=True, fuse_opt=True,
+            prologue=True, fuse_opt=True,
         )
     if backend == "reference":
         from repro.kernels.integer_sgd.ref import integer_sgd_ref
@@ -323,7 +323,7 @@ def grad_x_matmul(
         tiles = autotune.resolve_tiles(
             "matmul_grad_x", (delta2.shape[0], delta2.shape[1], w2.shape[0]),
             dtype=f"{delta2.dtype},{w2.dtype}", backend=backend,
-            fuse_bwd=True,
+            prologue=True,
         )
     if backend == "reference":
         return nitro_matmul_grad_x_ref(delta2, z_star2, w2, alpha_inv=alpha_inv)

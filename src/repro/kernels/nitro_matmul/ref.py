@@ -67,9 +67,10 @@ def masked_delta(delta: jax.Array, z_star: jax.Array, alpha_inv: int) -> jax.Arr
     reference ops: NITRO-ReLU derivative then the scaling STE (identity).
 
     The single jnp definition of that composition — the grad oracles
-    below, the conv dispatcher's materialise pre-mask and ``grad_ops``'s
-    unfused escape hatch all share it, so the fused/unfused parity oracle
-    cannot drift apart across modules.
+    below, the autotuner's pre-masked operands and the tests' unfused δ
+    oracle (``masked_delta`` then ``grad_ops``' ``z_star=None`` route) all
+    share it, so the fused/unfused parity oracle cannot drift apart across
+    modules.
     """
     return scale_backward(nitro_relu_backward(z_star, delta, alpha_inv))
 

@@ -264,15 +264,12 @@ def main():
           f"route={target!r}")
     for row in first.plan.summary():
         hbm = row["hbm_bytes_per_out_elem"]
-        per_sample = row["hbm_per_sample_bytes"]
         print(f"  {row['kind']:<7} w={row['weight_shape']} "
               f"({row['weight_dtype']}) sf={row['sf']} "
               f"act={row['activation_dtype']} "
               f"operands={row['operand_dtype']} pool={row['pool']} "
               f"hbm/elem {hbm['unfused']}B→{hbm['fused']}B "
-              f"hbm/sample {per_sample['materialise']}B→"
-              f"{per_sample['stream']}B "
-              f"({row['stream_saving_ratio']}x stream saving)")
+              f"hbm/sample {row['hbm_per_sample_bytes']}B")
 
     # each request's image is shaped for the arm it will land on, so a
     # fleet of heterogeneous input shapes serves without special-casing

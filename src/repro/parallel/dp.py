@@ -158,10 +158,8 @@ def dp_train_step(
     mesh: Mesh,
     dp_reduce: str = "psum",
     fused: bool = True,
-    fuse_bwd: bool = True,
     fuse_opt: bool = False,
     backend: str = "auto",
-    conv_mode: str = "stream",
     telemetry: bool = False,
 ):
     """One data-parallel NITRO-D step — ``les.train_step`` over a mesh.
@@ -202,8 +200,7 @@ def dp_train_step(
     def _body(state, x, labels, key):
         grads, metrics, aux = les.compute_gradients(
             state, cfg, x, labels, key,
-            fused=fused, fuse_bwd=fuse_bwd, backend=backend,
-            conv_mode=conv_mode, dp_axis=DP_AXIS, dp_shards=n,
+            fused=fused, backend=backend, dp_axis=DP_AXIS, dp_shards=n,
         )
         if telemetry:
             # pre-reduce: the shard-local widths are what hit the wire
@@ -247,10 +244,8 @@ def make_dp_train_step(
     *,
     dp_reduce: str = "psum",
     fused: bool = True,
-    fuse_bwd: bool = True,
     fuse_opt: bool = False,
     backend: str = "auto",
-    conv_mode: str = "stream",
     telemetry: bool = False,
 ):
     """jit-compiled ``dp_train_step`` closure over (cfg, mesh, knobs) —
@@ -259,9 +254,8 @@ def make_dp_train_step(
     def step(state, x, labels, key):
         return dp_train_step(
             state, cfg, x, labels, key,
-            mesh=mesh, dp_reduce=dp_reduce, fused=fused, fuse_bwd=fuse_bwd,
-            fuse_opt=fuse_opt, backend=backend, conv_mode=conv_mode,
-            telemetry=telemetry,
+            mesh=mesh, dp_reduce=dp_reduce, fused=fused, fuse_opt=fuse_opt,
+            backend=backend, telemetry=telemetry,
         )
 
     return jax.jit(step)

@@ -11,7 +11,18 @@ duplicate across ``test_kernels.py``, ``test_fused_training.py`` and
     every unordered backend pairing for A-vs-B parity tests;
   * jaxpr helpers — recursive eqn iteration (optionally skipping Pallas
     kernel bodies), aval-shape collection, the integer-only scan, and the
-    primitive/shape query the backward structural tests use.
+    primitive/shape query the backward structural tests use;
+  * ``unfused_delta_path`` — the oracle the fused δ prologue is held to
+    at block and step level: ``masked_delta`` on the full δ, then
+    ``grad_ops``' ``z_star=None`` route (plain gradient matmuls/convs).
+
+The oracles that the fused kernels are compared against: the
+``backend="reference"`` jnp compositions (whose masked gradients are
+pinned to the unfused composition in ``test_grad_fusion.py``), the
+``z_star=None`` route after ``masked_delta``, the unfused forward
+(``fused=False``: explicit im2col + ``int_matmul``), and
+``jax.lax.conv_general_dilated`` on int32 operands
+(``test_conv_stream.py``).
 
 Import what you need directly (the file is underscore-prefixed so pytest
 does not collect it):
@@ -21,12 +32,17 @@ does not collect it):
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 
 import jax
 from jax.extend import core as jex_core
 import numpy as np
 import pytest
+
+from repro.kernels import grad_ops
+from repro.kernels.nitro_matmul.ref import masked_delta
 
 # Backends runnable on this host: the Pallas interpreter and the jnp
 # oracle run everywhere; the real kernel joins the sweep on TPU.
@@ -66,6 +82,41 @@ def assert_bitwise_equal(got, want, *, err_msg: str = "") -> None:
             f"dtype mismatch at leaf {i}: {g.dtype} vs {w.dtype} {err_msg}"
         )
         np.testing.assert_array_equal(g, w, err_msg=f"leaf {i} {err_msg}")
+
+
+# The grad_ops entry points that take (x, w, δ, ...) and a ``z_star``.
+_DELTA_ENTRY_POINTS = ("linear_grads", "conv_grads",
+                       "linear_weight_update", "conv_weight_update")
+
+
+@contextlib.contextmanager
+def unfused_delta_path():
+    """Run every activation backward through the unfused composition.
+
+    Inside the block, each ``grad_ops`` entry point that gets a
+    ``z_star`` applies ``masked_delta`` (NITRO-ReLU derivative + STE) to
+    the full-size δ first and takes the ``z_star=None`` route: plain
+    gradient matmuls/convs, plus ``optimizer.apply_update`` for the
+    weight-update pair.  Trace (jit) the block- or step-level call inside
+    the ``with``; the layers look the entry points up at trace time.
+    """
+    originals = {name: getattr(grad_ops, name) for name in _DELTA_ENTRY_POINTS}
+
+    def premasked(fn):
+        @functools.wraps(fn)
+        def wrapped(x, w, delta, *args, z_star=None, alpha_inv=10, **kw):
+            if z_star is not None:
+                delta = masked_delta(delta, z_star, alpha_inv)
+            return fn(x, w, delta, *args, alpha_inv=alpha_inv, **kw)
+        return wrapped
+
+    for name, fn in originals.items():
+        setattr(grad_ops, name, premasked(fn))
+    try:
+        yield
+    finally:
+        for name, fn in originals.items():
+            setattr(grad_ops, name, fn)
 
 
 # ---------------------------------------------------------------------------

@@ -204,8 +204,6 @@ class TestTune:
 
     def test_untunable_combinations_return_none(self):
         assert tune("matmul", (8, 8, 8), backend="reference") == (None, {})
-        assert tune("conv_grad_w", (1, 8, 8, 3, 3, 8), backend="reference",
-                    conv_mode="materialise") == (None, {})
 
     @pytest.mark.parametrize("op,shape", [
         ("matmul_fwd", (16, 32, 16)),
@@ -346,8 +344,7 @@ class TestTileInvariance:
         for p in plan_shapes(plan, 4):
             if p["op"] == "conv":  # force a non-default band height
                 cache.put(cache_key(p["op"], p["shape"], p["dtype"],
-                                    "reference", p["conv_mode"],
-                                    p["fuse_bwd"]),
+                                    "reference", p["prologue"]),
                           TileConfig(bh=3))
         configure(cache)
         tuned_plan = compile_plan(fm, backend="reference")
@@ -381,15 +378,14 @@ class TestInt8OperandPath:
         assert_bitwise_equal(got, want)
 
     @pytest.mark.parametrize("backend", ["reference", "interpret"])
-    @pytest.mark.parametrize("conv_mode", ["stream", "materialise"])
-    def test_conv_int8_parity(self, backend, conv_mode):
+    def test_conv_int8_parity(self, backend):
         x8 = _rand((2, 12, 12, 3), jnp.int8, -127, 128, seed=14)
         w8 = _rand((3, 3, 3, 16), jnp.int8, -16, 16, seed=15)
         sf = conv_scale_factor(3, 3)
         want = fused_conv(x8.astype(jnp.int32), w8.astype(jnp.int32),
                           sf=sf, pool=True, backend="reference")
         got = fused_conv(x8, w8, sf=sf, pool=True, backend=backend,
-                         conv_mode=conv_mode, operand_dtype="int8")
+                         operand_dtype="int8")
         assert_bitwise_equal(got, want)
 
     def test_guard_narrows_concrete_fit(self):

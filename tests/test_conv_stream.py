@@ -1,14 +1,16 @@
 """Streaming implicit-im2col conv: kernel/oracle/dispatcher parity.
 
-The tentpole guarantee: retiring the HBM patch matrix changes *nothing*
-numerically.  Every (conv_mode, backend) combination — the Pallas
-streaming kernel (interpret mode off-TPU), the pure-jnp row-band oracle,
-and the materialised im2col escape hatch — produces bit-identical
-activations, caches, gradients, plan logits and post-step parameters,
-over both paper CNN configs, K ∈ {3, 5}, odd H/W edges, pooled and
-unpooled blocks.  On top of parity, the streaming path is held to its
-defining structural property: no (N·H·W, K²·C) patch matrix appears in
-the traced program.
+The guarantee: streaming the patches instead of building the HBM patch
+matrix changes *nothing* numerically.  Every backend — the Pallas
+streaming kernel (interpret mode off-TPU) and the pure-jnp row-band
+oracle — produces activations, caches, gradients, plan logits and
+post-step parameters bit-identical with two independent oracles: the
+explicit-im2col composition of ``repro.core`` (``layers.conv_forward``,
+the unfused forward) and ``jax.lax.conv_general_dilated`` on int32
+operands.  Covered: both paper CNN configs, K ∈ {3, 5}, odd H/W edges,
+pooled and unpooled blocks, folded and banded inputs.  On top of
+parity, the streaming path is held to its defining structural property:
+no (N·H·W, K²·C) patch matrix appears in the traced program.
 """
 
 import functools
@@ -28,6 +30,7 @@ from repro.configs import paper
 from repro.core import activations, layers, les, model as M, scaling
 from repro.core.blocks import BlockSpec
 from repro.core.model import NitroConfig
+from repro.core.numerics import int_matmul
 from repro.core.scaling import conv_scale_factor
 from repro.kernels.nitro_conv import (
     conv_grad_w,
@@ -35,7 +38,6 @@ from repro.kernels.nitro_conv import (
     folds_taps,
     fused_conv,
     fused_conv_fwd,
-    resolve_conv_mode,
     stream_conv,
     stream_conv_fwd,
     stream_conv_fwd_ref,
@@ -44,7 +46,9 @@ from repro.kernels.nitro_conv import (
     stream_conv_grad_x_ref,
     stream_conv_ref,
 )
+from repro.kernels.nitro_conv.ref import rot180_swap
 from repro.kernels.nitro_matmul.ops import check_alpha_inv, fused_matmul
+from repro.kernels.nitro_matmul.ref import masked_delta
 
 
 def _rand_case(n, h, w_sp, c, f, k, seed=0, dtype=jnp.int32):
@@ -65,6 +69,17 @@ def _materialised_conv(x, w, *, sf, alpha_inv=10, apply_relu=True,
     if pool:
         a = jnp.max(layers.window_view_2x2(a), axis=3)
     return a.astype(out_dtype)
+
+
+def _materialised_conv_grads(x, g, w):
+    """Independent oracle for the conv gradients: grad_W = im2colᵀ · g,
+    grad_x = the explicit-im2col conv of g with the rotated kernel."""
+    k = w.shape[0]
+    n, h, w_sp, c = x.shape
+    patches = layers.im2col(x, k, k // 2).reshape(n * h * w_sp, k * k * c)
+    grad_w = int_matmul(patches.T, g.reshape(n * h * w_sp, -1))
+    grad_x, _ = layers.conv_forward({"w": rot180_swap(w)}, g)
+    return grad_x, grad_w.reshape(w.shape)
 
 
 # shape sweep: tile-aligned, odd H/W edges, degenerate smalls, H < band
@@ -129,10 +144,8 @@ class TestStreamOracle:
         g = jnp.asarray(rng.integers(-63, 64, (2, 6, 5, 4)), jnp.int32)
         gw = stream_conv_grad_w_ref(x, g, kernel_size=k)
         gx = stream_conv_grad_x_ref(g, w)
-        gx_want, grads_want = layers.conv_backward(
-            {"w": w}, layers.ConvCache(x=x), g, conv_mode="materialise"
-        )
-        assert_bitwise_equal(gw, grads_want["w"])
+        gx_want, gw_want = _materialised_conv_grads(x, g, w)
+        assert_bitwise_equal(gw, gw_want)
         assert_bitwise_equal(gx, gx_want)
 
 
@@ -199,54 +212,38 @@ class TestStreamKernel:
 
 
 class TestDispatcher:
-    """conv_mode/backend dispatch + the alpha_inv validation satellite."""
+    """Backend dispatch + the alpha_inv validation satellite."""
 
     @pytest.mark.parametrize("pool", [False, True])
     def test_all_routes_agree(self, pool):
         x, w = _rand_case(2, 6, 6, 3, 8, 3, seed=9)
         sf = conv_scale_factor(3, 3)
-        outs = {}
-        for mode in ("stream", "materialise"):
-            for backend in ("reference", "interpret"):
-                outs[(mode, backend)] = fused_conv(
-                    x, w, sf=sf, pool=pool, backend=backend, conv_mode=mode
-                )
-        first = next(iter(outs.values()))
-        for key, out in outs.items():
-            assert_bitwise_equal(out, first, err_msg=str(key))
+        want = _materialised_conv(x, w, sf=sf, pool=pool)
+        for backend in ("reference", "interpret"):
+            got = fused_conv(x, w, sf=sf, pool=pool, backend=backend)
+            assert_bitwise_equal(got, want, err_msg=backend)
 
     def test_fwd_routes_agree(self):
         x, w = _rand_case(2, 7, 7, 3, 8, 3, seed=10)
         sf = conv_scale_factor(3, 3)
-        ref = fused_conv_fwd(x, w, sf=sf, backend="reference",
-                             conv_mode="stream")
-        for mode, backend in [("stream", "interpret"),
-                              ("materialise", "reference")]:
-            got = fused_conv_fwd(x, w, sf=sf, backend=backend, conv_mode=mode)
-            assert_bitwise_equal(got, ref, err_msg=f"{mode}/{backend}")
+        z, _ = layers.conv_forward({"w": w}, x)
+        z_star = scaling.scale_forward(z, sf)
+        want = (activations.nitro_relu(z_star, 10), z_star)
+        for backend in ("reference", "interpret"):
+            got = fused_conv_fwd(x, w, sf=sf, backend=backend)
+            assert_bitwise_equal(got, want, err_msg=backend)
 
     def test_grad_routes_agree(self):
         x, w = _rand_case(2, 6, 6, 3, 4, 3, seed=11)
         rng = np.random.default_rng(11)
         g = jnp.asarray(rng.integers(-63, 64, (2, 6, 6, 4)), jnp.int32)
-        ref_w = conv_grad_w(x, g, kernel_size=3, backend="reference",
-                            conv_mode="materialise")
-        ref_x = conv_grad_x(g, w, backend="reference", conv_mode="materialise")
-        for mode, backend in [("stream", "reference"), ("stream", "interpret")]:
+        ref_x, ref_w = _materialised_conv_grads(x, g, w)
+        for backend in ("reference", "interpret"):
             assert_bitwise_equal(
-                conv_grad_w(x, g, kernel_size=3, backend=backend,
-                            conv_mode=mode),
-                ref_w, err_msg=f"{mode}/{backend}")
+                conv_grad_w(x, g, kernel_size=3, backend=backend),
+                ref_w, err_msg=backend)
             assert_bitwise_equal(
-                conv_grad_x(g, w, backend=backend, conv_mode=mode),
-                ref_x, err_msg=f"{mode}/{backend}")
-
-    def test_unknown_conv_mode_raises(self):
-        with pytest.raises(ValueError, match="unknown conv_mode"):
-            resolve_conv_mode("fuse-everything")
-        x, w = _rand_case(1, 4, 4, 2, 2, 3)
-        with pytest.raises(ValueError, match="unknown conv_mode"):
-            fused_conv(x, w, sf=8, conv_mode="材料")
+                conv_grad_x(g, w, backend=backend), ref_x, err_msg=backend)
 
     def test_even_kernel_rejected_on_stream(self):
         x = jnp.zeros((1, 4, 4, 2), jnp.int32)
@@ -280,8 +277,93 @@ class TestDispatcher:
         assert out.shape == (4, 4)
 
 
+# ---------------------------------------------------------------------------
+# Every streaming entry point ≡ XLA's own integer convolution
+# ---------------------------------------------------------------------------
+
+
+def _lax_conv(x, w):
+    """'same' stride-1 conv on int32 operands, accumulated in int32."""
+    return jax.lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32)
+
+
+def _lax_grad_w(x, g, k):
+    """dW[i,j,c,f] = Σ_{n,h,w} x_pad[n,h+i,w+j,c]·g[n,h,w,f]: a conv whose
+    batch axis is C and whose contraction runs over N and the window."""
+    p = k // 2
+    out = jax.lax.conv_general_dilated(
+        x, g, (1, 1), ((p, p), (p, p)),
+        dimension_numbers=("CHWN", "IHWO", "NHWC"),
+        preferred_element_type=jnp.int32)
+    return out.transpose(1, 2, 0, 3)  # (C,K,K,F) → (K,K,C,F)
+
+
+def _lax_grad_x(g, w):
+    """dx = 'same' conv of g with the flipped kernel, its I/O axes swapped."""
+    return jax.lax.conv_general_dilated(
+        g, w[::-1, ::-1], (1, 1), "SAME",
+        dimension_numbers=("NHWC", "HWOI", "NHWC"),
+        preferred_element_type=jnp.int32)
+
+
+def _lax_pool(a):
+    return jax.lax.reduce_window(a, jnp.iinfo(a.dtype).min, jax.lax.max,
+                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+# C = 3 folds its K×K taps into one contraction (K²·C ≤ 128); C = 16 runs
+# the banded kernels.  The interpreter runs the Pallas bodies at K = 3.
+_LAX_CASES = [
+    pytest.param(entry, k, c, backend, id=f"{entry}-k{k}-c{c}-{backend}")
+    for entry in ("conv", "conv_pool", "conv_fwd", "grad_w", "grad_x")
+    for k in (3, 5)
+    for c in (3, 16)
+    for backend in ("reference", "interpret")
+    if backend == "reference" or k == 3
+]
+
+
+class TestLaxConvParity:
+    """An oracle independent of im2col: ``lax.conv_general_dilated``."""
+
+    @pytest.mark.parametrize("entry,k,c,backend", _LAX_CASES)
+    def test_matches_lax_conv(self, entry, k, c, backend):
+        n, h, w_sp, f = 2, 8, 7, 8
+        x, w = _rand_case(n, h, w_sp, c, f, k, seed=k * 100 + c)
+        assert folds_taps(k, c) == (c == 3)
+        if entry.startswith("grad"):
+            rng = np.random.default_rng(k + c)
+            delta = jnp.asarray(rng.integers(-63, 64, (n, h, w_sp, f)),
+                                jnp.int32)
+            z_star = jnp.asarray(rng.integers(-300, 301, (n, h, w_sp, f)),
+                                 jnp.int32)
+            g = masked_delta(delta, z_star, 10)
+            if entry == "grad_w":
+                got = conv_grad_w(x, delta, kernel_size=k, z_star=z_star,
+                                  backend=backend)
+                want = _lax_grad_w(x, g, k)
+            else:
+                got = conv_grad_x(delta, w, z_star=z_star, backend=backend)
+                want = _lax_grad_x(g, w)
+        else:
+            sf = conv_scale_factor(k, c)
+            z_star = scaling.scale_forward(_lax_conv(x, w), sf)
+            a = activations.nitro_relu(z_star, 10)
+            if entry == "conv_fwd":
+                got = fused_conv_fwd(x, w, sf=sf, backend=backend)
+                want = (a, z_star)
+            else:
+                pool = entry == "conv_pool"
+                got = fused_conv(x, w, sf=sf, pool=pool, backend=backend)
+                want = _lax_pool(a) if pool else a
+        assert_bitwise_equal(got, want, err_msg=f"{entry} K={k} C={c}")
+
+
 class TestTrainingParity:
-    """forward_layers / train_step across conv modes on the paper configs."""
+    """Streaming forward_layers / train_step ≡ the explicit-im2col
+    composition (``fused=False``) on the paper configs."""
 
     @pytest.mark.parametrize("arch", ["vgg8b", "vgg11b"])
     def test_forward_stream_bit_exact_on_paper_cnn(self, arch):
@@ -291,18 +373,16 @@ class TestTrainingParity:
         x = jnp.asarray(
             rng.integers(-127, 128, (4, *cfg.input_shape)), jnp.int32
         )
-        outs = {
-            mode: M.forward(state.params, cfg, x, train=False, fused=True,
-                            conv_mode=mode)
-            for mode in ("stream", "materialise")
-        }
+        y, acts, caches, _ = M.forward(state.params, cfg, x, train=False,
+                                       fused=True)
         unfused = M.forward(state.params, cfg, x, train=False, fused=False)
-        for mode, (y, acts, caches, _) in outs.items():
-            assert_bitwise_equal(y, unfused[0], err_msg=mode)
-            for a_m, a_u, c_m, c_u in zip(acts, unfused[1], caches, unfused[2]):
-                assert_bitwise_equal(a_m, a_u, err_msg=mode)
-                assert_bitwise_equal(c_m["z_star"], c_u["z_star"],
-                                     err_msg=mode)
+        assert_bitwise_equal(y, unfused[0])
+        for i, (a_s, a_u, c_s, c_u) in enumerate(
+            zip(acts, unfused[1], caches, unfused[2])
+        ):
+            assert_bitwise_equal(a_s, a_u, err_msg=f"block {i}")
+            assert_bitwise_equal(c_s["z_star"], c_u["z_star"],
+                                 err_msg=f"block {i}")
 
     @pytest.mark.parametrize("kernel_size", [3, 5])
     def test_k5_block_and_odd_input(self, kernel_size):
@@ -316,14 +396,11 @@ class TestTrainingParity:
         state = les.create_train_state(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.integers(-127, 128, (3, 9, 9, 3)), jnp.int32)
-        y_s, _, c_s, _ = M.forward(state.params, cfg, x, conv_mode="stream")
-        y_m, _, c_m, _ = M.forward(state.params, cfg, x,
-                                   conv_mode="materialise")
-        y_u, _, _, _ = M.forward(state.params, cfg, x, fused=False)
-        np.testing.assert_array_equal(np.asarray(y_s), np.asarray(y_m))
+        y_s, _, c_s, _ = M.forward(state.params, cfg, x)
+        y_u, _, c_u, _ = M.forward(state.params, cfg, x, fused=False)
         np.testing.assert_array_equal(np.asarray(y_s), np.asarray(y_u))
         np.testing.assert_array_equal(
-            np.asarray(c_s[0]["z_star"]), np.asarray(c_m[0]["z_star"])
+            np.asarray(c_s[0]["z_star"]), np.asarray(c_u[0]["z_star"])
         )
 
     def test_train_step_stream_bit_exact(self):
@@ -336,24 +413,22 @@ class TestTrainingParity:
         y = jnp.asarray(rng.integers(0, cfg.num_classes, 8), jnp.int32)
         key = jax.random.PRNGKey(9)
         stepped = {
-            mode: jax.jit(functools.partial(
-                les.train_step, cfg=cfg, conv_mode=mode
+            fused: jax.jit(functools.partial(
+                les.train_step, cfg=cfg, fused=fused
             ))(st, x=x, labels=y, key=key)
-            for mode in ("stream", "materialise")
+            for fused in (True, False)
         }
-        assert_bitwise_equal(stepped["stream"][0].params,
-                             stepped["materialise"][0].params)
-        assert int(stepped["stream"][1].loss) == int(stepped["materialise"][1].loss)
+        assert_bitwise_equal(stepped[True][0].params, stepped[False][0].params)
+        assert int(stepped[True][1].loss) == int(stepped[False][1].loss)
 
     def test_conv_backward_modes_agree(self):
         x, w = _rand_case(2, 8, 6, 3, 8, 3, seed=12)
         rng = np.random.default_rng(12)
         g = jnp.asarray(rng.integers(-63, 64, (2, 8, 6, 8)), jnp.int32)
         cache = layers.ConvCache(x=x)
-        stream = layers.conv_backward({"w": w}, cache, g, conv_mode="stream")
-        materialise = layers.conv_backward({"w": w}, cache, g,
-                                           conv_mode="materialise")
-        assert_bitwise_equal(stream, materialise)
+        grad_x, grads = layers.conv_backward({"w": w}, cache, g)
+        assert_bitwise_equal((grad_x, grads["w"]),
+                             _materialised_conv_grads(x, g, w))
 
 
 # ---------------------------------------------------------------------------
@@ -376,8 +451,8 @@ class TestStructural:
     def test_no_patch_matrix_in_stream_fwd(self, backend, c):
         """Acceptance criterion: the (N·H·W, K²·C) patch matrix must not
         appear anywhere in the streaming program — including inside the
-        Pallas kernel body — while the materialised path (sanity check)
-        does produce it.  A shallow input whose taps fold (C = 8: 72
+        Pallas kernel body — while the explicit-im2col oracle (sanity
+        check) does produce it.  A shallow input whose taps fold (C = 8: 72
         lanes) may hold only the 4-D (N,H,W,K²·C) patches the folded 1×1
         conv reads: no more HBM than the lane-padded input it replaces."""
         n, h, w_sp, f, k = 4, 16, 16, 8, 3
@@ -385,13 +460,11 @@ class TestStructural:
         sf = conv_scale_factor(k, c)
         patch_shapes = self._patch_shapes(n, h, w_sp, c, k)
 
-        def trace(mode):
-            jaxpr = jax.make_jaxpr(functools.partial(
-                fused_conv, sf=sf, backend=backend, conv_mode=mode
-            ))(x, w)
-            return collect_aval_shapes(jaxpr.jaxpr)
+        def trace(fn):
+            return collect_aval_shapes(jax.make_jaxpr(fn)(x, w).jaxpr)
 
-        found = patch_shapes & trace("stream")
+        found = patch_shapes & trace(
+            functools.partial(fused_conv, sf=sf, backend=backend))
         if folds_taps(k, c):
             assert k * k * c <= 128
             assert found <= {(n, h, w_sp, k * k * c)}, (
@@ -401,15 +474,17 @@ class TestStructural:
             assert not found, (
                 "streaming path materialised a full-size patch matrix"
             )
-        assert patch_shapes & trace("materialise"), (
-            "sanity: materialised path should contain the patch matrix"
+        assert patch_shapes & trace(
+            functools.partial(_materialised_conv, sf=sf)), (
+            "sanity: the explicit-im2col oracle should contain the patch "
+            "matrix"
         )
 
     def test_no_patch_matrix_in_stream_plan(self):
         """Same property end-to-end through a compiled multi-layer plan:
         none of the per-layer (N·Hℓ·Wℓ, K²·Cℓ) full patch sizes may appear
-        in the streaming program, while the materialised one (sanity)
-        contains every one of them."""
+        in the streaming program, while the explicit-im2col frozen forward
+        (sanity) contains every one of them."""
         from repro.infer.export import freeze
         from repro.infer.plan import _execute, compile_plan
 
@@ -432,25 +507,25 @@ class TestStructural:
             if spec.pool:
                 h, w_sp = h // 2, w_sp // 2
 
-        for mode, expect_patch in (("stream", False), ("materialise", True)):
-            plan = compile_plan(fm, backend="reference", conv_mode=mode)
-            jaxpr = jax.make_jaxpr(functools.partial(
-                _execute, metas=plan.metas, backend=plan.backend
-            ))(plan.weights, x)
-            shapes = collect_aval_shapes(jaxpr.jaxpr)
-            if expect_patch:
-                assert flat_patches <= shapes, "sanity: patches expected"
-            else:
-                assert not (patch_shapes & shapes), (
-                    "streaming plan materialised a full patch matrix"
-                )
+        plan = compile_plan(fm, backend="reference")
+        jaxpr = jax.make_jaxpr(functools.partial(
+            _execute, metas=plan.metas, backend=plan.backend
+        ))(plan.weights, x)
+        assert not (patch_shapes & collect_aval_shapes(jaxpr.jaxpr)), (
+            "streaming plan materialised a full patch matrix"
+        )
+        oracle = jax.make_jaxpr(functools.partial(
+            M.frozen_forward, cfg=cfg
+        ))(state.params, x=x)
+        assert flat_patches <= collect_aval_shapes(oracle.jaxpr), (
+            "sanity: patches expected"
+        )
 
-    @pytest.mark.parametrize("conv_mode,backend", [
-        ("stream", "auto"),        # the default train path
-        ("stream", "interpret"),   # the actual Pallas kernel bodies, off-TPU
-        ("materialise", "auto"),   # explicit-im2col escape hatch
+    @pytest.mark.parametrize("backend", [
+        "auto",        # the default train path
+        "interpret",   # the actual Pallas kernel bodies, off-TPU
     ])
-    def test_train_step_integer_only(self, conv_mode, backend):
+    def test_train_step_integer_only(self, backend):
         """No float dtype anywhere in the traced step — descending into the
         streaming conv kernel bodies (fwd + grad_w + grad_x)."""
         cfg = NitroConfig(
@@ -465,7 +540,7 @@ class TestStructural:
         y = jnp.asarray(rng.integers(0, 10, 8), jnp.int32)
         jaxpr = jax.make_jaxpr(
             functools.partial(les.train_step, cfg=cfg, fused=True,
-                              backend=backend, conv_mode=conv_mode)
+                              backend=backend)
         )(st, x=x, labels=y, key=jax.random.PRNGKey(1))
         assert_jaxpr_integer_only(jaxpr.jaxpr)
 
@@ -474,7 +549,7 @@ class TestPlanStream:
     @pytest.mark.parametrize("arch", ["vgg8b", "vgg11b"])
     @pytest.mark.parametrize("backend", ["reference", "interpret"])
     def test_plan_parity_on_paper_cnn(self, arch, backend):
-        """Streaming plan ≡ materialised plan ≡ frozen_forward oracle."""
+        """Streaming plan ≡ the explicit-im2col frozen_forward oracle."""
         from repro.infer.export import freeze
         from repro.infer.plan import compile_plan
 
@@ -486,12 +561,11 @@ class TestPlanStream:
             rng.integers(-127, 128, (2, *cfg.input_shape)), jnp.int32
         )
         want = M.frozen_forward(state.params, cfg, x)
-        for mode in ("stream", "materialise"):
-            plan = compile_plan(fm, backend=backend, conv_mode=mode)
-            np.testing.assert_array_equal(
-                np.asarray(plan.logits(x)), np.asarray(want),
-                err_msg=f"{arch}/{backend}/{mode}",
-            )
+        plan = compile_plan(fm, backend=backend)
+        np.testing.assert_array_equal(
+            np.asarray(plan.logits(x)), np.asarray(want),
+            err_msg=f"{arch}/{backend}",
+        )
 
     def test_step_meta_describes_fusion(self):
         from repro.infer.export import freeze
@@ -500,22 +574,18 @@ class TestPlanStream:
         cfg = paper.get("vgg8b", scale=0.0625)
         state = les.create_train_state(jax.random.PRNGKey(0), cfg)
         fm = freeze(state, cfg)
-        plan = compile_plan(fm, backend="reference", conv_mode="stream")
+        plan = compile_plan(fm, backend="reference")
         for meta, spec in zip(plan.metas, cfg.blocks):
             if spec.kind == "conv":
-                assert meta.conv_mode == "stream"
                 assert meta.fused_pool == spec.pool
                 assert meta.kernel_size == spec.kernel_size
             else:
-                assert meta.conv_mode == ""
+                assert meta.kernel_size == 0
                 assert not meta.fused_pool
-        mat = compile_plan(fm, backend="reference", conv_mode="materialise")
-        assert all(not m.fused_pool for m in mat.metas)
 
     def test_summary_counts_patch_traffic(self):
-        """Satellite: conv rows must account the im2col patch round-trip
-        (~2K²·input bytes) in the materialised estimate and report the
-        per-layer streaming delta."""
+        """Every row estimates the streaming traffic: the step's input read
+        once plus its (pooled) output written once — no patch bytes."""
         from repro.infer.export import freeze
         from repro.infer.plan import compile_plan
 
@@ -524,22 +594,20 @@ class TestPlanStream:
         plan = compile_plan(freeze(state, cfg), backend="reference")
         shape = cfg.input_shape
         in_itemsize = 4
-        for row, meta in zip(plan.summary(), plan.metas):
-            per_sample = row["hbm_per_sample_bytes"]
+        rows = plan.summary()
+        assert len(rows) == len(plan.metas)
+        for row, meta in zip(rows, plan.metas):
+            out_itemsize = jnp.dtype(meta.out_dtype).itemsize
+            in_bytes = int(np.prod(shape)) * in_itemsize
+            f = row["weight_shape"][-1]
             if row["kind"] == "conv":
-                h, w_sp, c = shape
-                k = meta.kernel_size
-                in_bytes = h * w_sp * c * in_itemsize
-                # materialised estimate includes patch write + read back
-                assert per_sample["materialise"] >= 2 * k * k * in_bytes
-                assert per_sample["stream"] < per_sample["materialise"]
-                assert row["stream_saving_ratio"] > k  # ≈K², conservatively >K
-                f = row["weight_shape"][-1]
+                h, w_sp, _ = shape
                 shape = (h // 2, w_sp // 2, f) if meta.pool else (h, w_sp, f)
             else:
-                assert row["stream_saving_ratio"] == 1.0
-                shape = (row["weight_shape"][-1],)
-            in_itemsize = jnp.dtype(meta.out_dtype).itemsize
+                shape = (f,)
+            out_bytes = int(np.prod(shape)) * out_itemsize
+            assert row["hbm_per_sample_bytes"] == in_bytes + out_bytes, row
+            in_itemsize = out_itemsize
 
     def test_window_view_public_name(self):
         """Satellite: the pool window helper is public API now."""

@@ -7,8 +7,8 @@ an order-exact int32 accumulation is exact, so
 
     fused-epilogue step  ≡  compute_gradients → apply_gradients
 
-bit for bit, on both paper configs, every runnable backend, both conv
-data paths, over multi-step trajectories.  On top of parity, the fused
+bit for bit, on both paper configs, every runnable backend, over
+multi-step trajectories.  On top of parity, the fused
 path is held to its structural claims: no full-size grad_W-shaped
 floor-division output exists outside a Pallas kernel body, and the whole
 fused-opt step stays float-free.
@@ -40,6 +40,7 @@ from repro.core.model import NitroConfig
 from repro.kernels import grad_ops
 from repro.kernels.nitro_conv import conv_grad_w, conv_grad_w_opt
 from repro.kernels.nitro_matmul import grad_w_matmul, grad_w_opt_matmul
+from repro.kernels.nitro_matmul.ref import masked_delta
 
 
 def _linear_case(b, m, n, seed=0):
@@ -95,19 +96,21 @@ class TestLinearOptEpilogue:
         assert_bitwise_equal(a, b, err_msg=str(backend_pair))
 
     def test_dispatcher_escape_hatches(self, kernel_backend):
-        """z_star=None and fuse_bwd=False route through the materialised
-        gradient + ``opt.apply_update`` — same result, bitwise."""
+        """z_star=None routes through the materialised gradient +
+        ``opt.apply_update``: fed the pre-masked δ it equals the fused
+        epilogue, bitwise."""
         x, delta, z_star, w = _linear_case(8, 32, 16, seed=3)
         want_gx, want_w = grad_ops.linear_weight_update(
             x, w, delta, OPT, z_star=z_star, backend=kernel_backend
         )
-        for kw in (dict(z_star=z_star, fuse_bwd=False), dict(z_star=None)):
+        g = masked_delta(delta, z_star, 10)
+        for d in (g, delta):
             got_gx, got_w = grad_ops.linear_weight_update(
-                x, w, delta, OPT, backend=kernel_backend, **kw
+                x, w, d, OPT, backend=kernel_backend
             )
-            if kw.get("z_star") is not None:
-                assert_bitwise_equal(got_w, want_w, err_msg=str(kw))
-                assert_bitwise_equal(got_gx, want_gx, err_msg=str(kw))
+            if d is g:
+                assert_bitwise_equal(got_w, want_w, err_msg="pre-masked")
+                assert_bitwise_equal(got_gx, want_gx, err_msg="pre-masked")
             else:
                 # no z*: STE-only backward — different math by design;
                 # still must equal its own grad-then-update composition
@@ -141,32 +144,19 @@ class TestConvOptEpilogue:
         )
         assert_bitwise_equal(a, b, err_msg=str(backend_pair))
 
-    def test_materialise_mode_rejected(self):
-        """No kernel flush to fuse into — the dispatcher refuses rather
-        than silently downgrading."""
-        x, delta, z_star, w = _conv_case(1, 4, 4, 2, 4, 3, seed=6)
-        with pytest.raises(ValueError, match="stream-only"):
-            conv_grad_w_opt(
-                x, delta, w, OPT.gamma_inv, OPT.eta_inv,
-                kernel_size=3, z_star=z_star, conv_mode="materialise",
-            )
-
-    @pytest.mark.parametrize("kw", [
-        dict(fuse_bwd=False), dict(conv_mode="materialise")
-    ], ids=["unfused-bwd", "materialise"])
-    def test_weight_update_escape_hatches(self, kernel_backend, kw):
-        """``conv_weight_update`` takes the grad-then-update hatch for
-        unfused-bwd and materialise mode — bitwise equal to the fused
-        stream path."""
+    def test_weight_update_escape_hatches(self, kernel_backend):
+        """``conv_weight_update`` without ``z_star`` takes the
+        grad-then-update hatch: fed the pre-masked δ it is bitwise equal
+        to the fused epilogue."""
         x, delta, z_star, w = _conv_case(2, 8, 6, 3, 8, 3, seed=7)
         want_gx, want_w = grad_ops.conv_weight_update(
             x, w, delta, OPT, z_star=z_star, backend=kernel_backend
         )
         got_gx, got_w = grad_ops.conv_weight_update(
-            x, w, delta, OPT, z_star=z_star, backend=kernel_backend, **kw
+            x, w, masked_delta(delta, z_star, 10), OPT, backend=kernel_backend
         )
-        assert_bitwise_equal(got_w, want_w, err_msg=str(kw))
-        assert_bitwise_equal(got_gx, want_gx, err_msg=str(kw))
+        assert_bitwise_equal(got_w, want_w)
+        assert_bitwise_equal(got_gx, want_gx)
 
 
 # ---------------------------------------------------------------------------
@@ -184,29 +174,26 @@ def _step_args(cfg, batch, seed=4):
 
 
 class TestTrainStepFuseOptParity:
-    @pytest.mark.parametrize("conv_mode", ["stream", "materialise"])
     @pytest.mark.parametrize("arch,batch", [("vgg8b", 4), ("vgg11b", 2)])
-    def test_multi_step_trajectory_bit_exact(self, arch, batch, conv_mode,
+    def test_multi_step_trajectory_bit_exact(self, arch, batch,
                                              kernel_backend):
         """Acceptance criterion: multi-step fuse_opt trajectory ≡ the
-        split composition on both paper configs, every runnable backend,
-        both conv data paths.  Divergence compounds, so trajectory
-        equality is strictly stronger than single-step equality."""
+        split composition on both paper configs, every runnable backend.
+        Divergence compounds, so trajectory equality is strictly stronger
+        than single-step equality."""
         cfg = paper.get(arch, scale=0.0625)
         st_f, x, y = _step_args(cfg, batch)
         st_u = st_f
         step_f = jax.jit(functools.partial(
-            les.train_step, cfg=cfg, fuse_opt=True,
-            backend=kernel_backend, conv_mode=conv_mode))
+            les.train_step, cfg=cfg, fuse_opt=True, backend=kernel_backend))
         step_u = jax.jit(functools.partial(
-            les.train_step, cfg=cfg, fuse_opt=False,
-            backend=kernel_backend, conv_mode=conv_mode))
+            les.train_step, cfg=cfg, fuse_opt=False, backend=kernel_backend))
         for i in range(3):
             k = jax.random.PRNGKey(i)
             st_f, m_f = step_f(st_f, x=x, labels=y, key=k)
             st_u, m_u = step_u(st_u, x=x, labels=y, key=k)
         assert_bitwise_equal(st_f, st_u,
-                             err_msg=f"{arch}/{conv_mode}/{kernel_backend}")
+                             err_msg=f"{arch}/{kernel_backend}")
         assert_bitwise_equal(m_f, m_u)
 
     def test_unfused_forward_also_exact(self):

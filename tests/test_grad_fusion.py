@@ -3,11 +3,13 @@
 The tentpole guarantee: folding the NITRO-ReLU derivative and the scaling
 STE into the gradient kernels' δ prologue changes *nothing* numerically —
 weight gradients, input gradients and post-step parameters are bit-
-identical with the unfused jnp composition, on both paper CNN configs,
-for every backend runnable on this host and both conv data paths.  On
-top of parity, the fused backward is held to its structural property: the
-full-size post-ReLU-bwd δ tensor never appears outside a Pallas kernel
-body in the traced program, and the whole fused step stays float-free.
+identical with the unfused jnp composition (``masked_delta`` on the full
+δ, then the ``z_star=None`` route — ``_gradcheck.unfused_delta_path`` at
+block and step level), on both paper CNN configs, for every backend
+runnable on this host.  On top of parity, the fused backward is held to
+its structural property: the full-size post-ReLU-bwd δ tensor never
+appears outside a Pallas kernel body in the traced program, and the
+whole fused step stays float-free.
 
 All parity assertions go through the shared harness in
 ``tests/_gradcheck.py``.
@@ -27,6 +29,7 @@ from _gradcheck import (  # noqa: F401  (fixtures)
     backend_pair,
     eqn_output_shapes,
     kernel_backend,
+    unfused_delta_path,
 )
 from repro.configs import paper
 from repro.core import blocks as B
@@ -52,6 +55,7 @@ from repro.kernels.nitro_conv import (
     stream_conv_grad_x,
     stream_conv_grad_x_ref,
 )
+from repro.kernels.nitro_matmul.ref import masked_delta
 
 
 def _linear_case(b, m, n, seed=0):
@@ -214,22 +218,20 @@ class TestConvGradKernels:
         )
 
     def test_dispatcher_modes_and_backends_agree(self, backend_pair):
+        """Both δ routes — prologue and pre-masked — agree across backends."""
         a, b = backend_pair
         x, delta, z_star, w = _conv_case(2, 6, 6, 3, 8, 3, seed=11)
-        for mode in ("stream", "materialise"):
+        g = masked_delta(delta, z_star, 10)
+        for route, d, z in (("prologue", delta, z_star), ("premasked", g, None)):
             assert_bitwise_equal(
-                conv_grad_w(x, delta, kernel_size=3, z_star=z_star,
-                            backend=a, conv_mode=mode),
-                conv_grad_w(x, delta, kernel_size=3, z_star=z_star,
-                            backend=b, conv_mode=mode),
-                err_msg=f"grad_w {mode} {a} vs {b}",
+                conv_grad_w(x, d, kernel_size=3, z_star=z, backend=a),
+                conv_grad_w(x, d, kernel_size=3, z_star=z, backend=b),
+                err_msg=f"grad_w {route} {a} vs {b}",
             )
             assert_bitwise_equal(
-                conv_grad_x(delta, w, z_star=z_star,
-                            backend=a, conv_mode=mode),
-                conv_grad_x(delta, w, z_star=z_star,
-                            backend=b, conv_mode=mode),
-                err_msg=f"grad_x {mode} {a} vs {b}",
+                conv_grad_x(d, w, z_star=z, backend=a),
+                conv_grad_x(d, w, z_star=z, backend=b),
+                err_msg=f"grad_x {route} {a} vs {b}",
             )
 
 
@@ -240,28 +242,25 @@ class TestConvGradKernels:
 
 class TestGradOpsDispatcher:
     def test_linear_fused_vs_unfused(self, kernel_backend):
+        """Fused δ prologue ≡ ``masked_delta`` then the z_star=None route."""
         x, delta, z_star, w = _linear_case(12, 40, 24, seed=1)
         fused = grad_ops.linear_grads(
-            x, w, delta, z_star=z_star, fuse_bwd=True, backend=kernel_backend
+            x, w, delta, z_star=z_star, backend=kernel_backend
         )
         unfused = grad_ops.linear_grads(
-            x, w, delta, z_star=z_star, fuse_bwd=False, backend=kernel_backend
+            x, w, masked_delta(delta, z_star, 10), backend=kernel_backend
         )
         assert_bitwise_equal(fused, unfused, err_msg=kernel_backend)
 
-    @pytest.mark.parametrize("conv_mode", ["stream", "materialise"])
-    def test_conv_fused_vs_unfused(self, kernel_backend, conv_mode):
+    def test_conv_fused_vs_unfused(self, kernel_backend):
         x, delta, z_star, w = _conv_case(2, 8, 6, 3, 8, 3, seed=2)
         fused = grad_ops.conv_grads(
-            x, w, delta, z_star=z_star, fuse_bwd=True,
-            backend=kernel_backend, conv_mode=conv_mode,
+            x, w, delta, z_star=z_star, backend=kernel_backend,
         )
         unfused = grad_ops.conv_grads(
-            x, w, delta, z_star=z_star, fuse_bwd=False,
-            backend=kernel_backend, conv_mode=conv_mode,
+            x, w, masked_delta(delta, z_star, 10), backend=kernel_backend,
         )
-        assert_bitwise_equal(fused, unfused,
-                             err_msg=f"{kernel_backend}/{conv_mode}")
+        assert_bitwise_equal(fused, unfused, err_msg=kernel_backend)
 
     def test_no_activation_path_is_plain_matmuls(self):
         """z_star=None (learning/output layers: STE only) must reproduce
@@ -294,16 +293,18 @@ def _block_cases(cfg, batch, seed=7):
 class TestForwardLayersBackwardParity:
     @pytest.mark.parametrize("arch", ["vgg8b", "vgg11b"])
     def test_fused_backward_bit_exact_on_paper_cnn(self, arch, kernel_backend):
-        """Acceptance criterion: fuse_bwd=True ≡ fuse_bwd=False through
-        every block of both paper CNNs, on every runnable backend."""
+        """Acceptance criterion: the fused δ prologue ≡ the unfused δ
+        composition through every block of both paper CNNs, on every
+        runnable backend."""
         cfg = paper.get(arch, scale=0.0625)
         for i, (spec, p, cache, delta) in enumerate(_block_cases(cfg, 2)):
             fused = B.forward_layers_backward(
-                p, spec, cache, delta, backend=kernel_backend, fuse_bwd=True
+                p, spec, cache, delta, backend=kernel_backend
             )
-            unfused = B.forward_layers_backward(
-                p, spec, cache, delta, backend=kernel_backend, fuse_bwd=False
-            )
+            with unfused_delta_path():
+                unfused = B.forward_layers_backward(
+                    p, spec, cache, delta, backend=kernel_backend
+                )
             assert_bitwise_equal(
                 fused, unfused, err_msg=f"{arch} block {i} {kernel_backend}"
             )
@@ -321,10 +322,9 @@ class TestForwardLayersBackwardParity:
             p, spec, x, train=True, dropout_key=jax.random.PRNGKey(5)
         )
         delta = jnp.asarray(rng.integers(-63, 64, (3, 4, 4, 12)), jnp.int32)
-        fused = B.forward_layers_backward(p, spec, cache, delta,
-                                          fuse_bwd=True)
-        unfused = B.forward_layers_backward(p, spec, cache, delta,
-                                            fuse_bwd=False)
+        fused = B.forward_layers_backward(p, spec, cache, delta)
+        with unfused_delta_path():
+            unfused = B.forward_layers_backward(p, spec, cache, delta)
         assert_bitwise_equal(fused, unfused)
 
 
@@ -342,12 +342,11 @@ class TestTrainStepBackwardParity:
     def test_fused_bwd_step_bit_exact(self, arch, batch):
         cfg = paper.get(arch, scale=0.0625)
         st, x, y, key = self._step_args(cfg, batch)
-        stepped = {
-            fb: jax.jit(functools.partial(les.train_step, cfg=cfg,
-                                          fuse_bwd=fb))(st, x=x, labels=y,
-                                                        key=key)
-            for fb in (True, False)
-        }
+        stepped = {True: jax.jit(functools.partial(les.train_step, cfg=cfg))(
+            st, x=x, labels=y, key=key)}
+        with unfused_delta_path():
+            stepped[False] = jax.jit(functools.partial(
+                les.train_step, cfg=cfg))(st, x=x, labels=y, key=key)
         assert_bitwise_equal(stepped[True][0].params, stepped[False][0].params,
                              err_msg=arch)
         assert int(stepped[True][1].loss) == int(stepped[False][1].loss)
@@ -359,11 +358,12 @@ class TestTrainStepBackwardParity:
         cfg = paper.get("vgg8b", scale=0.0625)
         st, x, y, key = self._step_args(cfg, 4)
         got = jax.jit(functools.partial(
-            les.train_step, cfg=cfg, fuse_bwd=True, backend="interpret"
+            les.train_step, cfg=cfg, backend="interpret"
         ))(st, x=x, labels=y, key=key)
-        want = jax.jit(functools.partial(
-            les.train_step, cfg=cfg, fuse_bwd=False
-        ))(st, x=x, labels=y, key=key)
+        with unfused_delta_path():
+            want = jax.jit(functools.partial(
+                les.train_step, cfg=cfg, backend="reference"
+            ))(st, x=x, labels=y, key=key)
         assert_bitwise_equal(got[0].params, want[0].params)
 
     def test_multi_step_training_stays_exact(self):
@@ -378,14 +378,13 @@ class TestTrainStepBackwardParity:
         x = jnp.asarray(rng.integers(-127, 128, (16, 8, 8, 3)), jnp.int32)
         y = jnp.asarray(rng.integers(0, 10, 16), jnp.int32)
         st_f = st_u = les.create_train_state(jax.random.PRNGKey(0), cfg)
-        step_f = jax.jit(functools.partial(les.train_step, cfg=cfg,
-                                           fuse_bwd=True))
-        step_u = jax.jit(functools.partial(les.train_step, cfg=cfg,
-                                           fuse_bwd=False))
+        step_f = jax.jit(functools.partial(les.train_step, cfg=cfg))
+        step_u = jax.jit(functools.partial(les.train_step, cfg=cfg))
         for i in range(8):
             k = jax.random.PRNGKey(i)
             st_f, _ = step_f(st_f, x=x, labels=y, key=k)
-            st_u, _ = step_u(st_u, x=x, labels=y, key=k)
+            with unfused_delta_path():  # traced on the first call
+                st_u, _ = step_u(st_u, x=x, labels=y, key=k)
         assert_bitwise_equal(st_f.params, st_u.params)
 
 
@@ -422,12 +421,11 @@ def _zstar_shapes(cfg, batch):
 
 
 class TestBackwardStructure:
-    @pytest.mark.parametrize("fuse_bwd,backend", [
-        (True, "auto"),        # the default train path
-        (True, "interpret"),   # the actual grad-kernel bodies, off-TPU
-        (False, "auto"),       # unfused δ escape hatch
+    @pytest.mark.parametrize("backend", [
+        "auto",        # the default train path
+        "interpret",   # the actual grad-kernel bodies, off-TPU
     ])
-    def test_fused_bwd_step_is_integer_only(self, fuse_bwd, backend):
+    def test_fused_bwd_step_is_integer_only(self, backend):
         """Acceptance criterion: the fused-backward train step is float-free
         end-to-end, descending into every Pallas kernel body."""
         cfg = NitroConfig(
@@ -441,7 +439,7 @@ class TestBackwardStructure:
         x = jnp.asarray(rng.integers(-127, 128, (8, 8, 8, 3)), jnp.int32)
         y = jnp.asarray(rng.integers(0, 10, 8), jnp.int32)
         jaxpr = jax.make_jaxpr(functools.partial(
-            les.train_step, cfg=cfg, fuse_bwd=fuse_bwd, backend=backend
+            les.train_step, cfg=cfg, backend=backend
         ))(st, x=x, labels=y, key=jax.random.PRNGKey(1))
         assert_jaxpr_integer_only(jaxpr.jaxpr)
 
@@ -449,7 +447,7 @@ class TestBackwardStructure:
         """Acceptance criterion: in the fused step, no ReLU-backward op
         (select/rem) produces a full-size z*-shaped tensor anywhere outside
         a Pallas kernel body — the masked δ exists only as VMEM tiles.  The
-        unfused step (sanity) does materialise it."""
+        unfused δ composition (sanity) does materialise it."""
         cfg = _structural_cfg()
         batch = 6
         st = les.create_train_state(jax.random.PRNGKey(0), cfg)
@@ -458,19 +456,20 @@ class TestBackwardStructure:
         y = jnp.asarray(rng.integers(0, 10, batch), jnp.int32)
         zstar_shapes = _zstar_shapes(cfg, batch)
 
-        def mask_shapes(fuse_bwd):
+        def mask_shapes():
             jaxpr = jax.make_jaxpr(functools.partial(
-                les.train_step, cfg=cfg, fuse_bwd=fuse_bwd,
-                backend="interpret",
+                les.train_step, cfg=cfg, backend="interpret",
             ))(st, x=x, labels=y, key=jax.random.PRNGKey(1))
             return set(
                 eqn_output_shapes(jaxpr.jaxpr, _MASK_PRIMS, skip_pallas=True)
             )
 
-        assert not (mask_shapes(True) & zstar_shapes), (
+        assert not (mask_shapes() & zstar_shapes), (
             "fused backward materialised a full-size post-ReLU-bwd δ"
         )
-        assert mask_shapes(False) & zstar_shapes, (
+        with unfused_delta_path():
+            unfused = mask_shapes()
+        assert unfused & zstar_shapes, (
             "sanity: the unfused δ path should materialise the masked δ"
         )
 
@@ -483,8 +482,7 @@ class TestBackwardStructure:
         x = jnp.zeros((batch, 8, 8, 3), jnp.int32)
         y = jnp.zeros((batch,), jnp.int32)
         jaxpr = jax.make_jaxpr(functools.partial(
-            les.train_step, cfg=cfg, fused=False, fuse_bwd=True,
-            backend="interpret",
+            les.train_step, cfg=cfg, fused=False, backend="interpret",
         ))(st, x=x, labels=y, key=jax.random.PRNGKey(1))
         # unfused *forward* still materialises z*-shaped selects (sanity
         # that the discriminator sees forward activations as well)

@@ -37,16 +37,15 @@ def toy_data():
 
 
 class TestTrainStep:
-    @pytest.mark.parametrize("fused,fuse_bwd,backend", [
-        (True, True, "auto"),       # the default train path (fwd + bwd fused)
-        (True, True, "interpret"),  # the actual Pallas kernel bodies, off-TPU
-        (True, False, "auto"),      # unfused δ path escape hatch
-        (False, False, "auto"),     # fully unfused reference composition
+    @pytest.mark.parametrize("fused,backend", [
+        (True, "auto"),        # the default train path (fwd + bwd fused)
+        (True, "interpret"),   # the actual Pallas kernel bodies, off-TPU
+        (False, "auto"),       # unfused forward reference composition
     ])
-    def test_step_is_integer_only(self, toy_data, fused, fuse_bwd, backend):
+    def test_step_is_integer_only(self, toy_data, fused, backend):
         """No float dtype anywhere in the jit-compiled training step —
         fused forward *and* fused backward (including inside the Pallas
-        kernel jaxprs), plus both unfused escape hatches."""
+        kernel jaxprs), plus the unfused forward oracle."""
         cfg = NitroConfig(
             blocks=(BlockSpec("conv", 16, pool=True, d_lr=256, dropout=0.1),
                     BlockSpec("linear", 64, dropout=0.1)),
@@ -57,7 +56,7 @@ class TestTrainStep:
         st = les.create_train_state(jax.random.PRNGKey(0), cfg)
         jaxpr = jax.make_jaxpr(
             functools.partial(les.train_step, cfg=cfg, fused=fused,
-                              fuse_bwd=fuse_bwd, backend=backend)
+                              backend=backend)
         )(st, x=x[:8], labels=y[:8], key=jax.random.PRNGKey(1))
         assert_jaxpr_integer_only(jaxpr.jaxpr)
 

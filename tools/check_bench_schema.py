@@ -37,7 +37,6 @@ import sys
 #: file name → expected ``benchmark`` field of the emitting module.
 EXPECTED_NAMES = {
     "BENCH_autotune.json": "autotune_gain",
-    "BENCH_conv.json": "conv_stream",
     "BENCH_infer.json": "serve_infer",
     "BENCH_obs.json": "obs_overhead",
     "BENCH_parallel.json": "dp_scaling",

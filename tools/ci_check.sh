@@ -37,7 +37,6 @@ fi
 
 python -m benchmarks.run --quick --only kernel
 python -m benchmarks.train_step --smoke
-python -m benchmarks.conv_stream --smoke
 python -m benchmarks.autotune_gain --smoke
 python -m benchmarks.serve_fleet --smoke
 python -m benchmarks.obs_overhead --smoke
